@@ -451,6 +451,15 @@ _PROBES = {
 }
 
 
+def program_probe(engine, name: str):
+    """The :class:`Probe` of one contracted program: the engine's own
+    jitted wrapper with example args built from its shapes — what the
+    auditor traces, for a caller that lowers or compiles the same
+    program (``chip_smoke.py`` reads the Pallas kernel out of the
+    compiled decode text). A declining probe returns its reason."""
+    return _PROBES[name](engine)
+
+
 # ---------------------------------------------------------------------------
 # jaxpr analysis
 # ---------------------------------------------------------------------------
@@ -584,7 +593,9 @@ def audit_traced(program: str, fn, args, static_argnums, argnames,
 
     labels = _leaf_labels(args, static_argnums, argnames)
     eqns = closed.jaxpr.eqns
-    if len(eqns) == 1 and eqns[0].primitive.name == "pjit" \
+    # jax 0.9 names the jit call's primitive "jit" (it was "pjit");
+    # its params still carry the closed inner jaxpr + donated_invars
+    if len(eqns) == 1 and eqns[0].primitive.name == "jit" \
             and "jaxpr" in eqns[0].params:
         eq = eqns[0]
         inner = eq.params["jaxpr"].jaxpr
@@ -705,13 +716,17 @@ def audit_traced(program: str, fn, args, static_argnums, argnames,
                   "must not hide behind an existing allowance)")
 
     # ---- DD ----
+    # an input the body hands straight back is a passthrough (DD002
+    # below), not a read: with nothing else using it, it is dead too
+    invar_ids = {id(var): labels[i][1]
+                 for i, var in enumerate(inner.invars)}
     used = set()
     for e in inner.eqns:
         for var in e.invars:
             if not _is_literal(var):
                 used.add(id(var))
     for var in inner.outvars:
-        if not _is_literal(var):
+        if not _is_literal(var) and id(var) not in invar_ids:
             used.add(id(var))
     dead = [labels[i] for i, var in enumerate(inner.invars)
             if id(var) not in used]
@@ -722,14 +737,12 @@ def audit_traced(program: str, fn, args, static_argnums, argnames,
               "but every dispatch flattens and ships it — drop it "
               "from the signature or allowlist it in dead_ok with "
               "a justification")
-    # passthrough outputs are detected on the OUTER jaxpr: pjit
-    # forwards a returned-unchanged input past the call boundary at
-    # trace time, so the inner jaxpr no longer shows it
-    outer = closed.jaxpr
-    invar_ids = {id(var): labels[i][1]
-                 for i, var in enumerate(outer.invars)}
-    for j, var in enumerate(outer.outvars):
-        if id(var) in invar_ids:
+    # passthrough outputs are detected on the INNER jaxpr: jax 0.9
+    # keeps a returned-unchanged input inside the jit call (the outer
+    # eqn binds a fresh outvar for it), so only the body shows an
+    # outvar that IS an invar
+    for j, var in enumerate(inner.outvars):
+        if not _is_literal(var) and id(var) in invar_ids:
             lab = invar_ids[id(var)]
             if not _allowed((lab.split("[")[0].split(".")[0], lab),
                             contract.passthrough_ok):

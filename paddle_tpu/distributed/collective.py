@@ -24,8 +24,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..jax_compat import shard_map
 
 from ..observability import record_collective as _record
 from .topology import get_hybrid_communicate_group

@@ -109,8 +109,6 @@ class CollectiveController:
                 "PADDLE_MASTER": master,
                 "COORDINATOR_ADDRESS": master,
             })
-            if self.args.devices:
-                env["CUDA_VISIBLE_DEVICES"] = self.args.devices
             cmd = [sys.executable] + self.extra
             self.containers.append(
                 Container(rank, cmd, env, self.args.log_dir)
@@ -188,7 +186,8 @@ def parse_args(argv=None):
     p.add_argument("--nproc_per_node", type=int, default=1)
     p.add_argument("--master", type=str,
                    default=os.environ.get("PADDLE_MASTER"))
-    p.add_argument("--devices", type=str, default=None)
+    p.add_argument("--devices", type=str, default=None,
+                   help="refused: a GPU-visibility list no TPU reads")
     p.add_argument("--log_dir", type=str, default="log")
     p.add_argument("--elastic", action="store_true",
                    help="restart failed workers (checkpoint-resume)")
@@ -213,8 +212,41 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
+def host_platform() -> str:
+    """The platform this host's workers will get, found WITHOUT
+    creating a JAX backend: a launcher that touched JAX would hold the
+    chip its worker needs. ``JAX_PLATFORMS`` where the caller set it,
+    else ``tpu`` where TPU chips sit on this host's PCI bus."""
+    want = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
+    if want:
+        return want
+    from jax._src import hardware_utils
+
+    chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    return "tpu" if chips else "cpu"
+
+
+def check_one_process_per_tpu_host(args):
+    """A chip belongs to one process at a time and one process drives
+    every chip of a host, so on a TPU host a second worker would fail
+    or hang at backend start-up: refuse it here, in words."""
+    if args.devices:
+        raise SystemExit(
+            "--devices is a GPU-visibility list (CUDA_VISIBLE_DEVICES) "
+            "that no TPU reads; one worker process drives every chip of "
+            "its host")
+    if args.nproc_per_node > 1 and host_platform() == "tpu":
+        raise SystemExit(
+            f"--nproc_per_node {args.nproc_per_node} on a TPU host: a "
+            "chip belongs to one process at a time, so launch ONE worker "
+            "per host (it drives all of the host's chips); "
+            "nproc_per_node > 1 is for CPU/debug meshes "
+            "(JAX_PLATFORMS=cpu)")
+
+
 def launch(argv=None) -> int:
     args = parse_args(argv)
+    check_one_process_per_tpu_host(args)
     if args.np and args.elastic_store == "/tmp" and \
             parse_np_max(args.np) > 1:
         print("warning: --elastic_store=/tmp is node-local; multi-node "

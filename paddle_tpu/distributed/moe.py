@@ -275,7 +275,7 @@ def dropless_moe_ep_apply(xf, gate_weight, w1, b1, w2, b2, act, top_k,
     """
     from jax import lax
 
-    from ..jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     ep = mesh.shape[ep_axis]

@@ -44,9 +44,8 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from ..jax_compat import pcast as _pcast
-from ..jax_compat import shard_map
-from ..jax_compat import vma_of as _vma_of
+from jax import shard_map
+from jax.lax import pcast as _pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import initializer as I
@@ -224,7 +223,7 @@ def pipeline_1f1b_step(
             # scan carries become pp-varying through the ppermute/axis_index
             # data flow; the zero-init must carry the same vma type.
             # Idempotent: already-varying values pass through.
-            if axis in _vma_of(x):
+            if axis in jax.typeof(x).vma:
                 return x
             return _pcast(x, (axis,), to="varying")
 

@@ -249,6 +249,12 @@ def suppress_constraints():
         _suppress_var.reset(tok)
 
 
+def constraints_suppressed() -> bool:
+    """True while tracing inside a manual-axis region (see
+    ``suppress_constraints``)."""
+    return _suppress_var.get()
+
+
 def shard_activation(x, *spec_entries):
     """with_sharding_constraint against the ambient mesh; no-op when no
     mesh is active (single-device eager use) or when constraints are

@@ -4,9 +4,10 @@ Parity: the reference's native reader/worker pipeline — this keeps token
 batch materialization (mmap reads + shuffle + copy) off the Python
 interpreter; Python only pops finished int32 buffers and device_puts.
 
-Builds the .so on first use (g++ is in the image); falls back cleanly —
-callers should catch ImportError/OSError and use the pure-python
-DataLoader.
+Builds the .so on first use (g++ is in the image) and again whenever its
+source or the Makefile is newer — ``make`` decides, so a stale library
+from another checkout or compiler is never loaded as found. Callers
+should catch ImportError/OSError and use the pure-python DataLoader.
 """
 
 from __future__ import annotations
@@ -23,15 +24,21 @@ _SO = os.path.join(_CSRC, "libptdataloader.so")
 _lib = None
 
 
+def _build(so_path: str) -> str:
+    """``make`` the library: a no-op when it is newer than its source
+    and the Makefile, a rebuild when it is missing or stale."""
+    subprocess.run(
+        ["make", "-C", _CSRC, os.path.basename(so_path)],
+        check=True, capture_output=True,
+    )
+    return so_path
+
+
 def _load():
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_SO):
-        subprocess.run(
-            ["make", "-C", _CSRC], check=True, capture_output=True
-        )
-    lib = ctypes.CDLL(_SO)
+    lib = ctypes.CDLL(_build(_SO))
     lib.ptdl_open.argtypes = [ctypes.c_char_p, ctypes.c_int, ctypes.c_int64]
     lib.ptdl_open.restype = ctypes.c_int
     lib.ptdl_num_seqs.argtypes = [ctypes.c_int]
@@ -130,13 +137,11 @@ def load_ckpt_writer():
         raise OSError("native checkpoint writer unavailable (cached)")
     if _ckpt_lib is not None:
         return _ckpt_lib
-    if not os.path.exists(_CKPT_SO):
-        try:
-            subprocess.run(["make", "-C", _CSRC], check=True,
-                           capture_output=True)
-        except Exception:
-            _ckpt_lib = False
-            raise
+    try:
+        _build(_CKPT_SO)
+    except (OSError, subprocess.CalledProcessError):
+        _ckpt_lib = False
+        raise
     lib = ctypes.CDLL(_CKPT_SO)
     lib.ptck_write_batch.argtypes = [
         ctypes.c_int,

@@ -26,9 +26,11 @@ Structure (mirrors kernels/paged_attention.py):
   - RoPE is applied in-kernel from scalar-prefetched positions (the
     cos/sin table row is the block index — one row read per slot);
   - the new token's K/V is merged into the streamed chunk in VMEM and
-    written back as ONE aliased row (``input_output_aliases``), so the
-    token never round-trips through HBM before attention reads it and
-    the separate append scatter disappears from the decode trace.
+    the sublane tile around its row is written back through
+    ``input_output_aliases`` (the chip's compiler refuses a one-row
+    block), so the token never round-trips through HBM before
+    attention reads it and the separate append scatter disappears from
+    the decode trace.
 """
 
 from __future__ import annotations
@@ -42,10 +44,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import flags
-from ..jax_compat import tpu_compiler_params
 from .paged_attention import (
     NEG_INF,
     _interpret,
+    append_tile_rows,
     kernel_quant_rows,
     kernel_rope_rot,
     online_softmax_update,
@@ -117,7 +119,8 @@ def fused_decode_active(head_dim: int, minor: int, dtype=None) -> bool:
 # ---------------------------------------------------------------------------
 def _fused_contig_kernel(lens_ref, pos_ref, q_ref, kn_ref, vn_ref,
                          k_ref, v_ref, *rest,
-                         scale, chunk, n_chunks, kvh, d, quant):
+                         scale, chunk, n_chunks, kvh, d, quant,
+                         append_rows):
     if quant:
         (ks_ref, vs_ref, cos_ref, sin_ref, o_ref, ko_ref, vo_ref,
          kso_ref, vso_ref, q_scratch, m_scratch, l_scratch,
@@ -138,7 +141,7 @@ def _fused_contig_kernel(lens_ref, pos_ref, q_ref, kn_ref, vn_ref,
         return kernel_rope_rot(x, cos, sin)
 
     # rotated new-token K for all heads, flattened to the cache row
-    # layout [1, kvh*d]; written back as ONE aliased row per slot.
+    # layout [1, kvh*d] — the row appended to the cache.
     # Attention merges the CACHE-DTYPE-ROUNDED values — same rounding
     # the unfused path's appended row gets — so bf16/int8 caches cannot
     # flip a greedy argmax between the fused and unfused engines
@@ -150,19 +153,13 @@ def _fused_contig_kernel(lens_ref, pos_ref, q_ref, kn_ref, vn_ref,
         # the cache row, f32 scales to the [1, kvh] scale row
         kq, kscl = kernel_quant_rows(k_rot)   # [kvh, 1, d], [kvh, 1, 1]
         vq, vscl = kernel_quant_rows(v_raw)
-        ko_ref[...] = kq.reshape(1, kvh * d)
-        vo_ref[...] = vq.reshape(1, kvh * d)
-        kso_ref[...] = kscl.reshape(1, kvh)
-        vso_ref[...] = vscl.reshape(1, kvh)
         k_new = (kq.astype(jnp.float32) * kscl).reshape(1, kvh * d)
         v_new = (vq.astype(jnp.float32) * vscl).reshape(1, kvh * d)
     else:
-        k_store = k_rot.reshape(1, kvh * d).astype(ko_ref.dtype)
-        v_store = v_raw.reshape(1, kvh * d).astype(vo_ref.dtype)
-        ko_ref[...] = k_store
-        vo_ref[...] = v_store
-        k_new = k_store.astype(jnp.float32)
-        v_new = v_store.astype(jnp.float32)
+        k_new = k_rot.reshape(1, kvh * d).astype(
+            ko_ref.dtype).astype(jnp.float32)
+        v_new = v_raw.reshape(1, kvh * d).astype(
+            vo_ref.dtype).astype(jnp.float32)
 
     @pl.when(j == 0)
     def _init():
@@ -178,6 +175,34 @@ def _fused_contig_kernel(lens_ref, pos_ref, q_ref, kn_ref, vn_ref,
         sel = (row == offs) & is_last
         kf = k_ref[...].astype(jnp.float32)
         vf = v_ref[...].astype(jnp.float32)
+
+        # the append: the sublane tile of `append_rows` rows that holds
+        # row `offs` goes back with the new row merged in (Mosaic
+        # refuses a one-row output block — see _fused_decode_kernel);
+        # the f32 round trip of the other rows is exact, so they return
+        # bit-identical
+        @pl.when(is_last)
+        def _append():
+            base = pl.multiple_of(offs // append_rows * append_rows,
+                                  append_rows)
+            tile = pl.ds(base, append_rows)
+            hit = (base + jax.lax.broadcasted_iota(
+                jnp.int32, (append_rows, 1), 0)) == offs
+
+            def merged(new, ref):
+                old = ref[tile, :]
+                return jnp.where(hit, new.astype(jnp.float32),
+                                 old.astype(jnp.float32)).astype(old.dtype)
+
+            if quant:
+                ko_ref[...] = merged(kq.reshape(1, kvh * d), k_ref)
+                vo_ref[...] = merged(vq.reshape(1, kvh * d), v_ref)
+                kso_ref[...] = merged(kscl.reshape(1, kvh), ks_ref)
+                vso_ref[...] = merged(vscl.reshape(1, kvh), vs_ref)
+            else:
+                ko_ref[...] = merged(k_new, k_ref)
+                vo_ref[...] = merged(v_new, v_ref)
+
         if quant:
             # dequantize the streamed chunk: scale rows [chunk, kvh]
             # broadcast over each head's d-segment of the row layout
@@ -271,10 +296,13 @@ def fused_contiguous_decode_attention(q, k_new, v_new, ck, cv, seq_lens,
         return (s, jnp.minimum(j, lens_ref[s] // chunk), 0)
 
     def rope_index(s, j, lens_ref, pos_ref):
-        return (pos_ref[s], 0)
+        return (pos_ref[s], 0, 0)
+
+    append_rows = append_tile_rows(chunk, ck.dtype.itemsize)
 
     def append_index(s, j, lens_ref, pos_ref):
-        return (s, lens_ref[s], 0)  # the new token's row, constant in j
+        # the tile that holds the new token's row, constant in j
+        return (s, lens_ref[s] // append_rows, 0)
 
     in_specs = [
         pl.BlockSpec((None, kvh, group_pad, d),
@@ -288,8 +316,8 @@ def fused_contiguous_decode_attention(q, k_new, v_new, ck, cv, seq_lens,
     ]
     out_specs = [
         pl.BlockSpec((1, kvh, group_pad, d), q_index),
-        pl.BlockSpec((None, 1, kvh * d), append_index),
-        pl.BlockSpec((None, 1, kvh * d), append_index),
+        pl.BlockSpec((None, append_rows, kvh * d), append_index),
+        pl.BlockSpec((None, append_rows, kvh * d), append_index),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((slots, kvh, group_pad, d), q.dtype),
@@ -307,8 +335,8 @@ def fused_contiguous_decode_attention(q, k_new, v_new, ck, cv, seq_lens,
             pl.BlockSpec((None, chunk, kvh), kv_index),
         ]
         out_specs += [
-            pl.BlockSpec((None, 1, kvh), append_index),
-            pl.BlockSpec((None, 1, kvh), append_index),
+            pl.BlockSpec((None, append_rows, kvh), append_index),
+            pl.BlockSpec((None, append_rows, kvh), append_index),
         ]
         out_shape += [
             jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
@@ -316,11 +344,12 @@ def fused_contiguous_decode_attention(q, k_new, v_new, ck, cv, seq_lens,
         ]
         aliases.update({7: 3, 8: 4})
         operands += [k_scale, v_scale]
+    # [max_pos, 1, d/2]: see fused_paged_decode_attention
     in_specs += [
-        pl.BlockSpec((1, half), rope_index),
-        pl.BlockSpec((1, half), rope_index),
+        pl.BlockSpec((None, 1, half), rope_index),
+        pl.BlockSpec((None, 1, half), rope_index),
     ]
-    operands += [cos, sin]
+    operands += [cos.reshape(-1, 1, half), sin.reshape(-1, 1, half)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -337,13 +366,14 @@ def fused_contiguous_decode_attention(q, k_new, v_new, ck, cv, seq_lens,
     kernel = functools.partial(
         _fused_contig_kernel, scale=scale, chunk=chunk,
         n_chunks=n_chunks, kvh=kvh, d=d, quant=quant,
+        append_rows=append_rows,
     )
     res = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=_interpret(),

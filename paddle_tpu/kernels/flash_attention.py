@@ -13,6 +13,7 @@ own fusion is already competitive.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -57,15 +58,8 @@ def _use_pallas(q) -> bool:
         # CI/dryrun override: run the Pallas kernel in interpret mode off
         # TPU so the graft entry exercises the real kernel code path
         return aligned
-    try:
-        dev = q.devices() if hasattr(q, "devices") else set(jax.devices())
-        platform = next(iter(dev)).platform if dev else jax.default_backend()
-    except Exception:
-        platform = jax.default_backend()
-    if platform != "tpu":
-        return False
     # Pallas kernel wants MXU/VPU-aligned tiles
-    return aligned
+    return aligned and jax.default_backend() == "tpu"
 
 
 def flash_attention(
@@ -110,13 +104,12 @@ def flash_attention(
             is_causal=causal, scale=scale, training=training,
         )
     if _use_pallas(q):
-        try:
-            return _pallas_flash_attention(q, k, v, causal=causal,
-                                           scale=scale,
-                                           segment_ids=segment_ids,
-                                           window=window_size)
-        except Exception:
-            pass
+        # no fallback from here: what the kernel or the chip's compiler
+        # raises is the error, never a silent O(s²) dense attention
+        return _pallas_flash_attention(q, k, v, causal=causal,
+                                       scale=scale,
+                                       segment_ids=segment_ids,
+                                       window=window_size)
     if segment_ids is not None:
         return _segment_reference_attention(q, k, v, segment_ids,
                                             causal=causal, scale=scale,
@@ -140,14 +133,54 @@ def _segment_reference_attention(q, k, v, segment_ids, causal=False,
 # ---------------------------------------------------------------------------
 # Pallas implementation
 # ---------------------------------------------------------------------------
+def _mesh_axes(mesh, names, n: int):
+    """The mesh axes among ``names`` (in order, size > 1) a dim of
+    extent ``n`` can be split over — a PartitionSpec entry, or None."""
+    kept, ways = [], 1
+    for a in names:
+        size = mesh.shape.get(a, 1)
+        if size > 1 and n % (ways * size) == 0:
+            kept.append(a)
+            ways *= size
+    return tuple(kept) or None
+
+
 def _pallas_flash_attention(q, k, v, causal=False, scale=None,
                             segment_ids=None, window=0):
     from .. import flags
+    from ..distributed.sharding import constraints_suppressed, current_mesh
     from .pallas_attention import mha as pallas_mha
 
-    # VMEM tile shape knobs (PT_FLAGS_flash_attention_block_{q,k});
-    # mha clamps them to the actual (padded) sequence internally
-    return pallas_mha(q, k, v, causal=causal, sm_scale=scale,
-                      q_block=int(flags.flag("flash_attention_block_q")),
-                      k_block=int(flags.flag("flash_attention_block_k")),
-                      segment_ids=segment_ids, window=window)
+    def attend(q, k, v, segment_ids=None):
+        # VMEM tile shape knobs (PT_FLAGS_flash_attention_block_{q,k});
+        # mha clamps them to the actual (padded) sequence internally
+        return pallas_mha(
+            q, k, v, causal=causal, sm_scale=scale,
+            q_block=int(flags.flag("flash_attention_block_q")),
+            k_block=int(flags.flag("flash_attention_block_k")),
+            segment_ids=segment_ids, window=window)
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1 or constraints_suppressed():
+        return attend(q, k, v, segment_ids)
+    # The SPMD partitioner refuses a Pallas call ("Mosaic kernels cannot
+    # be automatically partitioned. Please wrap the call in a
+    # shard_map"), so under a mesh the kernel runs per shard, split the
+    # way the model already lays its activations out: batch over
+    # (dp, fsdp), heads over tp (and sep inside the Ulysses region).
+    # Attention is independent across both, so no collective is needed;
+    # a dim the axes do not divide stays whole.
+    from jax.sharding import PartitionSpec as P
+
+    batch = _mesh_axes(mesh, ("dp", "fsdp"), q.shape[0])
+    heads = _mesh_axes(mesh, ("tp", "sep"),
+                       math.gcd(q.shape[2], k.shape[2]))
+    qkv = P(batch, None, heads, None)
+    if segment_ids is None:
+        return jax.shard_map(
+            attend, mesh=mesh, in_specs=(qkv, qkv, qkv), out_specs=qkv,
+            check_vma=False)(q, k, v)
+    seg = jax.tree_util.tree_map(lambda _: P(batch, None), segment_ids)
+    return jax.shard_map(
+        attend, mesh=mesh, in_specs=(qkv, qkv, qkv, seg), out_specs=qkv,
+        check_vma=False)(q, k, v, segment_ids)

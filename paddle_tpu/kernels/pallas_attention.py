@@ -61,9 +61,7 @@ def _interpret() -> bool:
 
 
 def _params(*parallel_then_arbitrary: str):
-    from ..jax_compat import tpu_compiler_params
-
-    return tpu_compiler_params(dimension_semantics=parallel_then_arbitrary)
+    return pltpu.CompilerParams(dimension_semantics=parallel_then_arbitrary)
 
 
 def _causal_j_max(i: int, q_block: int, k_block: int):

@@ -33,7 +33,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30

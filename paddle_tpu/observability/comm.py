@@ -29,7 +29,6 @@ _SKIP_DIRS = (
     os.path.join("paddle_tpu", "distributed"),
     os.path.join("paddle_tpu", "observability"),
     os.sep + "jax" + os.sep,
-    os.sep + "jax_compat.py",
     "functools.py",
     "contextlib.py",
 )

@@ -10,7 +10,7 @@ import torch
 
 import paddle_tpu as pt
 from paddle_tpu import autograd
-from paddle_tpu.jax_compat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 
 
 class TestFunctionalAutograd:

@@ -39,7 +39,7 @@ def test_headline_cpu_smoke():
     """The headline llama bench body itself (not via subprocess)."""
     import bench
 
-    r = bench.bench_llama_train(None)
+    r = bench.bench_llama_train()
     assert r["value"] > 0
     assert r["unit"] == "tokens/s/chip"
 
@@ -92,56 +92,47 @@ def test_compact_line_contract(tmp_path, monkeypatch):
         "T" * 1500
 
 
-def test_compact_line_cpu_fallback_carries_capture_pointer(
-        tmp_path, monkeypatch):
-    """Any cpu-plane headline (probe failure OR explicit
-    JAX_PLATFORMS=cpu) must name the freshest COMMITTED device capture
-    (timestamp + commit + headline metric) so the driver ledger always
-    points at verifiable evidence — and the pointer must survive the
-    final over-2KB shed. A tpu-plane result must NOT carry one."""
+@pytest.mark.parametrize("jax_platforms,exits", [(None, True),
+                                                 ("cpu", False)])
+def test_child_without_chip_exits_nonzero(jax_platforms, exits,
+                                          monkeypatch):
+    """A bench process that finds no TPU ends non-zero before it builds
+    a model — no probe, no retry, no rerun on the CPU. Only
+    ``JAX_PLATFORMS=cpu`` GIVEN BY THE CALLER is the labelled CPU smoke
+    (this suite's own lane)."""
     import bench
 
-    monkeypatch.setattr(bench, "DETAILS_PATH",
-                        str(tmp_path / "BENCH_DETAILS.json"))
-    cap = tmp_path / "BENCH_TPU_CAPTURE.json"
-    cap.write_text(json.dumps({
-        "captured_at": "2026-07-31T07:16:14Z", "headline": "llama_b4",
-        "configs": {"llama_b4": {
-            "metric": "llama876m_train_tokens_per_sec_per_chip",
-            "value": 25933.2, "unit": "tokens/s/chip"}}}))
-    monkeypatch.setattr(bench, "CAPTURE_PATH", str(cap))
+    if jax_platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", jax_platforms)
+    if exits:
+        with pytest.raises(SystemExit) as e:
+            bench._require_chip()
+        assert e.value.code not in (0, None)
+        assert "no TPU" in str(e.value.code)
+    else:
+        bench._require_chip()
 
-    fat = _fat_result()
-    parsed = json.loads(bench._compact_line(fat))
-    ptr = parsed["extra"]["last_device_capture"]
-    assert ptr["captured_at"] == "2026-07-31T07:16:14Z"
-    assert ptr["metric"] == "llama876m_train_tokens_per_sec_per_chip"
-    assert ptr["value"] == 25933.2
-    # uncommitted tmp file: identity rides without git provenance
-    assert "commit" not in ptr
 
-    # explicit-cpu line (no tpu_probe at all) still carries it
-    slim = {"metric": "llama_train_cpu_smoke_tokens_per_sec",
-            "value": 90.0, "unit": "tokens/s/chip", "vs_baseline": 1.0,
-            "extra": {"platform": "cpu", "n_chips": 1}}
-    parsed = json.loads(bench._compact_line(slim))
-    assert parsed["extra"]["last_device_capture"]["value"] == 25933.2
+def test_failed_headline_exits_nonzero_without_a_result_line(
+        monkeypatch, capsys):
+    """A headline child that raised (no result line, rc != 0) makes the
+    parent exit non-zero and print NO JSON line — an exception is never
+    turned into a result and exit 0."""
+    import bench
 
-    # the final shed keeps it: with the byte budget squeezed below the
-    # compacted fat line, extra collapses to its survival set and the
-    # pointer must be in it
-    monkeypatch.setattr(bench, "MAX_LINE_BYTES", 500)
-    shed = json.loads(bench._compact_line(fat))
-    assert set(shed["extra"]) <= {"platform", "n_chips",
-                                  "last_device_capture"}
-    assert shed["extra"]["last_device_capture"]["value"] == 25933.2
-    monkeypatch.setattr(bench, "MAX_LINE_BYTES", 2000)
-
-    # a tpu-plane result never points at itself
-    tpu = {"metric": "m", "value": 1.0, "unit": "u", "vs_baseline": 1.0,
-           "extra": {"platform": "tpu", "n_chips": 1}}
-    assert "last_device_capture" not in \
-        json.loads(bench._compact_line(tpu))["extra"]
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--no-secondary"])
+    monkeypatch.setattr(
+        bench, "_run_one_config",
+        lambda name, env, timeout: {
+            "metric": f"bench_{name}_failed", "value": 0.0,
+            "unit": "error", "vs_baseline": 0.0,
+            "extra": {"rc": 1, "stderr": "Traceback ... boom"}})
+    with pytest.raises(SystemExit) as e:
+        bench.main()
+    assert e.value.code not in (0, None)
+    assert capsys.readouterr().out == ""
 
 
 def test_compact_line_headline_error(tmp_path, monkeypatch):
@@ -248,3 +239,49 @@ def test_compact_line_carries_flight_scalars(tmp_path, monkeypatch):
     shed = json.loads(bench._compact_line(r))
     sec = shed["extra"].get("secondary", {}).get("serve7b", {})
     assert "flight" not in sec and "goodput" not in sec
+
+
+def test_chip_smoke_refuses_to_run_without_a_chip():
+    """``chip_smoke.py`` with no argument on a machine without a TPU
+    exits non-zero before it builds a model and prints no result — the
+    driver runs exactly this in the sandbox and it must fail."""
+    import subprocess
+    import time
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    t0 = time.monotonic()
+    r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=root, env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode not in (0, None), r.stdout
+    assert '"ok"' not in r.stdout and "model:" not in r.stdout
+    assert "needs 1 tpu device" in r.stderr
+    assert time.monotonic() - t0 < 60
+
+
+@pytest.mark.parametrize("env_dir", [None, "/some/where/else"])
+def test_compile_cache_directory_rule(env_dir, monkeypatch):
+    """One rule for chip_smoke.py and bench.py: with
+    JAX_COMPILATION_CACHE_DIR set, no directory is set in code (JAX
+    reads the variable itself); unset, the cache is the one fixed
+    directory inside the checkout."""
+    import jax
+
+    from benchmarks import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert compile_cache.enable_compile_cache() == \
+            compile_cache.DEFAULT_DIR
+        assert updates == [("jax_compilation_cache_dir",
+                            compile_cache.DEFAULT_DIR)]
+        assert compile_cache.DEFAULT_DIR == os.path.join(
+            compile_cache.REPO_ROOT, ".jax_cache")
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert compile_cache.enable_compile_cache() == env_dir
+        assert updates == []

@@ -1,0 +1,226 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+chip that is described and not attached. Interpret-mode tests cannot see
+what it refuses (a block not aligned to the (8, 128) tiling, too much
+VMEM), so the Pallas kernels of the main path are compiled here at
+llama2-7B widths. Nothing runs: a case passing says the kernel lowers,
+never that it is right or fast.
+
+This is the only test file that describes the chip. Only one process
+may hold libtpu, so the topology is described inside a module-scoped
+fixture (never at import, in a ``skipif`` or in ``parametrize``
+arguments) and every case compiles in the test's own process.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.kernels import (
+    decode_attention,
+    paged_attention,
+    pallas_attention,
+    quant_matmul,
+)
+
+BF16 = jnp.bfloat16
+# llama2-7B attention widths; the engine's 8 decode slots
+HEADS, D, SLOTS, MAX_POS = 32, 128, 8, 4096
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip_compile(one_chip, monkeypatch):
+    """compile(fn, specs) for the described chip, with the
+    kernels' interpret switch forced off (here ``default_backend()`` is
+    the CPU), the persistent compile cache off (an entry written for a
+    described chip cannot be read back without one) and matmul precision
+    at the product default (``conftest.py`` pins ``highest`` for the
+    numerics tests; Mosaic refuses an fp32-precision bf16 matmul)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    for mod in (pallas_attention, paged_attention, decode_attention,
+                quant_matmul):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    saved = {k: getattr(jax.config, k) for k in
+             ("jax_enable_compilation_cache",
+              "jax_default_matmul_precision")}
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, specs, sharding=one_chip):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+                for s, d in specs]
+        return jax.jit(fn).lower(*args).compile()
+
+    yield compile_
+    for k, v in saved.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def _flash(grad, b=2, s=2048, hq=HEADS, hk=HEADS):
+    def fwd(q, k, v):
+        return pallas_attention.mha(q, k, v, causal=True)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), (0, 1, 2)
+        )(q, k, v)
+
+    q = ((b, s, hq, D), BF16)
+    kv = ((b, s, hk, D), BF16)
+    return (fwd_bwd if grad else fwd), (q, kv, kv)
+
+
+def _paged_specs(kvh, page, pool_dtype, new_token):
+    """Operand specs of the paged decode kernels: max_len 1024 at 8
+    slots, as ``chip_smoke.py`` serves."""
+    max_pages = 1024 // page
+    n_pages = SLOTS * max_pages + 1
+    pool = ((kvh, n_pages, page, D), pool_dtype)
+    specs = [((SLOTS, kvh, HEADS // kvh, D), BF16)]
+    if new_token:
+        specs += [((SLOTS, kvh, D), BF16)] * 2
+    specs += [pool, pool, ((SLOTS, max_pages), jnp.int32),
+              ((SLOTS,), jnp.int32)]
+    return specs, n_pages
+
+
+def _block_table_decode(kvh, page):
+    specs, _ = _paged_specs(kvh, page, BF16, new_token=False)
+    return paged_attention.paged_decode_attention, specs
+
+
+def _fused_paged(kvh, page, pool_dtype):
+    specs, n_pages = _paged_specs(kvh, page, pool_dtype, new_token=True)
+    rope = ((MAX_POS, D // 2), jnp.float32)
+    specs += [((SLOTS,), jnp.int32), rope, rope]
+    if pool_dtype == jnp.int8:
+        scale = ((kvh, n_pages, page, 1), jnp.float32)
+        specs += [scale, scale]
+
+        def fn(q, kn, vn, kp, vp, bt, lens, pos, cos, sin, ks, vs):
+            return paged_attention.fused_paged_decode_attention(
+                q, kn, vn, kp, vp, bt, lens, pos, cos, sin,
+                k_scale=ks, v_scale=vs)
+
+        return fn, specs
+    return paged_attention.fused_paged_decode_attention, specs
+
+
+def _fused_contiguous(kvh, max_len, cache_dtype):
+    cache = ((SLOTS, max_len, kvh, D), cache_dtype)
+    rope = ((MAX_POS, D // 2), jnp.float32)
+    specs = [((SLOTS, kvh, HEADS // kvh, D), BF16),
+             ((SLOTS, kvh, D), BF16), ((SLOTS, kvh, D), BF16),
+             cache, cache, ((SLOTS,), jnp.int32), ((SLOTS,), jnp.int32),
+             rope, rope]
+    if cache_dtype == jnp.int8:
+        scale = ((SLOTS, max_len, kvh), jnp.float32)
+        specs += [scale, scale]
+
+        def fn(q, kn, vn, ck, cv, lens, pos, cos, sin, ks, vs):
+            return decode_attention.fused_contiguous_decode_attention(
+                q, kn, vn, ck, cv, lens, pos, cos, sin,
+                k_scale=ks, v_scale=vs)
+
+        return fn, specs
+    return decode_attention.fused_contiguous_decode_attention, specs
+
+
+def _quant_matmul(m, weight_dtype):
+    k, n = 4096, 11008  # llama2-7B gate/up projection
+    rows = k // 2 if weight_dtype == "int4" else k
+
+    def fn(x, w, s):
+        return quant_matmul.weight_only_matmul_pallas(
+            x, w, s, weight_dtype=weight_dtype)
+
+    return fn, [((m, k), BF16), ((rows, n), jnp.int8),
+                ((k // 128, n), jnp.float32)]
+
+
+# every Pallas kernel of the train and serve main paths, and every
+# decode kernel PT_FLAGS_fused_decode=auto can choose on a TPU
+CASES = {
+    "flash_fwd": lambda: _flash(False),
+    "flash_fwd_bwd": lambda: _flash(True),
+    "flash_fwd_bwd_gqa8_s8192": lambda: _flash(True, b=1, s=8192, hk=8),
+    "block_table_decode_p64": lambda: _block_table_decode(32, 64),
+    "block_table_decode_p16_gqa8": lambda: _block_table_decode(8, 16),
+    "fused_paged_bf16_p64": lambda: _fused_paged(32, 64, BF16),
+    "fused_paged_bf16_p16_gqa8": lambda: _fused_paged(8, 16, BF16),
+    "fused_paged_bf16_p128": lambda: _fused_paged(32, 128, BF16),
+    "fused_paged_int8_p64": lambda: _fused_paged(32, 64, jnp.int8),
+    "fused_paged_int8_p32_gqa8": lambda: _fused_paged(8, 32, jnp.int8),
+    "fused_contiguous_bf16": lambda: _fused_contiguous(32, 2048, BF16),
+    "fused_contiguous_bf16_gqa8": lambda: _fused_contiguous(8, 1024, BF16),
+    "fused_contiguous_int8": lambda: _fused_contiguous(32, 1024, jnp.int8),
+    "wo_matmul_int8_m8": lambda: _quant_matmul(8, "int8"),
+    "wo_matmul_int8_m256": lambda: _quant_matmul(256, "int8"),
+    "wo_matmul_int4_m256": lambda: _quant_matmul(256, "int4"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, chip_compile):
+    fn, specs = CASES[case]()
+    compiled = chip_compile(fn, specs)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_compiles_under_four_chip_mesh(topo, chip_compile,
+                                             monkeypatch):
+    """The ZeRO-3 train step's attention on the 2x2 host: a bare Pallas
+    call there is refused ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"), so
+    ``flash_attention`` runs it per shard under a mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu import distributed as dist
+    from paddle_tpu.distributed.sharding import mesh_context
+    from paddle_tpu.kernels.flash_attention import flash_attention
+
+    # _use_pallas asks default_backend(), which is the CPU here
+    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    mesh = dist.build_mesh(fsdp=4, devices=list(topo.devices))
+    qkv = ((4, 2048, HEADS, D), BF16)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: flash_attention(
+            *a, causal=True).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    with mesh_context(mesh):
+        compiled = chip_compile(
+            fwd_bwd, (qkv, qkv, qkv),
+            NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None)))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("page,dtype", [(64, BF16), (16, BF16),
+                                        (32, jnp.int8), (128, jnp.int8)])
+def test_auto_only_picks_compiled_decode_kernels(page, dtype, monkeypatch):
+    """What ``auto`` says on a TPU for the tilings compiled above: the
+    gate and the compile cases must not drift apart."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert decode_attention.fused_decode_active(D, page, dtype)
+    assert not decode_attention.fused_decode_active(D, 8, dtype)

@@ -4,7 +4,7 @@ checkpoint writer, the process DataLoader, and the serving scheduler.
 Parity intent: the reference runs sanitizer CI builds and worker-kill
 tests (test/collective/, DataLoader worker-exit tests); functional purity
 covers device races here, so the host-side threads are what need stress
-coverage (VERDICT r4 §aux: the one 'partial' row).
+coverage.
 """
 
 import gc

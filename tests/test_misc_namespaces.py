@@ -7,7 +7,7 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import distribution, fft, jit, linalg, nn, quantization as q
-from paddle_tpu.jax_compat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 
 
 def test_linalg_basics():

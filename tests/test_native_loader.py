@@ -93,3 +93,27 @@ def test_ckpt_writer_python_fallback(tmp_path, monkeypatch):
     ckpt.save_state_dict({"w": jnp.ones((4, 4))}, str(tmp_path / "fb"))
     loaded = ckpt.load_state_dict(str(tmp_path / "fb"))
     np.testing.assert_allclose(np.asarray(loaded["w"]), 1.0)
+
+
+def test_stale_library_is_rebuilt_not_loaded_as_found():
+    """A library older than its source (another checkout's or another
+    compiler's build left on disk) must be rebuilt before it is loaded:
+    ``make`` decides, on every first use."""
+    import os
+
+    from paddle_tpu.io import native
+
+    native._build(native._SO)
+    src = os.path.join(native._CSRC, "dataloader.cpp")
+    built = os.stat(native._SO).st_mtime_ns
+    # up to date: a no-op, the file is left alone
+    native._build(native._SO)
+    assert os.stat(native._SO).st_mtime_ns == built
+    # source newer than the library: rebuilt
+    src_times = os.stat(src)
+    try:
+        os.utime(src, ns=(built + 10**9, built + 10**9))
+        native._build(native._SO)
+        assert os.stat(native._SO).st_mtime_ns > built
+    finally:
+        os.utime(src, ns=(src_times.st_atime_ns, src_times.st_mtime_ns))

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 import paddle_tpu.nn.functional as F
-from paddle_tpu.jax_compat import enable_x64 as _enable_x64
+from jax import enable_x64 as _enable_x64
 
 # core-engine fast lane (see README "Tests")
 pytestmark = pytest.mark.fast

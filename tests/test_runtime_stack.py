@@ -136,6 +136,44 @@ def test_launch_cli_single_node(tmp_path):
     assert "rank 0 of 2" in content and "rank 1 of 2" in content
 
 
+@pytest.mark.parametrize("argv,platform,refused", [
+    (["--nproc_per_node", "2"], "tpu", "one process at a time"),
+    (["--nproc_per_node", "1"], "tpu", None),
+    (["--nproc_per_node", "2"], "cpu", None),
+    (["--devices", "0,1"], "cpu", "no TPU reads"),
+])
+def test_launch_one_process_per_tpu_host(argv, platform, refused,
+                                         monkeypatch):
+    """A chip belongs to one process: on a TPU host a second worker per
+    node is refused in words (CPU/debug meshes keep nproc > 1), and the
+    GPU-visibility list is refused everywhere instead of being exported
+    as an env var nothing reads."""
+    from paddle_tpu.distributed.launch import main as launch_main
+
+    monkeypatch.setattr(launch_main, "host_platform", lambda: platform)
+    args = launch_main.parse_args(argv + ["train.py"])
+    if refused is None:
+        launch_main.check_one_process_per_tpu_host(args)
+    else:
+        with pytest.raises(SystemExit, match=refused):
+            launch_main.check_one_process_per_tpu_host(args)
+
+
+def test_launch_host_platform_creates_no_backend(monkeypatch):
+    """The launcher must learn the platform without starting JAX's
+    backend, or it would hold the chip its worker needs."""
+    from jax._src import xla_bridge
+
+    from paddle_tpu.distributed.launch import main as launch_main
+
+    before = set(xla_bridge._backends)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert launch_main.host_platform() == "cpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert launch_main.host_platform() in ("cpu", "tpu")
+    assert set(xla_bridge._backends) == before
+
+
 def test_launch_cli_elastic_restart(tmp_path):
     # worker fails on first run (marker file absent), succeeds on restart
     script = tmp_path / "flaky.py"
