@@ -78,12 +78,8 @@ def _train_throughput(model, data, loss_fn=None, unit_count=0):
     ts.run(data)
     loss = jax.block_until_ready(ts.run(data))
 
-    # cost analysis BEFORE the timed phase, and prime the TrainStep's
-    # telemetry FLOPs cache with it — the lazy probe (an AOT
-    # lower+compile) must never fire inside a traced timing window
+    # cost analysis BEFORE the timed phase (an AOT lower+compile)
     flops = compiled_flops(ts.lower(data))
-    ts._flops_per_step = flops
-    ts._flops_probed = True
 
     # phase 1: short trace to learn the true device step time
     timing = traced_step_ms(lambda: ts.run(data), n_steps=3)

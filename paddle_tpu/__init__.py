@@ -37,6 +37,17 @@ if _mmp:
             "float32|highest, or empty for the default)") from _e
     del _jax_cfg
 del _mmp
+# The persistent compile cache keys a program WITHOUT its op_name
+# metadata by default, so a build whose only change is a
+# ``jax.named_scope`` (or a shifted source line) is served another
+# build's executable, and a profile of it shows that build's names: the
+# device phases of observability/spans.py would read "no scope". A
+# profile must not show another build's names. Price: the first run
+# after a change that moves traced source lines compiles again.
+import jax as _jax_cfg
+
+_jax_cfg.config.update("jax_compilation_cache_include_metadata_in_key", True)
+del _jax_cfg
 from . import incubate  # noqa: F401
 from . import jit  # noqa: F401
 from . import linalg  # noqa: F401

@@ -2130,7 +2130,9 @@ class ContinuousBatchingEngine:
             p_want = prof is not None and prof.want("prefill_chunk")
             p_dec = None
             t_call = time.perf_counter()
-            with self._ctx():
+            with jax.profiler.TraceAnnotation(
+                    "pt.engine.dispatch", program="prefill_chunk"), \
+                    self._ctx():
                 toks, caches = self._prefill_chunked()(
                     self._pb, jnp.asarray(ids, jnp.int32), caches, bt,
                     jnp.asarray(start), jnp.asarray(last_idx), sub,
@@ -2301,14 +2303,22 @@ class ContinuousBatchingEngine:
         or prompt+history for a crash-recovery replay, whose original
         TTFT and admit instant are preserved (per-request TPOT stays
         the honest wall from FIRST admission to last token, fault
-        stalls included)."""
+        stalls included). Returns the fresh admissions and the sum
+        of their submit-to-admit times, the arguments of the caller's
+        ``pt.engine.admit`` span."""
+        admitted, wait_ms = 0, 0.0
         for req, slot, n_ctx, first_dev in pending:
-            first = int(first_dev)  # scalar, not [1, bucket, vocab]
+            # a wait for the device like the step's own (the prefill
+            # program has to finish), and named like it
+            with jax.profiler.TraceAnnotation("pt.engine.sync"):
+                first = int(first_dev)  # scalar, not [1, bucket, vocab]
             now = time.perf_counter()
             fresh = req.ttft_ms is None
             if fresh:
                 req._admit_t = now
                 req.ttft_ms = (now - req._submit_t) * 1e3
+                admitted += 1
+                wait_ms += req.ttft_ms
             req.output.append(first)
             # the prefill-sampled first token counts toward the
             # flight-data token counter too (telemetry's on_admit/
@@ -2336,6 +2346,7 @@ class ContinuousBatchingEngine:
                                replayed_tokens=int(n_ctx
                                                    - req.prompt.size))
             self._maybe_finish(slot, first)
+        return {"admitted": admitted, "queue_wait_ms_sum": wait_ms}
 
     def _admit(self):
         """Blocking admission (dispatch + integrate) with the same
@@ -2345,7 +2356,9 @@ class ContinuousBatchingEngine:
         the exact fault class ``serve_recovery`` promises to survive
         would crash the idle-engine admission path."""
         try:
-            self._admit_integrate(self._admit_dispatch())
+            with jax.profiler.TraceAnnotation("pt.engine.admit") as span:
+                span.set_metadata(**self._admit_integrate(
+                    self._admit_dispatch()))
         except BaseException as e:
             if not self._recoverable(e):
                 raise
@@ -2355,7 +2368,8 @@ class ContinuousBatchingEngine:
         """``_admit_integrate`` as a recovery point: the first-token
         sync is where an async prefill failure actually lands."""
         try:
-            self._admit_integrate(pending)
+            with jax.profiler.TraceAnnotation("pt.engine.admit") as span:
+                span.set_metadata(**self._admit_integrate(pending))
         except BaseException as e:
             if not self._recoverable(e):
                 raise
@@ -2909,9 +2923,22 @@ class ContinuousBatchingEngine:
         wd = self._watchdog
         if wd is not None:
             wd.tick_begin()
-        out = self._step_impl()
-        self._tick_epilogue(wd, san, "step")
+        with jax.profiler.TraceAnnotation("pt.engine.tick") as span:
+            self._tick_args(span)
+            out = self._step_impl()
+            self._tick_epilogue(wd, san, "step")
         return out
+
+    def _tick_args(self, span):
+        """The state the tick finds (before its own admission: the
+        active slots are the ones it will decode), as the arguments of
+        its ``pt.engine.tick`` span (observability/spans.py): read
+        only while a profiler trace is running."""
+        if span.is_enabled():
+            queued, _occ, used, total = self._tel_state()
+            span.set_metadata(
+                active=int(self.active.sum()), queued=queued,
+                pages_used=int(used), pages_total=int(total))
 
     def _tick_epilogue(self, wd, san, site: str):
         """Shared post-step sequence for the step()/step_chunk()
@@ -2966,7 +2993,9 @@ class ContinuousBatchingEngine:
             prof = self._prof
             p_want = prof is not None and prof.want("decode_step")
             t_call = time.perf_counter()
-            with self._ctx():
+            with jax.profiler.TraceAnnotation(
+                    "pt.engine.dispatch", program="decode_step"), \
+                    self._ctx():
                 if self.cfg.paged:
                     state = PagedState(
                         block_tables=jnp.asarray(self.pool.block_tables),
@@ -2986,7 +3015,8 @@ class ContinuousBatchingEngine:
                 p_dec = prof.observe("decode_step", t0, t_call,
                                      t_disp, nxt)
                 self._hbm_update()
-            nxt = np.asarray(nxt)
+            with jax.profiler.TraceAnnotation("pt.engine.sync"):
+                nxt = np.asarray(nxt)
         except BaseException as e:
             if not self._recoverable(e):
                 raise
@@ -2995,20 +3025,22 @@ class ContinuousBatchingEngine:
         t_sync = time.perf_counter()
         emitted = 0
         cost_shares = [] if self._cost_enabled else None
-        for slot in range(self.cfg.max_slots):
-            if not self.active[slot]:
-                continue
-            tok = int(nxt[slot])
-            req = self._slot_req[slot]
-            req.output.append(tok)
-            self.seq_lens[slot] += 1
-            self.last_tok[slot] = tok
-            emitted += 1
-            if adv is not None:
-                adv[req.rid] = 1
-            if cost_shares is not None:
-                cost_shares.append((req, 1))
-            self._maybe_finish(slot, tok)
+        with jax.profiler.TraceAnnotation("pt.engine.emit") as span:
+            for slot in range(self.cfg.max_slots):
+                if not self.active[slot]:
+                    continue
+                tok = int(nxt[slot])
+                req = self._slot_req[slot]
+                req.output.append(tok)
+                self.seq_lens[slot] += 1
+                self.last_tok[slot] = tok
+                emitted += 1
+                if adv is not None:
+                    adv[req.rid] = 1
+                if cost_shares is not None:
+                    cost_shares.append((req, 1))
+                self._maybe_finish(slot, tok)
+            span.set_metadata(tokens=emitted)
         self._tokens_emitted += emitted
         if cost_shares:
             # attributed device wall: the measured sample when this
@@ -3142,7 +3174,9 @@ class ContinuousBatchingEngine:
             prof = self._prof
             p_want = prof is not None and prof.want("spec_verify")
             t_call = time.perf_counter()
-            with self._ctx():
+            with jax.profiler.TraceAnnotation(
+                    "pt.engine.dispatch", program="spec_verify"), \
+                    self._ctx():
                 preds, accepted, caches = self._verify()(
                     self._pb, jnp.asarray(ids, jnp.int32), caches, bt,
                     jnp.asarray(start), jnp.asarray(n_draft), sub, samp,
@@ -3164,10 +3198,12 @@ class ContinuousBatchingEngine:
                 t_admit0 = time.perf_counter()
             # admission dispatches behind the in-flight verify (stream
             # order, exactly like step_chunk's decode-chunk overlap)
-            pending = self._admit_dispatch()
+            with jax.profiler.TraceAnnotation("pt.engine.admit"):
+                pending = self._admit_dispatch()
             t_admit = time.perf_counter()
-            preds_np = np.asarray(preds)  # ONE sync for S tokens/slot
-            acc_np = np.asarray(accepted)
+            with jax.profiler.TraceAnnotation("pt.engine.sync"):
+                preds_np = np.asarray(preds)  # ONE sync, S tokens/slot
+                acc_np = np.asarray(accepted)
         except BaseException as e:
             if not self._recoverable(e):
                 raise
@@ -3177,37 +3213,39 @@ class ContinuousBatchingEngine:
         emitted = 0
         proposed_tot = accepted_tot = 0
         cost_shares = [] if self._cost_enabled else None
-        for slot in range(cfg.max_slots):
-            req = chunk_reqs.get(slot)
-            if req is None or self._slot_req.get(slot) is not req:
-                continue  # finished at sync, or preempted + re-claimed
-            n = int(n_draft[slot])
-            a = min(int(acc_np[slot]), n)
-            toks = [int(ids[slot, 1 + j]) for j in range(a)]
-            toks.append(int(preds_np[slot, a]))
-            slot_emitted = 0
-            for tok in toks:
-                if req.done:
-                    break  # EOS mid-chain: later tokens discarded
-                req.output.append(tok)
-                self.seq_lens[slot] += 1
-                self.last_tok[slot] = tok
-                emitted += 1
-                slot_emitted += 1
-                if adv is not None:
-                    adv[req.rid] = adv.get(req.rid, 0) + 1
-                self._maybe_finish(slot, tok)
-            if cost_shares is not None and slot_emitted:
-                cost_shares.append((req, slot_emitted))
-            if spec_by_rid is not None and n:
-                spec_by_rid[req.rid] = [n, a]
-            if n:
-                req._spec_proposed += n
-                req._spec_accepted += a
-                proposed_tot += n
-                accepted_tot += a
-                if self._tel is not None:
-                    self._tel.on_spec_slot(n, a)
+        with jax.profiler.TraceAnnotation("pt.engine.emit") as span:
+            for slot in range(cfg.max_slots):
+                req = chunk_reqs.get(slot)
+                if req is None or self._slot_req.get(slot) is not req:
+                    continue  # finished at sync, or preempted+re-claimed
+                n = int(n_draft[slot])
+                a = min(int(acc_np[slot]), n)
+                toks = [int(ids[slot, 1 + j]) for j in range(a)]
+                toks.append(int(preds_np[slot, a]))
+                slot_emitted = 0
+                for tok in toks:
+                    if req.done:
+                        break  # EOS mid-chain: later tokens discarded
+                    req.output.append(tok)
+                    self.seq_lens[slot] += 1
+                    self.last_tok[slot] = tok
+                    emitted += 1
+                    slot_emitted += 1
+                    if adv is not None:
+                        adv[req.rid] = adv.get(req.rid, 0) + 1
+                    self._maybe_finish(slot, tok)
+                if cost_shares is not None and slot_emitted:
+                    cost_shares.append((req, slot_emitted))
+                if spec_by_rid is not None and n:
+                    spec_by_rid[req.rid] = [n, a]
+                if n:
+                    req._spec_proposed += n
+                    req._spec_accepted += a
+                    proposed_tot += n
+                    accepted_tot += a
+                    if self._tel is not None:
+                        self._tel.on_spec_slot(n, a)
+            span.set_metadata(tokens=emitted)
         self.spec_stats["verify_calls"] += 1
         self.spec_stats["proposed"] += proposed_tot
         self.spec_stats["accepted"] += accepted_tot
@@ -3291,8 +3329,10 @@ class ContinuousBatchingEngine:
         wd = self._watchdog
         if wd is not None:
             wd.tick_begin()
-        out = self._step_chunk_impl(max_chunk)
-        self._tick_epilogue(wd, san, "step_chunk")
+        with jax.profiler.TraceAnnotation("pt.engine.tick") as span:
+            self._tick_args(span)
+            out = self._step_chunk_impl(max_chunk)
+            self._tick_epilogue(wd, san, "step_chunk")
         return out
 
     def _corrupt_point(self):
@@ -3434,7 +3474,9 @@ class ContinuousBatchingEngine:
             prof = self._prof
             p_want = prof is not None and prof.want("decode_chunk")
             t_call = time.perf_counter()
-            with self._ctx():
+            with jax.profiler.TraceAnnotation(
+                    "pt.engine.dispatch", program="decode_chunk"), \
+                    self._ctx():
                 toks_all, caches, _ = self._decode_n()(
                     self._pb, toks, caches, lens, act,
                     jnp.asarray(budget), bt, sub, samp, K, use_samp)
@@ -3458,9 +3500,11 @@ class ContinuousBatchingEngine:
             # admission dispatches behind the in-flight chunk (stream
             # order: chunk → prefills → inserts into the chunk's
             # output caches)
-            pending = self._admit_dispatch()
+            with jax.profiler.TraceAnnotation("pt.engine.admit"):
+                pending = self._admit_dispatch()
             t_admit = time.perf_counter()
-            toks_np = np.asarray(toks_all)  # ONE sync for K tokens
+            with jax.profiler.TraceAnnotation("pt.engine.sync"):
+                toks_np = np.asarray(toks_all)  # ONE sync for K tokens
         except BaseException as e:
             if not self._recoverable(e):
                 raise
@@ -3478,28 +3522,30 @@ class ContinuousBatchingEngine:
         emitted = 0
         cost_by_slot: Dict[int, list] = {} if self._cost_enabled \
             else None
-        for k in range(K):
-            for slot in range(self.cfg.max_slots):
-                # the slot advances only while its DISPATCH-TIME
-                # occupant still owns it: gone = finished (EOS) at an
-                # earlier k of this same chunk; replaced = preempted
-                # mid-chunk and re-claimed by this tick's admission —
-                # either way the chunk's remaining tokens are
-                # discarded, exactly like cancel's
-                req = chunk_reqs.get(slot)
-                if (req is None or k >= budget[slot]
-                        or self._slot_req.get(slot) is not req):
-                    continue
-                tok = int(toks_np[k, slot])
-                req.output.append(tok)
-                self.seq_lens[slot] += 1
-                self.last_tok[slot] = tok
-                emitted += 1
-                if adv is not None:
-                    adv[req.rid] = adv.get(req.rid, 0) + 1
-                if cost_by_slot is not None:
-                    cost_by_slot.setdefault(slot, [req, 0])[1] += 1
-                self._maybe_finish(slot, tok)
+        with jax.profiler.TraceAnnotation("pt.engine.emit") as span:
+            for k in range(K):
+                for slot in range(self.cfg.max_slots):
+                    # the slot advances only while its DISPATCH-TIME
+                    # occupant still owns it: gone = finished (EOS) at
+                    # an earlier k of this same chunk; replaced =
+                    # preempted mid-chunk and re-claimed by this tick's
+                    # admission — either way the chunk's remaining
+                    # tokens are discarded, exactly like cancel's
+                    req = chunk_reqs.get(slot)
+                    if (req is None or k >= budget[slot]
+                            or self._slot_req.get(slot) is not req):
+                        continue
+                    tok = int(toks_np[k, slot])
+                    req.output.append(tok)
+                    self.seq_lens[slot] += 1
+                    self.last_tok[slot] = tok
+                    emitted += 1
+                    if adv is not None:
+                        adv[req.rid] = adv.get(req.rid, 0) + 1
+                    if cost_by_slot is not None:
+                        cost_by_slot.setdefault(slot, [req, 0])[1] += 1
+                    self._maybe_finish(slot, tok)
+            span.set_metadata(tokens=emitted)
         self._tokens_emitted += emitted
         if cost_by_slot:
             self._attribute_cost(

@@ -138,13 +138,21 @@ class LlamaAttention(Layer):
                 cache_index=None):
         cfg = self.config
         b, s, _ = x.shape
-        q = self.q_proj(x).reshape(b, s, cfg.num_attention_heads, cfg.head_dim)
-        k = self.k_proj(x).reshape(b, s, cfg.num_key_value_heads, cfg.head_dim)
-        v = self.v_proj(x).reshape(b, s, cfg.num_key_value_heads, cfg.head_dim)
-        # heads are tp-sharded; keep [b, s, h_tp, d] layout explicit
-        q = shard_activation(q, ("dp", "fsdp"), "sep", "tp", None)
-        k = shard_activation(k, ("dp", "fsdp"), "sep", "tp", None)
-        v = shard_activation(v, ("dp", "fsdp"), "sep", "tp", None)
+        # device phases are named scopes (observability/spans.py) and
+        # never enclose an attention kernel's call site: a kernel's
+        # event is named from its scope path, and the benchmark matches
+        # the names the kernels have
+        with jax.named_scope("attn_in"):
+            q = self.q_proj(x).reshape(
+                b, s, cfg.num_attention_heads, cfg.head_dim)
+            k = self.k_proj(x).reshape(
+                b, s, cfg.num_key_value_heads, cfg.head_dim)
+            v = self.v_proj(x).reshape(
+                b, s, cfg.num_key_value_heads, cfg.head_dim)
+            # heads are tp-sharded; keep [b, s, h_tp, d] layout explicit
+            q = shard_activation(q, ("dp", "fsdp"), "sep", "tp", None)
+            k = shard_activation(k, ("dp", "fsdp"), "sep", "tp", None)
+            v = shard_activation(v, ("dp", "fsdp"), "sep", "tp", None)
         if kv_cache is not None:
             from ..distributed.sharding import current_mesh
             from ..inference.paged import (PagedLayerCache, QuantizedKV,
@@ -327,7 +335,8 @@ class LlamaAttention(Layer):
         else:
             from ..distributed.sharding import current_mesh
 
-            q, k = apply_rope(q, k, cos, sin, position_ids)
+            with jax.named_scope("attn_in"):
+                q, k = apply_rope(q, k, cos, sin, position_ids)
             mesh = current_mesh()
             sep = mesh.shape.get("sep", 1) if mesh is not None else 1
             if sep > 1 and cfg.sep_attention == "ring":
@@ -349,8 +358,9 @@ class LlamaAttention(Layer):
                     q, k, v, is_causal=True, training=self.training
                 )
             new_cache = None
-        out = out.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
-        out = self.o_proj(out)
+        with jax.named_scope("attn_out"):
+            out = out.reshape(b, s, cfg.num_attention_heads * cfg.head_dim)
+            out = self.o_proj(out)
         return (out, new_cache) if kv_cache is not None else out
 
 
@@ -388,7 +398,8 @@ class LlamaDecoderLayer(Layer):
     def forward(self, x, cos, sin, position_ids=None, kv_cache=None,
                 cache_index=None):
         residual = x
-        h = self.input_layernorm(x)
+        with jax.named_scope("attn_in"):
+            h = self.input_layernorm(x)
         if kv_cache is not None:
             h, new_cache = self.self_attn(
                 h, cos, sin, position_ids, kv_cache, cache_index
@@ -396,11 +407,13 @@ class LlamaDecoderLayer(Layer):
         else:
             h = self.self_attn(h, cos, sin, position_ids)
             new_cache = None
-        x = residual + h
-        residual = x
-        h = self.post_attention_layernorm(x)
-        h = self.mlp(h)
-        x = residual + h
+        with jax.named_scope("attn_out"):
+            x = residual + h
+        with jax.named_scope("mlp"):
+            residual = x
+            h = self.post_attention_layernorm(x)
+            h = self.mlp(h)
+            x = residual + h
         return (x, new_cache) if kv_cache is not None else x
 
 
@@ -427,8 +440,9 @@ class LlamaModel(Layer):
     def forward(self, input_ids, position_ids=None, kv_caches=None,
                 cache_index=None):
         cfg = self.config
-        h = self.embed_tokens(input_ids)
-        h = shard_activation(h, ("dp", "fsdp"), "sep", None)
+        with jax.named_scope("embed"):
+            h = self.embed_tokens(input_ids)
+            h = shard_activation(h, ("dp", "fsdp"), "sep", None)
         cos = self._buffers["rope_cos"]
         sin = self._buffers["rope_sin"]
         new_caches = [] if kv_caches is not None else None
@@ -446,7 +460,8 @@ class LlamaModel(Layer):
                 h = jax.checkpoint(fn, policy=policy)(h)
             else:
                 h = layer(h, cos, sin, position_ids)
-        h = self.norm(h)
+        with jax.named_scope("head_loss"):
+            h = self.norm(h)
         return (h, new_caches) if kv_caches is not None else h
 
 
@@ -482,6 +497,10 @@ class LlamaForCausalLM(Layer):
         hidden = self.model(input_ids, position_ids)
         if labels is None:
             return self.logits(hidden)
+        with jax.named_scope("head_loss"):
+            return self._lm_loss(hidden, labels)
+
+    def _lm_loss(self, hidden, labels):
         shift_labels = labels[:, 1:]
         if self.config.fused_head_loss_chunk:
             # chunked head+CE: math-identical to the full-logits path

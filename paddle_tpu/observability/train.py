@@ -8,10 +8,10 @@ update, ``device.memory_stats()`` is read, and the anomaly watchdog
 runs. Non-sampled steps perform NO ``device_get``/host sync beyond what
 the caller does with the returned loss.
 
-Rate metrics (tokens/s, MFU) are averaged over the SAMPLING INTERVAL,
+The rate metric (tokens/s) is averaged over the SAMPLING INTERVAL,
 measured between post-fetch sync points: per-step wall clock only times
 the async *dispatch*, which can run orders of magnitude ahead of the
-device and would report impossible throughput (MFU > 1). The interval
+device and would report impossible throughput. The interval
 endpoints sit right after ``float(loss)`` — a real completion fence —
 so the rate is device-true in steady state. The first interval includes
 compile time and undershoots; that is the honest direction.
@@ -20,36 +20,16 @@ compile time and undershoots; that is the honest direction.
 from __future__ import annotations
 
 import time
-from typing import Callable, Optional
+from typing import Optional
+
+import jax
 
 from .. import flags
 from .recorder import AnomalyWatchdog, FlightRecorder
 from .registry import exp_buckets, get_registry
 
-# device_kind -> peak bf16 FLOP/s per chip (public spec sheets); the
-# MFU estimate is best-effort — unknown kinds (CPU CI) report no MFU
-_PEAK_BF16_FLOPS = {
-    "TPU v4": 275e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v6e": 918e12,
-    "TPU v6 lite": 918e12,
-}
-
-
-def _peak_flops() -> Optional[float]:
-    import jax
-
-    kind = getattr(jax.devices()[0], "device_kind", "")
-    for k, v in _PEAK_BF16_FLOPS.items():
-        if k.lower() in str(kind).lower():
-            return v
-    return None
-
 
 def _memory_stats() -> Optional[dict]:
-    import jax
-
     try:
         stats = jax.devices()[0].memory_stats()
     except Exception:
@@ -95,15 +75,9 @@ class TrainTelemetry:
             "pt_train_grad_norm", "last sampled global gradient norm")
         self._tps = reg.gauge(
             "pt_train_tokens_per_sec", "sampled-step token throughput")
-        self._mfu = reg.gauge(
-            "pt_train_mfu", "estimated model FLOPs utilization (0-1)")
         self._mem = reg.gauge(
             "pt_device_memory_bytes", "device memory_stats()",
             labels=("stat",))
-        self._flops_per_step: Optional[float] = None
-        self._flops_known = False
-        self._peak = None
-        self._peak_known = False
         # sampling-interval accumulators (rates are computed between
         # post-fetch sync points, not from per-step dispatch wall time)
         self._interval_t0 = time.perf_counter()
@@ -117,8 +91,7 @@ class TrainTelemetry:
         return step % self.sample_every == 0
 
     def on_step(self, step: int, loss, grad_norm, tokens: int,
-                wall_s: float,
-                flops_getter: Optional[Callable[[], Optional[float]]] = None):
+                wall_s: float):
         """``loss``/``grad_norm`` are device scalars (async futures) —
         they are fetched ONLY on sampled steps."""
         wall_ms = wall_s * 1e3
@@ -134,6 +107,14 @@ class TrainTelemetry:
             self.recorder.record(**rec)
             return None
         # ---- sampled step: host sync on the two scalars ----
+        # (a span on the profiler's clock: the fetch waits for every
+        # step dispatched ahead, and a trace should say so by name)
+        with jax.profiler.TraceAnnotation(
+                "pt.train.sample_fetch",
+                interval_steps=self._interval_steps):
+            return self._sample(step, loss, grad_norm, rec)
+
+    def _sample(self, step: int, loss, grad_norm, rec: dict):
         loss_f = float(loss) if loss is not None else None
         gnorm_f = float(grad_norm) if grad_norm is not None else None
         # the float() above fenced this step's completion: NOW is a
@@ -150,11 +131,6 @@ class TrainTelemetry:
             tps = self._interval_tokens / interval_s
             self._tps.set(tps)
             rec["tokens_per_sec"] = round(tps, 1)
-        mfu = self._mfu_estimate(
-            interval_s / max(self._interval_steps, 1), flops_getter)
-        if mfu is not None:
-            self._mfu.set(mfu)
-            rec["mfu_est"] = round(mfu, 4)
         self._interval_t0 = now
         self._interval_tokens = 0
         self._interval_steps = 0
@@ -168,29 +144,6 @@ class TrainTelemetry:
         self.samples += 1
         self.last_sample = rec
         return self.watchdog.check(step, loss_f, gnorm_f)
-
-    def _mfu_estimate(self, wall_s: float, flops_getter) -> Optional[float]:
-        # peak first: on devices with no spec-sheet entry (CPU CI) MFU
-        # is undefined, so never pay the FLOPs probe (an AOT
-        # lower+compile) there
-        if not self._peak_known:
-            self._peak_known = True
-            try:
-                self._peak = _peak_flops()
-            except Exception:
-                self._peak = None
-        if not self._peak:
-            return None
-        if not self._flops_known:
-            self._flops_known = True
-            if flops_getter is not None:
-                try:
-                    self._flops_per_step = flops_getter()
-                except Exception:
-                    self._flops_per_step = None
-        if not self._flops_per_step or wall_s <= 0:
-            return None
-        return self._flops_per_step / wall_s / self._peak
 
 
 def record_scalars(prefix: str, logs: Optional[dict], step=None):

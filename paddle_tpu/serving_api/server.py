@@ -41,6 +41,8 @@ import queue
 import threading
 from typing import Dict, Optional
 
+import jax
+
 from .. import flags
 from ..inference.router import EngineRouter
 from ..inference.serving import metrics_http_get
@@ -270,7 +272,8 @@ class ServingFrontDoor:
                     # idle: sleep until a submit/cancel wakes us (the
                     # timeout keeps deadline expiry ticking for queued
                     # requests even with no new arrivals)
-                    self._wake.wait(timeout=0.02)
+                    with jax.profiler.TraceAnnotation("pt.engine.wait"):
+                        self._wake.wait(timeout=0.02)
                     self._wake.clear()
         except BaseException as e:  # noqa: BLE001
             self._dead = f"{type(e).__name__}: {e}"

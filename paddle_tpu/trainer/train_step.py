@@ -239,8 +239,6 @@ class TrainStep:
                 telemetry
                 if isinstance(telemetry, observability.TrainTelemetry)
                 else observability.TrainTelemetry())
-        self._flops_per_step = None
-        self._flops_probed = False
 
         model_ref = model
         loss_ref = loss_fn
@@ -263,8 +261,9 @@ class TrainStep:
                 # may rematerialize the casts under memory pressure
                 # instead of keeping 2 bytes/param alive across the step
                 params = dict(params)
-                for n, dt in master_dtypes.items():
-                    params[n] = opt_state["master"][n].astype(dt)
+                with jax.named_scope("master_cast"):
+                    for n, dt in master_dtypes.items():
+                        params[n] = opt_state["master"][n].astype(dt)
             if merge_k <= 1:
                 loss, grads = jax.value_and_grad(loss_of)(
                     params, batch, rng)
@@ -318,10 +317,13 @@ class TrainStep:
             if emit_gnorm:
                 # pre-clip global grad norm, fp32 accumulation — a
                 # single reduction pass, negligible next to fwd+bwd
-                gnorm = jnp.sqrt(sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree_util.tree_leaves(grads)))
-            new_params, new_state = optimizer.update(grads, opt_state, params)
+                with jax.named_scope("grad_norm"):
+                    gnorm = jnp.sqrt(sum(
+                        jnp.sum(jnp.square(g.astype(jnp.float32)))
+                        for g in jax.tree_util.tree_leaves(grads)))
+            with jax.named_scope("optimizer"):
+                new_params, new_state = optimizer.update(
+                    grads, opt_state, params)
             if master_dtypes:
                 # the low-precision copies are not carried: drop them so
                 # XLA dead-code-eliminates the cast-back
@@ -395,14 +397,25 @@ class TrainStep:
                 "TrainStep(abstract=True) holds no real parameters; "
                 "use lower() for AOT compilation, or rebuild without "
                 "abstract for execution")
+        # spans on the profiler's clock (observability/spans.py): a
+        # flag check each when no trace is running
+        tokens = _batch_tokens(batch)
+        with jax.profiler.StepTraceAnnotation(
+                "pt.train.step", step_num=self.step_count + 1,
+                tokens=tokens):
+            return self._run(batch, sharded, tokens)
+
+    def _run(self, batch: Dict, sharded: bool, tokens: int):
         tel = self.telemetry
         bench = bool(flags.flag("benchmark"))
         t0 = time.perf_counter() if tel is not None or bench else 0.0
         if not sharded:
-            batch = self.shard_batch(batch)
+            with jax.profiler.TraceAnnotation("pt.train.shard_batch"):
+                batch = self.shard_batch(batch)
         self._rng_key, sub = jax.random.split(self._rng_key)
         gnorm = None
-        with mesh_context(self.mesh):
+        with jax.profiler.TraceAnnotation("pt.train.dispatch"), \
+                mesh_context(self.mesh):
             if self._emit_gnorm:
                 self.params, self.opt_state, loss, gnorm = self._step(
                     self.params, self.opt_state, batch, sub
@@ -456,43 +469,23 @@ class TrainStep:
             # loss/gnorm stay async device futures unless this is a
             # sampled step (TrainTelemetry fetches them only then)
             tel.on_step(
-                self.step_count, loss, gnorm,
-                tokens=_batch_tokens(batch),
-                wall_s=time.perf_counter() - t0,
-                flops_getter=lambda: self._cost_flops(batch, sub))
-        if not self._master_dtypes:
-            self.sync_to_model()
-        else:
-            # master_only: skip the write-back ONLY for master-backed
-            # params (re-materializing them defeats the mode; call
-            # sync_to_model() explicitly before eval/export). Carried
-            # params (fp32, no master) were donated and MUST be rebound
-            # or their Parameters point at deleted buffers.
-            for n in self.params:
-                self._param_objs[n].value = self.params[n]
+                self.step_count, loss, gnorm, tokens=tokens,
+                wall_s=time.perf_counter() - t0)
+        with jax.profiler.TraceAnnotation("pt.train.sync_to_model"):
+            if not self._master_dtypes:
+                self.sync_to_model()
+            else:
+                # master_only: skip the write-back ONLY for
+                # master-backed params (re-materializing them defeats
+                # the mode; call sync_to_model() explicitly before
+                # eval/export). Carried params (fp32, no master) were
+                # donated and MUST be rebound or their Parameters point
+                # at deleted buffers.
+                for n in self.params:
+                    self._param_objs[n].value = self.params[n]
         if self.optimizer._lr_scheduler is not None:
             self.optimizer._lr_scheduler.step()
         return loss
-
-    def _cost_flops(self, batch, rng):
-        """Per-step FLOPs from XLA cost analysis, probed once (the
-        lowering retrace + compile-cache hit costs one sampled step,
-        never the steady loop); None when the backend can't say."""
-        if self._flops_probed:
-            return self._flops_per_step
-        self._flops_probed = True
-        try:
-            with mesh_context(self.mesh):
-                ca = self._step.lower(
-                    self.params, self.opt_state, batch, rng
-                ).compile().cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            f = (ca or {}).get("flops")
-            self._flops_per_step = float(f) if f and f > 0 else None
-        except Exception:
-            self._flops_per_step = None
-        return self._flops_per_step
 
     def _materialized_params(self):
         """Full param dict at model dtype; in master_only mode the
