@@ -13,23 +13,52 @@ Design notes (per /opt/skills/guides/pallas_guide.md):
   - GQA is native: q is viewed as [b*hk, rep, sq, d] and k/v as
     [b*hk, sk, d]; the kv block index map ignores the rep dimension, so
     kv is NEVER materialized rep times in HBM (no jnp.repeat).
-  - causal masking prunes fully-masked k-blocks: the kv index map clamps
-    the block index at the diagonal (a revisited block issues no DMA) and
-    the kernel body is skipped under pl.when, so causal runs ~half the
-    FLOPs and ~half the kv HBM traffic. The mask itself is applied only
-    in diagonal-straddling blocks.
-  - backward is two passes (flash-v2 style): a dq kernel with k innermost
-    accumulating dq in VMEM scratch, and a dk/dv kernel with (rep, q)
-    innermost accumulating dk/dv in VMEM scratch — no [bh, n_kb, sq, d]
-    HBM partials anywhere; every gradient's HBM footprint equals its
-    final size. The dk/dv pass also performs the GQA head-group reduction
-    in-register (sum over rep lands in the same scratch accumulator).
+  - causal pruning works at two grains. Grid tiles wholly above the
+    diagonal (or behind the sliding window) are skipped: the index maps
+    clamp their block (a revisited block issues no DMA) and the body
+    sits under pl.when. A square tile the diagonal or the window's edge
+    crosses is worked as a statically unrolled list of 128-row strips,
+    each against the one run of k columns it can see (_tile_strips):
+    what lies above the diagonal inside the tile is not computed, and
+    the iota mask is built only over the 128 columns the diagonal
+    crosses. causal_live_share() is the area computed over the square:
+    at s = 2048 in 1024-blocks 0.53 where the triangle is 0.50 and
+    whole tiles were 0.75; 0.52 at s = 4096, 0.51 at 8192. Tiles with
+    nothing to prune run whole, as one pair of large matmuls; so do
+    non-causal calls, non-square blocks and blocks of 128-256.
+  - backward is ONE kernel body (_bwd_kernel). While one kv group's dq
+    accumulator fits _FUSED_DQ_VMEM_BUDGET it runs fused: s/p/dp/ds once
+    per strip for all three gradients, dk/dv accumulating in VMEM over
+    (rep, q blocks) (which also sums the GQA group) and dq accumulating
+    in a float32 VMEM scratch over the k blocks, written once in the
+    input dtype. Beyond the budget it runs twice, a dq pass with k
+    innermost and a dk/dv pass. No gradient has HBM partials; every
+    gradient's HBM footprint equals its final size. delta =
+    rowsum(o · dO) is computed in the kernel from the o and dO blocks.
   - varlen/packed sequences via segment ids (parity with
     flash_attn_varlen): tokens attend only within equal segment id;
     padding can be given a sentinel segment.
   - blocks are MXU-aligned; all matmuls request fp32 accumulation via
     preferred_element_type; per-row stats are carried lane-broadcast
-    ([q_block, 128]) to keep Mosaic layouts trivial.
+    ([q_block, 128]) to keep Mosaic layouts trivial. lse is held from
+    forward to backward compact ([g, rep, sq]) and broadcast again there.
+
+Readings on one TPU v5e (PR 30's chip runs, PERF.md section 6: the
+kernels alone at the benchmark cell's shape, b 2, s 2048, 32 heads over
+8, d 128, bf16, causal; ms a call, forward / backward; the required
+work at the chip's 197 TFLOP/s is 0.35 / 0.87):
+  before PR 30: whole 1024-tiles, a mask on every tile   0.86 / 1.47
+      (and 0.18 more summing float32 dq partials from HBM)
+  whole tiles, a static mask on the diagonal tiles only  0.76 / 1.44
+  grid blocks of 512 and of 256 instead (wall time of forward and of
+      forward + backward, 1.14 and 3.43 at 1024): 1.76, 4.36; 3.14, 8.66
+  4 to 8 strips a tile, written strip by strip           0.87-0.95 / 1.20-1.33
+  8 strips of 128 rows, every score matmul first (forward), the next
+      strip's matmuls issued ahead (backward): this file 0.68 / 1.09
+  16 strips of 64 rows, same orders                      0.81-0.84 / 1.33-1.54
+Mosaic keeps to program order, so HOW the strips are written decides
+whether a strip's VPU work runs under its neighbours' matmuls; under
+128 rows a strip loses more at the MXU than it prunes.
 """
 
 from __future__ import annotations
@@ -42,26 +71,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# v5e-swept defaults (876M bench shape, b4 x s2048 x h24 x d128, causal):
-# 256/256 ran fwd 5.36ms / fwd+bwd 13.9ms; 512/1024 2.16/6.49;
-# 1024/1024 1.97/6.20 — 2.2x over 256-blocks (grid-step overhead
-# dominates small tiles; each 256x256 tile is ~0.2us of MXU work) and
-# ahead of the jax-bundled TPU flash kernel's 1.31/6.95 on fwd+bwd.
-# 2048-size blocks fail to compile (VMEM). Shorter sequences clamp in
-# _fold, so the large default is safe for every caller.
+# Blocks are the GRID tiles: few grid steps and large DMAs (2048-blocks
+# do not fit VMEM). The causal triangle is cut finer than that inside
+# the kernels, see _tile_strips.
 DEFAULT_Q_BLOCK = 1024
 DEFAULT_K_BLOCK = 1024
 NEG_INF = -1e30
 LANES = 128
+# rows of the strips a square causal grid tile is worked in, and the
+# width of the column ranges its mask is built over
+_STRIP = LANES
+# what Mosaic grants a kernel on a v5e without being asked: every
+# kernel's per-tile blocks and temporaries fit it at the default blocks
+# (tests/test_chip_compile.py holds that)
+_TILE_VMEM_BYTES = 16 << 20
+# the fused backward keeps one kv group's whole dq on chip (a float32
+# accumulator and the double-buffered output block); past this budget
+# the two-pass backward runs. 16 MiB is s = 4096 at rep 4, d 128, bf16.
+_FUSED_DQ_VMEM_BUDGET = 16 << 20
 
 
 def _interpret() -> bool:
     # run the kernel in interpreter mode off-TPU (CPU CI parity tests)
     return jax.default_backend() != "tpu"
-
-
-def _params(*parallel_then_arbitrary: str):
-    return pltpu.CompilerParams(dimension_semantics=parallel_then_arbitrary)
 
 
 def _causal_j_max(i: int, q_block: int, k_block: int):
@@ -89,40 +121,170 @@ def _window_i_max(j: int, q_block: int, k_block: int, window: int):
     return ((j + 1) * k_block - 1 + window - 1) // q_block
 
 
-def _block_mask(s, qb_idx, kb_idx, q_block, k_block, causal, q_seg, k_seg,
-                window=0):
-    """Apply causal/sliding-window/segment masking to a
-    [q_block, k_block] score tile.
+def _strip_rows(q_block: int, k_block: int, causal: bool = True) -> int:
+    """Rows of the strips a causal grid tile is worked in, 0 where tiles
+    are worked whole: non-causal calls have no diagonal, non-square
+    tiles meet it at an offset only the grid step knows, and under four
+    strips a block (128-256) is already as fine as the pruning gets."""
+    if causal and q_block == k_block and q_block >= 4 * _STRIP:
+        return _STRIP
+    return 0
 
-    Only called where it can matter: causal masking only on
-    diagonal-straddling blocks (callers prune/skip fully-masked blocks).
-    ``window`` > 0 (Mistral-style local attention, parity: flash_attn
-    window_size) additionally masks keys more than window−1 positions
-    behind the query.
-    """
-    mask = None
-    if causal or window:
-        q_pos = qb_idx * q_block + jax.lax.broadcasted_iota(
-            jnp.int32, (q_block, k_block), 0
-        )
-        k_pos = kb_idx * k_block + jax.lax.broadcasted_iota(
-            jnp.int32, (q_block, k_block), 1
-        )
-        mask = q_pos >= k_pos
+
+def _tile_strips(delta: int, blk: int, rows: int, window: int):
+    """Static work list of the square causal grid tile ``delta`` block
+    rows below the diagonal: ``((r0, r1, c0, c1, masks), ...)``, one
+    entry for each strip of ``rows`` q rows (0: the tile whole) with the
+    one run of k columns [c0, c1) it has to visit: the live columns of a
+    row are a band, so a strip's are contiguous, and what lies wholly
+    above the diagonal or behind the window is not computed at all.
+    ``masks`` lists the column ranges of that run the diagonal or the
+    window's edge crosses, as (ca, cb, off) relative to the run, ``off``
+    = q_pos − k_pos at the range's corner; the columns between them take
+    no mask. A tile with nothing to prune comes back as one whole-tile
+    strip (one pair of large matmuls), a dead tile as ()."""
+    h = rows or blk  # strips and mask ranges are h x h squares
+    out = []
+    for r0 in range(0, blk, h):
+        run, masks = [], []
+        for ca in range(0, blk, h):
+            off = delta * blk + r0 - ca
+            lo, hi = off - (h - 1), off + (h - 1)
+            if hi < 0 or (window and lo >= window):
+                continue
+            run.append(ca)
+            if lo < 0 or (window and hi >= window):
+                masks.append((ca, ca + h, off))
+        if run:
+            c0 = run[0]
+            out.append((r0, r0 + h, c0, run[-1] + h, tuple(
+                (ca - c0, cb - c0, off) for ca, cb, off in masks)))
+    if len(out) == blk // h and all(
+            (c0, c1, m) == (0, blk, ()) for _, _, c0, c1, m in out):
+        return ((0, blk, 0, blk, ()),)
+    return tuple(out)
+
+
+def causal_live_share(sq: int, sk: int, q_block: int, k_block: int,
+                      rows: int, causal: bool = True, window: int = 0):
+    """Area of the score square the kernels compute, over sq·sk, when
+    square causal tiles are worked in strips of ``rows`` (0: whole grid
+    tiles). A trace-time constant; the causal triangle itself is
+    (sq + 1) / 2sq. s = 2048 in 1024-blocks: 0.75 whole (3 of 4 tiles),
+    0.53125 in 128-row strips, against 0.50024 required."""
+    if not causal:
+        return 1.0
+    live = 0
+    for i in range(sq // q_block):
+        for j in range(sk // k_block):
+            if q_block == k_block:
+                live += sum((r1 - r0) * (c1 - c0)
+                            for r0, r1, c0, c1, _ in _tile_strips(
+                                i - j, q_block, rows, window))
+                continue
+            lo = i * q_block - (j + 1) * k_block + 1
+            hi = (i + 1) * q_block - 1 - j * k_block
+            if hi >= 0 and not (window and lo >= window):
+                live += q_block * k_block
+    return live / (sq * sk)
+
+
+def _tile_cases(i, j, n_qb, n_kb, q_block, k_block, causal, window):
+    """The ways grid tile (i, j) is worked, as (condition, strips): the
+    condition is on the program ids (None: always), the strips are
+    _tile_strips' static work list. Square causal tiles differ only by
+    i − j, so each distinct work list is unrolled once; steps no
+    condition admits (above the diagonal, behind the window) do nothing,
+    and the index maps clamp their block so they issue no DMA either.
+    A non-square causal tile is worked whole under a mask whose offset
+    is the step's own."""
+    if not causal:
+        return [(None, ((0, q_block, 0, k_block, ()),))]
+    if q_block != k_block:
+        live = j <= _causal_j_max(i, q_block, k_block)
         if window:
-            mask = jnp.logical_and(mask, q_pos - k_pos < window)
+            live = jnp.logical_and(
+                live, j >= _window_j_min(i, q_block, k_block, window))
+        off = i * q_block - j * k_block
+        return [(live, ((0, q_block, 0, k_block, ((0, k_block, off),)),))]
+    rows = _strip_rows(q_block, k_block)
+    runs = []  # [first delta, last delta, strips]
+    for delta in range(1 - n_kb, n_qb):
+        strips = _tile_strips(delta, q_block, rows, window)
+        if runs and runs[-1][1] == delta - 1 and runs[-1][2] == strips:
+            runs[-1][1] = delta
+        elif strips:
+            runs.append([delta, delta, strips])
+    return [(jnp.logical_and(i - j >= lo, i - j <= hi), strips)
+            for lo, hi, strips in runs]
+
+
+def _for_tile_cases(cases, work):
+    for cond, strips in cases:
+        if cond is None:
+            work(strips)
+        else:
+            pl.when(cond)(functools.partial(work, strips))
+
+
+def _clamp_j(i, j, q_block, k_block, causal, window):
+    """kv block the index maps fetch at step (i, j): pruned steps
+    revisit a live block, which issues no DMA."""
+    if causal:
+        j = jnp.minimum(j, _causal_j_max(i, q_block, k_block))
+    if window:
+        j = jnp.maximum(j, _window_j_min(i, q_block, k_block, window))
+    return j
+
+
+def _clamp_i(i, j, q_block, k_block, causal, window):
+    """q block fetched at step (i, j) where q is the streamed side."""
+    if causal:
+        i = jnp.maximum(i, _causal_i_min(j, q_block, k_block))
+    if window:
+        i = jnp.minimum(i, _window_i_max(j, q_block, k_block, window))
+    return i
+
+
+def _scores(q, k, sm_scale, masks, window, q_seg, k_seg):
+    """Scaled q·kᵀ for one strip's run, float32, with what is masked set
+    to NEG_INF. ``masks`` are the column ranges the positional mask
+    touches (_tile_strips); the others pass through. ``window`` > 0
+    (Mistral-style local attention, parity: flash_attn window_size) also
+    masks keys more than window−1 positions behind the query; segment
+    ids mask the whole run."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * sm_scale
+
+    def masked(part, off):
+        diff = (off + jax.lax.broadcasted_iota(jnp.int32, part.shape, 0)
+                - jax.lax.broadcasted_iota(jnp.int32, part.shape, 1))
+        keep = diff >= 0
+        if window:
+            keep = jnp.logical_and(keep, diff < window)
+        return jnp.where(keep, part, NEG_INF)
+
+    if masks:
+        parts, at = [], 0
+        for ca, cb, off in masks:
+            if ca > at:
+                parts.append(s[:, at:ca])
+            parts.append(masked(s[:, ca:cb], off))
+            at = cb
+        if at < s.shape[1]:
+            parts.append(s[:, at:])
+        s = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
     if q_seg is not None:
-        seg = q_seg == k_seg  # [q_block, 1] == [1, k_block] -> broadcast
-        mask = seg if mask is None else jnp.logical_and(mask, seg)
-    if mask is not None:
-        s = jnp.where(mask, s, NEG_INF)
+        # [rows, 1] == [1, cols] -> broadcast
+        s = jnp.where(q_seg == k_seg, s, NEG_INF)
     return s
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
-def _fwd_kernel(*refs, sm_scale, causal, q_block, k_block, n_kb,
+def _fwd_kernel(*refs, sm_scale, causal, q_block, k_block, n_qb, n_kb,
                 with_lse, with_segments, window):
     if with_segments:
         q_ref, k_ref, v_ref, qseg_ref, kseg_ref, *out_refs = refs
@@ -144,46 +306,39 @@ def _fwd_kernel(*refs, sm_scale, causal, q_block, k_block, n_kb,
         l_scratch[:] = jnp.zeros_like(l_scratch)
         acc_scratch[:] = jnp.zeros_like(acc_scratch)
 
-    def _step():
-        q = q_ref[0, 0]  # [q_block, d]
-        k = k_ref[0]  # [k_block, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        q_seg = qseg_ref[0][:, :1] if qseg_ref is not None else None
-        k_seg = kseg_ref[...][:1, :] if kseg_ref is not None else None
-        if causal or window or q_seg is not None:
-            s = _block_mask(s, i, j, q_block, k_block, causal, q_seg,
-                            k_seg, window)
+    def _tile(strips):
+        # All the tile's score matmuls first, then strip by strip one
+        # online-softmax update and its p·v. Mosaic keeps to program
+        # order: written strip by strip the three stages run one after
+        # the other and a strip costs more than it saves; in this order
+        # a strip's VPU work runs under its neighbours' matmuls.
+        scores = [
+            _scores(q_ref[0, 0, r0:r1, :], k_ref[0, c0:c1, :], sm_scale,
+                    masks, window,
+                    qseg_ref[0, r0:r1, :1] if with_segments else None,
+                    kseg_ref[:, c0:c1] if with_segments else None)
+            for r0, r1, c0, c1, masks in strips]
+        for (r0, r1, c0, c1, _), s in zip(strips, scores):
+            m_prev = m_scratch[r0:r1, :1]  # [rows, 1]
+            l_prev = l_scratch[r0:r1, :1]
+            m_cur = jnp.max(s, axis=1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp(s - m_new)  # [rows, cols] fp32
+            alpha = jnp.exp(m_prev - m_new)  # [rows, 1]
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
 
-        m_prev = m_scratch[:, :1]  # [q_block, 1]
-        l_prev = l_scratch[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)  # [q_block, k_block] fp32
-        alpha = jnp.exp(m_prev - m_new)  # [q_block, 1]
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            v = v_ref[0, c0:c1, :]
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            acc_scratch[r0:r1] = acc_scratch[r0:r1] * alpha + pv
+            m_scratch[r0:r1] = jnp.broadcast_to(m_new, (r1 - r0, LANES))
+            l_scratch[r0:r1] = jnp.broadcast_to(l_new, (r1 - r0, LANES))
 
-        v = v_ref[0]  # [k_block, d]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scratch[:] = acc_scratch[:] * alpha + pv
-        m_scratch[:] = jnp.broadcast_to(m_new, m_scratch.shape)
-        l_scratch[:] = jnp.broadcast_to(l_new, l_scratch.shape)
-
-    # pruned iterations (causal: fully above the diagonal; window:
-    # fully behind the window) do no work; the kv index map clamps their
-    # block index so they issue no DMA either.
-    if causal and window:
-        pl.when(jnp.logical_and(
-            j <= _causal_j_max(i, q_block, k_block),
-            j >= _window_j_min(i, q_block, k_block, window)))(_step)
-    elif causal:
-        pl.when(j <= _causal_j_max(i, q_block, k_block))(_step)
-    else:
-        _step()
+    _for_tile_cases(
+        _tile_cases(i, j, n_qb, n_kb, q_block, k_block, causal, window),
+        _tile)
 
     @pl.when(j == n_kb - 1)
     def _finalize():
@@ -195,6 +350,18 @@ def _fwd_kernel(*refs, sm_scale, causal, q_block, k_block, n_kb,
             lse_ref[0, 0] = jnp.broadcast_to(lse, (q_block, LANES))
 
 
+# The two calls below are jitted INLINE: every layer of a model makes the
+# same call, and Pallas traces a kernel's body anew at each pallas_call
+# (0.3 s a layer for the unrolled strips, forward and backward). jit's
+# cache traces it once; ``inline`` leaves no jit in the name stack, so
+# the kernels' events keep the names a bare call gives them. Trace-time
+# module constants are baked into the cached trace: a test that patches
+# one clears JAX's caches.
+_STATIC = ("sm_scale", "causal", "q_block", "k_block", "window")
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=_STATIC + ("return_lse",))
 def _mha_fwd_impl(q, k, v, qseg, kseg, sm_scale, causal, q_block, k_block,
                   return_lse=False, window=0):
     """q: [g, rep, sq, d]; k, v: [g, sk, d]; g = batch * kv_heads.
@@ -209,11 +376,7 @@ def _mha_fwd_impl(q, k, v, qseg, kseg, sm_scale, causal, q_block, k_block,
     grid = (g, rep, n_qb, n_kb)
 
     def kv_index(b, r, i, j):
-        if causal:
-            j = jnp.minimum(j, _causal_j_max(i, q_block, k_block))
-        if window:
-            j = jnp.maximum(j, _window_j_min(i, q_block, k_block, window))
-        return (b, j, 0)
+        return (b, _clamp_j(i, j, q_block, k_block, causal, window), 0)
 
     q_spec = pl.BlockSpec((1, 1, q_block, d), lambda b, r, i, j: (b, r, i, 0))
     k_spec = pl.BlockSpec((1, k_block, d), kv_index)
@@ -224,26 +387,28 @@ def _mha_fwd_impl(q, k, v, qseg, kseg, sm_scale, causal, q_block, k_block,
         in_specs.append(pl.BlockSpec((1, q_block, LANES),
                                      lambda b, r, i, j: (b, i, 0)))
         in_specs.append(pl.BlockSpec(
-            (1, k_block),
-            (lambda b, r, i, j: (b, kv_index(b, r, i, j)[1]))))
+            (1, k_block), lambda b, r, i, j: kv_index(b, r, i, j)[:2]))
         inputs += [qseg, kseg]
     scratch = [
         pltpu.VMEM((q_block, LANES), jnp.float32),
         pltpu.VMEM((q_block, LANES), jnp.float32),
         pltpu.VMEM((q_block, d), jnp.float32),
     ]
-    flops = 4 * g * rep * sq * sk * d // (2 if causal else 1)
+    live = g * rep * sq * sk * causal_live_share(
+        sq, sk, q_block, k_block, _strip_rows(q_block, k_block, causal),
+        causal, window)
     cost = pl.CostEstimate(
-        flops=flops,
+        flops=int(4 * live * d),
         bytes_accessed=(q.size + 2 * g * sk * d + q.size) * 2,
-        transcendentals=g * rep * sq * sk // (2 if causal else 1),
+        transcendentals=int(live),
     )
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, q_block=q_block,
-        k_block=k_block, n_kb=n_kb, with_lse=return_lse,
+        k_block=k_block, n_qb=n_qb, n_kb=n_kb, with_lse=return_lse,
         with_segments=qseg is not None, window=window,
     )
-    params = _params("parallel", "parallel", "parallel", "arbitrary")
+    params = pltpu.CompilerParams(dimension_semantics=(
+        "parallel", "parallel", "parallel", "arbitrary"))
     if not return_lse:
         return pl.pallas_call(
             kernel,
@@ -276,380 +441,230 @@ def _mha_fwd_impl(q, k, v, qseg, kseg, sm_scale, causal, q_block, k_block,
 
 
 # ---------------------------------------------------------------------------
-# backward: dq pass (grid k-innermost, dq accumulates in VMEM scratch)
+# backward. One kernel body, three uses:
+#   fused (emit_dq and emit_dkv; flash-v2 backward proper): grid
+#     (g, kb, rep, qb). s/p/dp/ds are computed ONCE per strip and feed
+#     all three gradients, 5 matmuls and one VPU chain. dk/dv accumulate
+#     in VMEM scratch over (rep, qb), which also sums the GQA group; dq,
+#     whose natural accumulation order is transposed, accumulates in a
+#     float32 VMEM scratch that holds one g's whole [rep, sq, d] across
+#     the kb axis and is written once, in the input dtype. Taken while
+#     that accumulator fits _FUSED_DQ_VMEM_BUDGET.
+#   dq pass (emit_dq alone): grid (g, rep, qb, kb), k innermost, one q
+#     block of dq in scratch.            } the two-pass backward: 7
+#   dk/dv pass (emit_dkv alone): the     } matmuls and the VPU chain
+#     fused grid without dq.             } twice
+# Every gradient's HBM footprint equals its final size. delta =
+# rowsum(o · dO) (less the lse cotangent, where a caller differentiates
+# lse) is computed in the kernel from the o and dO blocks.
 # ---------------------------------------------------------------------------
-def _bwd_dq_kernel(*refs, sm_scale, causal, q_block, k_block, n_kb,
-                   with_segments, window):
-    if with_segments:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
-         kseg_ref, dq_ref, dq_scratch) = refs
+def _bwd_kernel(*refs, sm_scale, causal, q_block, k_block, n_qb, n_kb, rep,
+                with_dlse, with_segments, window, emit_dq, emit_dkv):
+    refs = iter(refs)
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = (
+        next(refs) for _ in range(6))
+    dlse_ref = next(refs) if with_dlse else None
+    qseg_ref = next(refs) if with_segments else None
+    kseg_ref = next(refs) if with_segments else None
+    dq_ref = next(refs) if emit_dq else None
+    dk_ref, dv_ref = (next(refs), next(refs)) if emit_dkv else (None, None)
+    dq_scratch = next(refs) if emit_dq else None
+    dk_scratch, dv_scratch = (
+        (next(refs), next(refs)) if emit_dkv else (None, None))
+
+    if emit_dkv:
+        j, r, i = (pl.program_id(a) for a in (1, 2, 3))
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-         dq_scratch) = refs
-        qseg_ref = kseg_ref = None
+        r, i, j = (pl.program_id(a) for a in (1, 2, 3))
+    # the fused pass keeps every q block of one g; the dq pass just its own
+    slot = r * n_qb + i if emit_dkv else 0
 
-    i = pl.program_id(2)
-    j = pl.program_id(3)
+    if emit_dkv:
+        @pl.when(jnp.logical_and(r == 0, i == 0))
+        def _init_dkv():
+            dk_scratch[:] = jnp.zeros_like(dk_scratch)
+            dv_scratch[:] = jnp.zeros_like(dv_scratch)
 
-    @pl.when(j == 0)
-    def _init():
-        dq_scratch[:] = jnp.zeros_like(dq_scratch)
+    if emit_dq:
+        @pl.when(j == 0)
+        def _init_dq():
+            dq_scratch[slot] = jnp.zeros(dq_scratch.shape[1:],
+                                         dq_scratch.dtype)
 
-    def _step():
-        q = q_ref[0, 0]
-        k = k_ref[0]
-        v = v_ref[0]
-        # matmul operands stay in the INPUT dtype (bf16 in training) with
-        # f32 accumulation — flash-v2 precision. f32 operands would run
-        # the MXU at half rate on v5e/v5p.
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
-        q_seg = qseg_ref[0][:, :1] if qseg_ref is not None else None
-        k_seg = kseg_ref[...][:1, :] if kseg_ref is not None else None
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if causal or window or q_seg is not None:
-            s = _block_mask(s, i, j, q_block, k_block, causal, q_seg,
-                            k_seg, window)
-        p = jnp.exp(s - lse)
+    def _products(strip):
+        # the two matmuls of a strip that wait for no VPU work. Operands
+        # stay in the INPUT dtype (bf16 in training) with f32
+        # accumulation — flash-v2 precision. f32 operands would run the
+        # MXU at half rate on v5e/v5p.
+        r0, r1, c0, c1, masks = strip
+        s = _scores(q_ref[0, 0, r0:r1, :], k_ref[0, c0:c1, :], sm_scale,
+                    masks, window,
+                    qseg_ref[0, r0:r1, :1] if with_segments else None,
+                    kseg_ref[:, c0:c1] if with_segments else None)
         dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            do_ref[0, 0, r0:r1, :], v_ref[0, c0:c1, :],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
         )
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dq_scratch[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        return s, dp
 
-    if causal and window:
-        pl.when(jnp.logical_and(
-            j <= _causal_j_max(i, q_block, k_block),
-            j >= _window_j_min(i, q_block, k_block, window)))(_step)
-    elif causal:
-        pl.when(j <= _causal_j_max(i, q_block, k_block))(_step)
-    else:
-        _step()
+    def _tile(strips):
+        # software-pipelined by hand (Mosaic keeps to program order): the
+        # next strip's s and dp matmuls are issued before this strip's
+        # exp/ds chain, which then runs under them
+        ahead = _products(strips[0])
+        for t, (r0, r1, c0, c1, _) in enumerate(strips):
+            s, dp = ahead
+            if t + 1 < len(strips):
+                ahead = _products(strips[t + 1])
+            q = q_ref[0, 0, r0:r1, :]
+            do = do_ref[0, 0, r0:r1, :]
+            lse = lse_ref[0, 0, r0:r1, :1]
+            delta = jnp.sum(
+                o_ref[0, 0, r0:r1, :].astype(jnp.float32)
+                * do.astype(jnp.float32), axis=1, keepdims=True)
+            if with_dlse:
+                # lse cotangent folds into delta:
+                # ds = p*(dp - (delta - dlse))
+                delta = delta - dlse_ref[0, 0, r0:r1, :1]
+            p = jnp.exp(s - lse)  # [rows, cols] f32, ONCE for all grads
+            ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
+            if emit_dkv:
+                # dv += p^T do
+                dv_scratch[c0:c1] += jax.lax.dot_general(
+                    p.astype(q.dtype), do, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                # dk += ds^T q
+                dk_scratch[c0:c1] += jax.lax.dot_general(
+                    ds, q, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+            if emit_dq:
+                dq_scratch[slot, r0:r1] += jax.lax.dot_general(
+                    ds, k_ref[0, c0:c1, :], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
 
-    @pl.when(j == n_kb - 1)
-    def _fin():
-        dq_ref[0, 0] = dq_scratch[:].astype(dq_ref.dtype)
+    _for_tile_cases(
+        _tile_cases(i, j, n_qb, n_kb, q_block, k_block, causal, window),
+        _tile)
 
+    if emit_dq:
+        @pl.when(j == n_kb - 1)
+        def _fin_dq():
+            dq_ref[0, slot] = dq_scratch[slot].astype(dq_ref.dtype)
 
-# ---------------------------------------------------------------------------
-# backward: dk/dv pass (grid (rep, q)-innermost, dk/dv accumulate in VMEM;
-# the GQA group-sum over rep happens in the same accumulator)
-# ---------------------------------------------------------------------------
-def _bwd_dkv_kernel(*refs, sm_scale, causal, q_block, k_block, n_qb, rep,
-                    with_segments, window):
-    if with_segments:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
-         kseg_ref, dk_ref, dv_ref, dk_scratch, dv_scratch) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-         dk_scratch, dv_scratch) = refs
-        qseg_ref = kseg_ref = None
-
-    j = pl.program_id(1)
-    r = pl.program_id(2)
-    i = pl.program_id(3)
-
-    @pl.when(jnp.logical_and(r == 0, i == 0))
-    def _init():
-        dk_scratch[:] = jnp.zeros_like(dk_scratch)
-        dv_scratch[:] = jnp.zeros_like(dv_scratch)
-
-    def _step():
-        q = q_ref[0, 0]
-        k = k_ref[0]
-        v = v_ref[0]
-        # input-dtype matmul operands, f32 accumulation (see dq kernel)
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
-        q_seg = qseg_ref[0][:, :1] if qseg_ref is not None else None
-        k_seg = kseg_ref[...][:1, :] if kseg_ref is not None else None
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if causal or window or q_seg is not None:
-            s = _block_mask(s, i, j, q_block, k_block, causal, q_seg,
-                            k_seg, window)
-        p = jnp.exp(s - lse)  # [q_block, k_block] f32
-        # dv += p^T do
-        dv_scratch[:] += jax.lax.dot_general(
-            p.astype(q.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        # dk += ds^T q
-        dk_scratch[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    if causal and window:
-        pl.when(jnp.logical_and(
-            i >= _causal_i_min(j, q_block, k_block),
-            i <= _window_i_max(j, q_block, k_block, window)))(_step)
-    elif causal:
-        pl.when(i >= _causal_i_min(j, q_block, k_block))(_step)
-    else:
-        _step()
-
-    @pl.when(jnp.logical_and(r == rep - 1, i == n_qb - 1))
-    def _fin():
-        dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
+    if emit_dkv:
+        @pl.when(jnp.logical_and(r == rep - 1, i == n_qb - 1))
+        def _fin_dkv():
+            dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
 
 
-# ---------------------------------------------------------------------------
-# backward: FUSED single pass (flash-v2 backward proper).
-#
-# The two-pass layout above runs 7 tile-matmuls (s and dp are computed
-# twice) and the full exp/mask/ds VPU chain twice — and the round-4
-# profile showed the backward VPU-bound at ~31% of roofline. This kernel
-# computes s/p/dp/ds ONCE per (j, i) tile and emits all three gradients:
-# dk/dv accumulate in VMEM scratch exactly as before (j is the outer
-# grid dim), while dq — whose natural accumulation order is transposed —
-# is written as per-j f32 PARTIALS [g, n_kb, rep, sq, d] that one XLA
-# reduction folds afterwards. 5 tile-matmuls, one VPU chain; extra HBM
-# is n_kb x sizeof(dq) for the partials, so the fused path is gated to
-# small n_kb (large k_block keeps n_kb = seq/1024) and falls back to the
-# two-pass kernels beyond it. Races: every partial block is written by
-# exactly one grid step; fully-masked steps zero-fill theirs.
-# ---------------------------------------------------------------------------
-_FUSED_BWD_MAX_KB = 4
-
-
-def _bwd_fused_kernel(*refs, sm_scale, causal, q_block, k_block, n_qb, rep,
-                      with_segments, window):
-    if with_segments:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qseg_ref,
-         kseg_ref, dqp_ref, dk_ref, dv_ref, dk_scratch, dv_scratch) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dqp_ref,
-         dk_ref, dv_ref, dk_scratch, dv_scratch) = refs
-        qseg_ref = kseg_ref = None
-
-    j = pl.program_id(1)
-    r = pl.program_id(2)
-    i = pl.program_id(3)
-
-    @pl.when(jnp.logical_and(r == 0, i == 0))
-    def _init():
-        dk_scratch[:] = jnp.zeros_like(dk_scratch)
-        dv_scratch[:] = jnp.zeros_like(dv_scratch)
-
-    def _step():
-        q = q_ref[0, 0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, :1]
-        delta = delta_ref[0, 0][:, :1]
-        q_seg = qseg_ref[0][:, :1] if qseg_ref is not None else None
-        k_seg = kseg_ref[...][:1, :] if kseg_ref is not None else None
-
-        # input-dtype matmul operands, f32 accumulation (flash-v2)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale
-        if causal or window or q_seg is not None:
-            s = _block_mask(s, i, j, q_block, k_block, causal, q_seg,
-                            k_seg, window)
-        p = jnp.exp(s - lse)  # computed ONCE for all three grads
-        dv_scratch[:] += jax.lax.dot_general(
-            p.astype(q.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dk_scratch[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dqp_ref[0, 0, 0] = jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).astype(dqp_ref.dtype)
-
-    def _skip():
-        # fully-masked tile: its dq partial block must still be defined
-        dqp_ref[0, 0, 0] = jnp.zeros_like(dqp_ref[0, 0, 0])
-
-    if causal and window:
-        live = jnp.logical_and(
-            i >= _causal_i_min(j, q_block, k_block),
-            i <= _window_i_max(j, q_block, k_block, window))
-        pl.when(live)(_step)
-        pl.when(jnp.logical_not(live))(_skip)
-    elif causal:
-        live = i >= _causal_i_min(j, q_block, k_block)
-        pl.when(live)(_step)
-        pl.when(jnp.logical_not(live))(_skip)
-    else:
-        _step()
-
-    @pl.when(jnp.logical_and(r == rep - 1, i == n_qb - 1))
-    def _fin():
-        dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
-
-
+@functools.partial(jax.jit, inline=True, static_argnames=_STATIC)
 def _mha_bwd_impl(q, k, v, o, do, lse, qseg, kseg, sm_scale, causal,
                   q_block, k_block, dlse=None, window=0):
     g, rep, sq, d = q.shape
     sk = k.shape[1]
     n_qb = sq // q_block
     n_kb = sk // k_block
-    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1)
-    if dlse is not None:
-        # lse cotangent folds into delta: ds = p*(dp - (delta - dlse))
-        delta = delta - dlse.astype(jnp.float32)
-    # lane-broadcast the per-row vectors to a 128 minor dim (TPU tiling)
-    lse_b = jnp.broadcast_to(lse[..., None], (g, rep, sq, LANES))
-    delta_b = jnp.broadcast_to(delta[..., None], (g, rep, sq, LANES))
+    # the per-row vectors go in lane-broadcast to a 128 minor dim (TPU
+    # tiling); only here, never as a residual held since the forward
+    rows = [jnp.broadcast_to(x.astype(jnp.float32)[..., None],
+                             (g, rep, sq, LANES))
+            for x in (lse, dlse) if x is not None]
+    live = g * rep * sq * sk * causal_live_share(
+        sq, sk, q_block, k_block, _strip_rows(q_block, k_block, causal),
+        causal, window)
+    dq_vmem = rep * sq * d * (4 + 2 * q.dtype.itemsize)
 
-    q_spec = pl.BlockSpec((1, 1, q_block, d), lambda b, r, i, j: (b, r, i, 0))
-    row_spec = pl.BlockSpec((1, 1, q_block, LANES),
-                            lambda b, r, i, j: (b, r, i, 0))
+    def run_pass(emit_dq, emit_dkv):
+        k_inner = not emit_dkv
 
-    def kv_index(b, r, i, j):
-        if causal:
-            j = jnp.minimum(j, _causal_j_max(i, q_block, k_block))
-        if window:
-            j = jnp.maximum(j, _window_j_min(i, q_block, k_block, window))
-        return (b, j, 0)
+        def step(*ids):  # grid ids -> (b, r, i, j)
+            if k_inner:
+                return ids
+            return ids[0], ids[2], ids[3], ids[1]
 
-    k_spec = pl.BlockSpec((1, k_block, d), kv_index)
-    in_specs = [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
-    inputs = [q, k, v, do, lse_b, delta_b]
-    if qseg is not None:
-        in_specs.append(pl.BlockSpec((1, q_block, LANES),
-                                     lambda b, r, i, j: (b, i, 0)))
-        in_specs.append(pl.BlockSpec(
-            (1, k_block), lambda b, r, i, j: (b, kv_index(b, r, i, j)[1])))
-        inputs += [qseg, kseg]
+        def q_index(*ids):
+            b, r, i, j = step(*ids)
+            if not k_inner:
+                i = _clamp_i(i, j, q_block, k_block, causal, window)
+            return (b, r, i, 0)
 
-    fused = n_kb <= _FUSED_BWD_MAX_KB
-    if not fused:
-        dq = pl.pallas_call(
+        def kv_index(*ids):
+            b, r, i, j = step(*ids)
+            if k_inner:
+                j = _clamp_j(i, j, q_block, k_block, causal, window)
+            return (b, j, 0)
+
+        q_spec = pl.BlockSpec((1, 1, q_block, d), q_index)
+        row_spec = pl.BlockSpec((1, 1, q_block, LANES), q_index)
+        kv_spec = pl.BlockSpec((1, k_block, d), kv_index)
+        in_specs = ([q_spec, kv_spec, kv_spec, q_spec, q_spec]
+                    + [row_spec] * len(rows))
+        inputs = [q, k, v, o, do] + rows
+        if qseg is not None:
+            in_specs.append(pl.BlockSpec(
+                (1, q_block, LANES),
+                lambda *ids: (ids[0], q_index(*ids)[2], 0)))
+            in_specs.append(pl.BlockSpec(
+                (1, k_block), lambda *ids: kv_index(*ids)[:2]))
+            inputs += [qseg, kseg]
+
+        out_specs, out_shape, scratch = [], [], []
+        if emit_dq:
+            slots = rep * n_qb if emit_dkv else 1
+            out_specs.append(pl.BlockSpec(
+                (1, slots, q_block, d),
+                (lambda b, j, r, i: (b, 0, 0, 0)) if emit_dkv else
+                (lambda b, r, i, j: (b, r * n_qb + i, 0, 0))))
+            out_shape.append(jax.ShapeDtypeStruct(
+                (g, rep * n_qb, q_block, d), q.dtype))
+            scratch.append(pltpu.VMEM((slots, q_block, d), jnp.float32))
+        if emit_dkv:
+            out_specs += [kv_spec] * 2
+            out_shape += [jax.ShapeDtypeStruct((g, sk, d), q.dtype)] * 2
+            scratch += [pltpu.VMEM((k_block, d), jnp.float32)] * 2
+        fused = emit_dq and emit_dkv
+        matmuls = 2 + emit_dq + 2 * emit_dkv
+        return pl.pallas_call(
             functools.partial(
-                _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                q_block=q_block, k_block=k_block, n_kb=n_kb,
+                _bwd_kernel, sm_scale=sm_scale, causal=causal,
+                q_block=q_block, k_block=k_block, n_qb=n_qb, n_kb=n_kb,
+                rep=rep, with_dlse=dlse is not None,
                 with_segments=qseg is not None, window=window,
+                emit_dq=emit_dq, emit_dkv=emit_dkv,
             ),
-            grid=(g, rep, n_qb, n_kb),
+            grid=(g, rep, n_qb, n_kb) if k_inner else (g, n_kb, rep, n_qb),
             in_specs=in_specs,
-            out_specs=q_spec,
-            out_shape=jax.ShapeDtypeStruct((g, rep, sq, d), q.dtype),
-            scratch_shapes=[pltpu.VMEM((q_block, d), jnp.float32)],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=scratch,
             cost_estimate=pl.CostEstimate(
-                flops=6 * g * rep * sq * sk * d // (2 if causal else 1),
-                bytes_accessed=4 * g * rep * sq * d * 2 + 2 * g * sk * d * 2,
-                transcendentals=g * rep * sq * sk // (2 if causal else 1),
+                flops=int(2 * matmuls * live * d),
+                bytes_accessed=(5 * q.size + 4 * g * sk * d)
+                * q.dtype.itemsize,
+                transcendentals=int(live),
             ),
-            compiler_params=_params("parallel", "parallel", "parallel",
-                                    "arbitrary"),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=(
+                    "parallel",
+                    "arbitrary" if fused else "parallel",
+                    "parallel" if k_inner else "arbitrary",
+                    "arbitrary"),
+                vmem_limit_bytes=(
+                    _TILE_VMEM_BYTES + dq_vmem if fused else None),
+            ),
             interpret=_interpret(),
         )(*inputs)
 
-    # dk/dv pass (fused: + dq partials): grid reordered (g, kb, rep, qb)
-    def q_index2(b, j, r, i):
-        if causal:
-            i = jnp.maximum(i, _causal_i_min(j, q_block, k_block))
-        if window:
-            i = jnp.minimum(i, _window_i_max(j, q_block, k_block, window))
-        return (b, r, i, 0)
-
-    q_spec2 = pl.BlockSpec((1, 1, q_block, d), q_index2)
-    row_spec2 = pl.BlockSpec(
-        (1, 1, q_block, LANES),
-        lambda b, j, r, i: q_index2(b, j, r, i))
-    kv_spec2 = pl.BlockSpec((1, k_block, d), lambda b, j, r, i: (b, j, 0))
-    in_specs2 = [q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2]
-    if qseg is not None:
-        in_specs2.append(pl.BlockSpec(
-            (1, q_block, LANES),
-            lambda b, j, r, i: (b, q_index2(b, j, r, i)[2], 0)))
-        in_specs2.append(pl.BlockSpec((1, k_block),
-                                      lambda b, j, r, i: (b, j)))
-
-    if fused:
-        dqp_spec = pl.BlockSpec(
-            (1, 1, 1, q_block, d), lambda b, j, r, i: (b, j, r, i, 0))
-        dq_part, dk, dv = pl.pallas_call(
-            functools.partial(
-                _bwd_fused_kernel, sm_scale=sm_scale, causal=causal,
-                q_block=q_block, k_block=k_block, n_qb=n_qb, rep=rep,
-                with_segments=qseg is not None, window=window,
-            ),
-            grid=(g, n_kb, rep, n_qb),
-            in_specs=in_specs2,
-            out_specs=(dqp_spec, kv_spec2, kv_spec2),
-            out_shape=(
-                jax.ShapeDtypeStruct((g, n_kb, rep, sq, d), jnp.float32),
-                jax.ShapeDtypeStruct((g, sk, d), q.dtype),
-                jax.ShapeDtypeStruct((g, sk, d), q.dtype),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((k_block, d), jnp.float32),
-                pltpu.VMEM((k_block, d), jnp.float32),
-            ],
-            cost_estimate=pl.CostEstimate(
-                flops=10 * g * rep * sq * sk * d // (2 if causal else 1),
-                bytes_accessed=(4 * g * rep * sq * d * 2
-                                + 2 * g * sk * d * 2
-                                + 4 * g * n_kb * rep * sq * d),
-                transcendentals=g * rep * sq * sk
-                // (2 if causal else 1),
-            ),
-            compiler_params=_params("parallel", "parallel", "arbitrary",
-                                    "arbitrary"),
-            interpret=_interpret(),
-        )(*inputs)
-        dq = dq_part.sum(axis=1).astype(q.dtype)
-        return dq, dk, dv
-
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-            q_block=q_block, k_block=k_block, n_qb=n_qb, rep=rep,
-            with_segments=qseg is not None, window=window,
-        ),
-        grid=(g, n_kb, rep, n_qb),
-        in_specs=in_specs2,
-        out_specs=(kv_spec2, kv_spec2),
-        out_shape=(
-            jax.ShapeDtypeStruct((g, sk, d), q.dtype),
-            jax.ShapeDtypeStruct((g, sk, d), q.dtype),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((k_block, d), jnp.float32),
-            pltpu.VMEM((k_block, d), jnp.float32),
-        ],
-        cost_estimate=pl.CostEstimate(
-            flops=8 * g * rep * sq * sk * d // (2 if causal else 1),
-            bytes_accessed=4 * g * rep * sq * d * 2 + 2 * g * sk * d * 2,
-            transcendentals=g * rep * sq * sk // (2 if causal else 1),
-        ),
-        compiler_params=_params("parallel", "parallel", "arbitrary",
-                                "arbitrary"),
-        interpret=_interpret(),
-    )(*inputs)
-    return dq, dk, dv
+    if dq_vmem <= _FUSED_DQ_VMEM_BUDGET:
+        dq, dk, dv = run_pass(True, True)
+    else:
+        (dq,) = run_pass(True, False)
+        dk, dv = run_pass(False, True)
+    return dq.reshape(g, rep, sq, d), dk, dv
 
 
 # ---------------------------------------------------------------------------

@@ -65,6 +65,9 @@ def chip_compile(one_chip, monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
     jax.config.update("jax_default_matmul_precision", None)
     compilation_cache.reset_cache()
+    # the flash kernels' calls are jitted: no trace made with the other
+    # setting of the interpret switch may serve this one, or outlive it
+    jax.clear_caches()
 
     def compile_(fn, specs, sharding=one_chip):
         args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
@@ -75,6 +78,7 @@ def chip_compile(one_chip, monkeypatch):
     for k, v in saved.items():
         jax.config.update(k, v)
     compilation_cache.reset_cache()
+    jax.clear_caches()
 
 
 def _flash(grad, b=2, s=2048, hq=HEADS, hk=HEADS):
@@ -186,6 +190,29 @@ def test_kernel_compiles_for_v5e(case, chip_compile):
     fn, specs = CASES[case]()
     compiled = chip_compile(fn, specs)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_is_one_forward_and_one_backward_call(chip_compile):
+    """The benchmark cell's own attention (mistral7b-train-2k: b 2,
+    s 2048, 32 heads over 8, d 128), forward + backward. The two flash
+    roofline metrics multiply one call's required work by the number of
+    kernel events the trace holds, so an attention call must stay ONE
+    forward and ONE backward custom call."""
+    import json
+    import pathlib
+    import re
+
+    fn, specs = _flash(True, b=2, s=2048, hq=32, hk=8)
+    text = chip_compile(fn, specs).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # ... under the names the two metrics' patterns look for: an event of
+    # the trace is named by its instruction's text
+    lines = [ln.strip() for ln in text.splitlines()]
+    metrics = pathlib.Path(__file__).parent.parent / "chipbench" / "metrics"
+    for name in ("flash_fwd_roofline.train", "flash_bwd_roofline.train"):
+        rx = re.compile(json.loads(
+            (metrics / f"{name}.json").read_text())["args"]["kernel"])
+        assert sum(bool(rx.search(ln)) for ln in lines) == 1, name
 
 
 def test_flash_compiles_under_four_chip_mesh(topo, chip_compile,

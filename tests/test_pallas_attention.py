@@ -285,30 +285,175 @@ def test_flash_attention_window_fallback_paths():
                         dropout_p=0.5)
 
 
-def test_mha_grad_two_pass_path_matches_fused():
-    """n_kb > _FUSED_BWD_MAX_KB falls back to the two-pass backward;
-    both paths must produce identical gradients."""
+@pytest.fixture
+def two_pass(monkeypatch):
+    """force() makes the dq and dk/dv passes run in place of the fused
+    backward: no dq accumulator fits a budget of 0. The kernels' calls
+    are jitted and the budget is baked into a cached trace, so JAX's
+    caches are cleared with every change of it."""
+    from paddle_tpu.kernels import pallas_attention as pa
+
+    def force():
+        monkeypatch.setattr(pa, "_FUSED_DQ_VMEM_BUDGET", 0)
+        jax.clear_caches()
+
+    yield force
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+def test_mha_grad_two_pass_path_matches_fused(two_pass):
+    """The fused backward is taken while one kv group's dq accumulator
+    fits _FUSED_DQ_VMEM_BUDGET, the two-pass backward beyond it; both
+    must produce the same gradients at the same blocks."""
     from paddle_tpu.kernels import pallas_attention as pa
 
     rng = np.random.default_rng(11)
-    # seq 768 / k_block 128 -> n_kb = 6 > pa._FUSED_BWD_MAX_KB
-    # (two-pass); k_block 256 -> n_kb = 3 (fused). Same math either way.
-    assert 768 // 128 > pa._FUSED_BWD_MAX_KB >= 768 // 256
-    q = jnp.asarray(rng.standard_normal((1, 2, 768, 64)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((1, 2, 768, 64)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((1, 2, 768, 64)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((1, 768, 2, 64)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, 768, 2, 64)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, 768, 2, 64)), jnp.float32)
+    # rep 1, s 768, d padded to 128, f32: 384 KiB of scratch and 768 KiB
+    # of double-buffered output block
+    assert 768 * 128 * (4 + 2 * 4) <= pa._FUSED_DQ_VMEM_BUDGET
 
-    def loss(blk):
+    def grads():
         def f(q, k, v):
             return jnp.sum(
-                mha(q, k, v, causal=True, q_block=128, k_block=blk) ** 2)
+                mha(q, k, v, causal=True, q_block=128, k_block=256) ** 2)
         return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
 
-    g_two = loss(128)   # n_kb=6: two-pass
-    g_fused = loss(256)  # n_kb=3: fused
+    g_fused = grads()
+    two_pass()
+    g_two = grads()
     for a, b in zip(g_two, g_fused):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-5)
+
+
+def _dense_attention(q, k, v, window=0, seg=None):
+    """Causal dense reference that also returns logsumexp [b, h, s]."""
+    b, s, hq, d = q.shape
+    rep = hq // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (d ** -0.5)
+    diff = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+    mask = diff >= 0
+    if window:
+        mask = jnp.logical_and(mask, diff < window)
+    mask = mask[None, None]
+    if seg is not None:
+        mask = jnp.logical_and(
+            mask, seg[:, None, :, None] == seg[:, None, None, :])
+    logits = jnp.where(mask, logits, jnp.float32(-1e30))
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1), v)
+    return out, lse
+
+
+# s, block, q heads, kv heads, window, segment lengths, lse cotangent.
+# 512-blocks are worked in four 128-row strips; s = 1024 adds the tiles
+# below the diagonal, which a window of 600 crosses one block row down.
+SUBTILED = {
+    "mha": (512, 512, 2, 2, 0, None, False),
+    "gqa_rep4": (512, 512, 4, 1, 0, None, False),
+    "window": (512, 512, 2, 2, 200, None, False),
+    "segments": (512, 512, 2, 2, 0, (200, 200, 112), False),
+    "dlse": (512, 512, 2, 2, 0, None, True),
+    "two_blocks_window": (1024, 512, 1, 1, 600, None, True),
+}
+
+
+@pytest.mark.parametrize("backward", ["fused", "two_pass"])
+@pytest.mark.parametrize("case", sorted(SUBTILED))
+def test_subtiled_causal_matches_reference(case, backward, two_pass):
+    """Forward and gradients where the diagonal tile is worked in strips
+    that skip what lies above the diagonal, in the fused and the
+    two-pass backward."""
+    from paddle_tpu.kernels import pallas_attention as pa
+
+    s, blk, hq, hk, window, seg_lens, dlse = SUBTILED[case]
+    assert pa._strip_rows(blk, blk) == 128
+    assert pa.causal_live_share(s, s, blk, blk, 128, True, window) < \
+        pa.causal_live_share(s, s, blk, blk, 0, True, window)
+    if backward == "two_pass":
+        two_pass()
+    rng = np.random.default_rng(21)
+    q = jnp.asarray(rng.standard_normal((1, s, hq, 128)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, s, hk, 128)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, s, hk, 128)), jnp.float32)
+    seg = None
+    if seg_lens:
+        seg = jnp.asarray(np.repeat(np.arange(len(seg_lens)), seg_lens),
+                          jnp.int32)[None, :]
+
+    def run_pallas(q, k, v):
+        return pa.mha_with_lse(q, k, v, causal=True, q_block=blk,
+                               k_block=blk, segment_ids=seg, window=window)
+
+    def run_ref(q, k, v):
+        return _dense_attention(q, k, v, window, seg)
+
+    def loss(run):
+        def f(q, k, v):
+            o, lse = run(q, k, v)
+            total = jnp.sum(o ** 2)
+            # a non-zero lse cotangent, as ring attention's merge sends
+            return total + jnp.sum(jnp.sin(lse)) if dlse else total
+        return f
+
+    o, lse = run_pallas(q, k, v)
+    o_ref, lse_ref = run_ref(q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                               rtol=2e-3, atol=2e-3)
+    gp = jax.grad(loss(run_pallas), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(run_ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-3, atol=5e-3)
+
+
+def test_causal_live_share_pins():
+    """The static count the causal pruning is judged by."""
+    from paddle_tpu.kernels import pallas_attention as pa
+
+    share = pa.causal_live_share
+    # whole 1024-tiles, the kernel before PR 30: 3 of 4
+    assert share(2048, 2048, 1024, 1024, 0) == 0.75
+    # what the defaults choose at the benchmark cell's s = 2048
+    rows = pa._strip_rows(pa.DEFAULT_Q_BLOCK, pa.DEFAULT_K_BLOCK)
+    assert rows == 128
+    assert share(2048, 2048, pa.DEFAULT_Q_BLOCK, pa.DEFAULT_K_BLOCK,
+                 rows) == 0.53125 <= 0.625
+    assert share(2048, 2048, 1024, 1024, 512) == 0.625
+    assert share(2048, 2048, 1024, 1024, rows, causal=False) == 1.0
+    assert pa._strip_rows(1024, 1024, causal=False) == 0
+    # blocks of 128-256 and non-square tiles are worked whole
+    assert pa._strip_rows(256, 256) == 0 and pa._strip_rows(512, 1024) == 0
+    assert share(512, 512, 128, 256, 0) == 0.75
+    # the window prunes from the other side: a band of 256 over s = 1024
+    # in 512-blocks keeps 3 whole tiles, in 128-row strips 21 squares of
+    # 64 (a strip sees at most 3: q_pos - k_pos < 256 reaches 2 back)
+    assert share(1024, 1024, 512, 512, 0, window=256) == 0.75
+    assert share(1024, 1024, 512, 512, 128, window=256) == 21 / 64
+    # the triangle itself, (s + 1) / 2s, is the floor
+    assert share(2048, 2048, 1024, 1024, rows) > 2049 / 4096
+
+
+def test_tile_strips_work_list():
+    """The diagonal tile's strips see a growing run of columns and mask
+    only the 128 the diagonal crosses; a tile below it runs whole."""
+    from paddle_tpu.kernels import pallas_attention as pa
+
+    assert pa._tile_strips(0, 512, 128, 0) == tuple(
+        (r, r + 128, 0, r + 128, ((r, r + 128, 0),))
+        for r in range(0, 512, 128))
+    assert pa._tile_strips(1, 512, 128, 0) == ((0, 512, 0, 512, ()),)
+    assert pa._tile_strips(-1, 512, 128, 0) == ()
+    # a window of 512, one block row down: the band's far edge
+    assert pa._tile_strips(1, 512, 128, 512) == tuple(
+        (r, r + 128, r, 512, ((0, 128, 512),)) for r in range(0, 512, 128))
 
 
 @pytest.mark.parametrize("segments", [False, True])
