@@ -15,6 +15,7 @@ the reference hand-codes, overlapped by XLA.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -95,12 +96,24 @@ def _switch_gating(logits, capacity: int):
     return combine, combine > 0.0, aux, drop_fraction
 
 
+def relu2(x):
+    """Squared ReLU, the two-matrix experts' activation."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def _activation(name: str):
+    return relu2 if name == "relu2" else getattr(F, name)
+
+
 class ExpertFFN(Layer):
     """Batched expert FFN: weights [E, in, hidden], [E, hidden, in] with
-    the expert dim sharded over ``expert_axis``."""
+    the expert dim sharded over ``expert_axis``. ``bias=False`` leaves
+    out ``b1``/``b2``; ``gated=True`` adds the third matrix ``w3`` of a
+    gated expert, ``act(x w3) * (x w1)`` before ``w2``; ``activation``
+    names a function of ``nn.functional`` or ``relu2``."""
 
     def __init__(self, num_experts, d_model, d_hidden, expert_axis="ep",
-                 activation="gelu", init_std=0.02):
+                 activation="gelu", init_std=0.02, bias=True, gated=False):
         super().__init__()
         init = I.Normal(0.0, init_std)
         self.w1 = self.create_parameter(
@@ -111,19 +124,29 @@ class ExpertFFN(Layer):
             (num_experts, d_hidden, d_model), default_initializer=init,
             spec=(expert_axis, "tp", None),
         )
+        self.w3 = self.create_parameter(
+            (num_experts, d_model, d_hidden), default_initializer=init,
+            spec=(expert_axis, None, "tp"),
+        ) if gated else None
         self.b1 = self.create_parameter(
             (num_experts, d_hidden), is_bias=True, spec=(expert_axis, "tp")
-        )
+        ) if bias else None
         self.b2 = self.create_parameter(
             (num_experts, d_model), is_bias=True, spec=(expert_axis, None)
-        )
-        self.act = getattr(F, activation)
+        ) if bias else None
+        self.act = _activation(activation)
 
     def forward(self, x):
         # x: [E, cap_total, d_model]
-        h = jnp.einsum("ecm,emh->ech", x, self.w1.value) + self.b1.value[:, None]
-        h = self.act(h)
-        return jnp.einsum("ech,ehm->ecm", h, self.w2.value) + self.b2.value[:, None]
+        h = jnp.einsum("ecm,emh->ech", x, self.w1.value)
+        if self.b1 is not None:
+            h = h + self.b1.value[:, None]
+        if self.w3 is not None:
+            h = self.act(jnp.einsum("ecm,emh->ech", x, self.w3.value)) * h
+        else:
+            h = self.act(h)
+        y = jnp.einsum("ech,ehm->ecm", h, self.w2.value)
+        return y if self.b2 is None else y + self.b2.value[:, None]
 
 
 class MoELayer(Layer):
@@ -281,7 +304,8 @@ def dropless_moe_ep_apply(xf, gate_weight, w1, b1, w2, b2, act, top_k,
     ep = mesh.shape[ep_axis]
     E = w1.shape[0]
     if E % ep:
-        raise ValueError(f"num_experts {E} must divide ep degree {ep}")
+        raise ValueError(
+            f"ep degree {ep} must divide num_experts {E}")
     e_loc = E // ep
 
     def body(x_loc, gw, w1_loc, b1_loc, w2_loc, b2_loc):
@@ -417,3 +441,263 @@ class DroplessMoELayer(MoELayer):
         self.last_aux_loss = aux * self.aux_loss_weight
         self.last_drop_fraction = jnp.zeros(())
         return y.reshape(b, s, m), self.last_aux_loss
+
+
+# ---------------------------------------------------------------------
+# One chip's share of an expert-parallel layer: told which experts it
+# holds, it routes over all of them and computes its own experts' part.
+# ---------------------------------------------------------------------
+def sigmoid_topk_routing(scores_in, correction_bias, top_k: int,
+                         scale: float = 1.0):
+    """Sigmoid-scored top-k routing (DeepSeek-V3 / Nemotron-H form).
+    ``scores_in`` [t, E] float32 router outputs. The choice is the top-k
+    of ``sigmoid + correction_bias``; the weights are the chosen
+    sigmoids WITHOUT the bias, renormalised over the k chosen, times
+    ``scale``. Returns (expert_idx [t, k] int32, gates [t, k]
+    float32)."""
+    s = jax.nn.sigmoid(scores_in.astype(jnp.float32))
+    bias = jax.lax.stop_gradient(correction_bias.astype(jnp.float32))
+    # top_k hands back the chosen values: taking the bias off them again
+    # spares a gather over [t, E] and its scatter in the backward pass
+    chosen, idx = jax.lax.top_k(s + bias, top_k)
+    g = chosen - bias[idx]
+    g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), g * scale
+
+
+def _held_rows(expert_idx, first: int, n_held: int):
+    """The (token, choice) assignments that fall on the experts
+    ``first .. first + n_held - 1``: ``order`` lists all t*k flat
+    assignments with the held ones first, grouped by expert and inside
+    an expert by token; ``counts`` [n_held] is how many each held expert
+    got."""
+    local = expert_idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    counts = jnp.sum(key[:, None] == jnp.arange(n_held), axis=0,
+                     dtype=jnp.int32)
+    return jnp.argsort(key, stable=True).astype(jnp.int32), counts
+
+
+def _expert_rows(xs, gate, w_e: dict, act):
+    """One block of rows ``xs`` [rows, m] through ONE expert's matrices,
+    weighed by ``gate`` (rows past the expert's last come in as zeros
+    with a zero weight)."""
+    return (act(xs @ w_e["w1"]) @ w_e["w2"]).astype(jnp.float32) \
+        * gate[:, None]
+
+
+def _block(order, start, end, j, top_k: int, rows: int, t: int):
+    """Block ``j`` of the expert whose sorted rows are
+    ``order[start:end]``: each row's token and flat assignment. Inside
+    an expert both rise and never repeat; rows past the expert's last
+    get indices past the arrays' ends, so every gather fills them with
+    zeros; the row accumulators (``_row_acc``) keep ``rows`` spare rows
+    for them. (Telling XLA that the indices are sorted and unique
+    doubles the time of the scatters on a v5e: PERF.md, PR 31.)"""
+    lo = start + j * rows
+    idx = jax.lax.dynamic_slice(order, (lo,), (rows,))
+    ahead = jnp.arange(rows)
+    valid = lo + ahead < end
+    return (jnp.where(valid, idx // top_k, t + ahead),
+            jnp.where(valid, idx, t * top_k + ahead))
+
+
+def _take(a, i):
+    return a.at[i].get(mode="fill", fill_value=0)
+
+
+def _add(a, i, v):
+    return a.at[i].add(v.astype(a.dtype), mode="drop")
+
+
+# Rows are added into a float32 accumulator laid out [row, m / 128, 128]:
+# a row is then whole (8, 128) tiles of its own, where a row of a
+# [t, m] array is one sublane of m / 128 tiles that it shares with
+# seven other rows. On a v5e XLA's scatter-add of 256 rows of 2688
+# takes 33 us in this layout and 95 us in the flat one (PERF.md, PR 31).
+def _row_acc(t: int, m: int, spare: int):
+    lanes = 128 if m % 128 == 0 else m
+    return jnp.zeros((t + spare, m // lanes, lanes), jnp.float32)
+
+
+def _add_rows(acc, tok, v):
+    """``tok`` lies inside ``acc``: a block's idle rows point at the
+    spare rows past ``t``, each at its own."""
+    return acc.at[tok].add(v.reshape(-1, *acc.shape[1:]),
+                           mode="promise_in_bounds")
+
+
+def _rows_of(acc, t: int):
+    return acc[:t].reshape(t, -1)
+
+
+def _n_blocks(start, end, rows: int):
+    return (end - start + rows - 1) // rows
+
+
+def _spans(counts):
+    ends = jnp.cumsum(counts)
+    return ends - counts, ends
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _held_experts(act, top_k, rows, x, gates, order, counts, w):
+    """sum over the held rows of gate * expert(x[token]), [t, m] float32.
+    A scan over the held experts; inside it each expert walks its own
+    rows in blocks of ``rows``, as many as it got: the trip count is
+    read on the device, so the backward pass is written out below."""
+    def per_expert(out, args):
+        w_e, start, end = args
+
+        def body(j, out):
+            tok, flat = _block(order, start, end, j, top_k, rows,
+                               x.shape[0])
+            return _add_rows(out, tok, _expert_rows(
+                _take(x, tok), _take(gates, flat), w_e, act))
+
+        return jax.lax.fori_loop(
+            0, _n_blocks(start, end, rows), body, out), None
+
+    out, _ = jax.lax.scan(per_expert, _row_acc(*x.shape, rows),
+                          (w, *_spans(counts)))
+    return _rows_of(out, x.shape[0])
+
+
+def _held_experts_fwd(act, top_k, rows, x, gates, order, counts, w):
+    out = _held_experts(act, top_k, rows, x, gates, order, counts, w)
+    return out, (x, gates, order, counts, w)
+
+
+def _held_experts_bwd(act, top_k, rows, res, dout):
+    x, gates, order, counts, w = res
+    f32 = jnp.float32
+
+    def per_expert(acc, args):
+        w_e, start, end = args
+
+        def body(j, inner):
+            dx, dgates, dw_e = inner
+            tok, flat = _block(order, start, end, j, top_k, rows,
+                               x.shape[0])
+            _, vjp = jax.vjp(
+                lambda xs, g, w_e: _expert_rows(xs, g, w_e, act),
+                _take(x, tok), _take(gates, flat), w_e)
+            dxs, dg, dw_b = vjp(_take(dout, tok))
+            return (_add_rows(dx, tok, dxs), _add(dgates, flat, dg),
+                    jax.tree_util.tree_map(
+                        lambda a, b: a + b.astype(f32), dw_e, dw_b))
+
+        dx, dgates, dw_e = jax.lax.fori_loop(
+            0, _n_blocks(start, end, rows), body,
+            (*acc, jax.tree_util.tree_map(
+                lambda v: jnp.zeros(v.shape, f32), w_e)))
+        return (dx, dgates), jax.tree_util.tree_map(
+            lambda a, v: a.astype(v.dtype), dw_e, w_e)
+
+    (dx, dgates), dw = jax.lax.scan(
+        per_expert, (_row_acc(*x.shape, rows), jnp.zeros(gates.shape, f32)),
+        (w, *_spans(counts)))
+    return _rows_of(dx, x.shape[0]).astype(x.dtype), dgates, None, None, dw
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def held_experts_apply(x, expert_idx, gates, w: dict, act, first: int,
+                       block_rows: int = 256):
+    """The held experts' part of a dropless top-k layer.
+
+    x [t, m]; expert_idx, gates [t, k] over ALL the router's experts;
+    ``w`` the held experts' stacked matrices (``w1`` [n_held, m, h],
+    ``w2`` [n_held, h, m]; two-matrix experts), which are the
+    experts ``first .. first + n_held - 1``. Returns
+    (y [t, m] float32 = sum over the chosen AND held experts of
+    gate * expert(x), counts [n_held]).
+
+    Rows of absent experts cost nothing: the assignments are sorted
+    with the held ones first, by expert, and each held expert (a scan
+    over the stacked matrices) walks its own rows in blocks
+    of ``block_rows`` (gather, two plain products,
+    scatter-add), as many blocks as it got. Nothing is dropped,
+    whatever the routing: a full expert takes more blocks. At most
+    ``block_rows - 1`` rows of zeros an expert are multiplied in
+    vain."""
+    t, k = expert_idx.shape
+    order, counts = _held_rows(expert_idx, first, w["w1"].shape[0])
+    # room for an expert's last block to read a whole slice
+    order = jnp.pad(order, (0, block_rows))
+    y = _held_experts(act, k, block_rows, x,
+                      gates.reshape(-1).astype(jnp.float32), order,
+                      counts, w)
+    return y, counts
+
+
+class HeldExpertsMoE(Layer):
+    """One chip's share of an expert-parallel sparse layer.
+
+    The router is whole (``num_experts`` outputs, top-k over all of
+    them); of the routed experts this layer holds ``held = (first,
+    count)`` and computes their part of the result for the rows routed
+    to them, and nothing for the rest; a shared expert, where
+    ``shared_hidden`` is given, sees every token. On one chip there is
+    no exchange: what the absent experts would add is added by the chips
+    that hold them.
+
+    forward(x [b, s, m]) -> y [b, s, m]; the step's routing counts are
+    left in ``last_counts`` ({"rows_routed", "rows_held", "rows_max"},
+    device scalars of the trace that called forward)."""
+
+    def __init__(self, d_model: int, num_experts: int, d_hidden: int,
+                 top_k: int, held, activation: str = "relu2",
+                 shared_hidden: Optional[int] = None,
+                 routed_scale: float = 1.0, init_std: float = 0.02,
+                 expert_axis: str = "ep"):
+        super().__init__()
+        self.num_experts, self.top_k = num_experts, top_k
+        self.first, n_held = held
+        if self.first < 0 or self.first + n_held > num_experts:
+            raise ValueError(f"held experts {held} outside 0..{num_experts}")
+        self.routed_scale = routed_scale
+        init = I.Normal(0.0, init_std)
+        self.gate_weight = self.create_parameter(
+            (d_model, num_experts), default_initializer=init)
+        # chosen by sigmoid + this, weighed without it; a buffer that
+        # load balancing moves, never a parameter
+        self.register_buffer("e_score_correction_bias",
+                             jnp.zeros((num_experts,), jnp.float32))
+        self.experts = ExpertFFN(n_held, d_model, d_hidden, expert_axis,
+                                 activation, init_std, bias=False)
+        self.shared_experts = ExpertFFN(
+            1, d_model, shared_hidden, None, activation, init_std,
+            bias=False) if shared_hidden else None
+        self.block_rows = 256  # rows of one product of a held expert
+        self.last_counts = None
+
+    def route(self, xf):
+        scores = jnp.matmul(
+            xf.astype(jnp.float32),
+            self.gate_weight.value.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
+        return sigmoid_topk_routing(
+            scores, self._buffers["e_score_correction_bias"], self.top_k,
+            self.routed_scale)
+
+    def forward(self, x):
+        b, s, m = x.shape
+        xf = x.reshape(b * s, m)
+        with jax.named_scope("moe_router"):
+            expert_idx, gates = self.route(xf)
+        with jax.named_scope("moe_experts"):
+            e = self.experts
+            w = {"w1": e.w1.value, "w2": e.w2.value}
+            y, counts = held_experts_apply(
+                xf, expert_idx, gates, w, e.act, self.first,
+                self.block_rows)
+            y = y.astype(x.dtype)
+        self.last_counts = {
+            "rows_routed": jnp.asarray(b * s * self.top_k, jnp.int32),
+            "rows_held": jnp.sum(counts), "rows_max": jnp.max(counts)}
+        if self.shared_experts is not None:
+            with jax.named_scope("moe_shared"):
+                y = y + self.shared_experts(xf[None])[0]
+        return y.reshape(b, s, m)

@@ -1,7 +1,12 @@
-"""Pallas chunked selective scan (S6 linear recurrence) for Mamba.
+"""Pallas chunked selective scan (S6 linear recurrence) for Mamba-1.
 
-Parity: the reference's selective-scan CUDA kernel (the "Mamba-2 / RWKV
-selective-scan + linear-recurrence Phi op" BASELINE.json config).
+Parity: the reference's selective-scan CUDA kernel (the "selective-scan
++ linear-recurrence Phi op" BASELINE.json config, whose name says
+"Mamba-2 / RWKV"). What this file computes is the S6 layer of Mamba-1: a
+decay per channel and state column (``A [d, n]``, n = 16), walked step
+by step. Mamba-2's recurrence (a scalar decay per head, a ``[64, 128]``
+state per head) is another layer and lives in ``kernels/ssd.py``, in its
+chunked matmul form, which this kernel cannot express.
 
 Why a kernel when ``jax.lax.associative_scan`` already runs on TPU: the
 associative formulation materializes the discretized operands

@@ -15,7 +15,8 @@ from .llama import (  # noqa: F401
     LlamaForCausalLM,
     LlamaModel,
 )
-from .mamba import MambaConfig, MambaForCausalLM  # noqa: F401
+from .mamba import Mamba2Mixer, MambaConfig, MambaForCausalLM  # noqa: F401
+from .nemotron_h import NemotronHConfig, NemotronHForCausalLM  # noqa: F401
 from .rwkv import RWKVConfig, RWKVForCausalLM  # noqa: F401
 from .t5 import (  # noqa: F401
     T5Config,

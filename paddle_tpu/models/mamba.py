@@ -1,20 +1,30 @@
-"""Mamba-style selective state-space LM.
+"""Selective state-space layers: Mamba-1 (S6) and Mamba-2.
 
-Parity: the "Mamba-2 / RWKV (selective-scan + linear-recurrence Phi op →
-Pallas)" config in BASELINE.json. The reference implements selective scan
-as a custom CUDA kernel; the TPU-native formulation is a **parallel
-associative scan** (`jax.lax.associative_scan`) over the linear
-recurrence h_t = a_t ⊙ h_{t-1} + b_t — the composition (a, b)∘(a', b') =
-(a·a', a'·b + b') is associative, so XLA lowers it to a log-depth scan
-that keeps the MXU/VPU busy instead of a sequential loop. This is the
-standard TPU mapping for S6/linear-attention recurrences; a Pallas
-chunked-scan kernel is the follow-up optimization for very long
-sequences.
+Two different layers live here, and each has its kernel:
+
+``MambaMixer`` / ``MambaForCausalLM`` are **Mamba-1** (S6): ``x_proj``
+and ``dt_proj`` make a step size per channel, ``A`` is ``[d_inner, n]``
+(n = 16), and the state ``[n, d_inner]`` is walked by
+``kernels/selective_scan.py``: a parallel associative scan
+(``jax.lax.associative_scan`` over h_t = a_t * h_{t-1} + b_t, whose
+composition (a, b) o (a', b') = (a a', a' b + b') is associative), or
+the Pallas chunked scan for long sequences. This is the layer the
+"selective-scan + linear-recurrence Phi op" line of BASELINE.json
+names.
+
+``Mamba2Mixer`` is **Mamba-2**: one fused ``in_proj`` gives the gate
+``z``, the convolved ``x, B, C`` and a step size per HEAD; ``A`` is one
+scalar a head, the state ``[head_dim, n]`` a head (n = 128), ``B`` and
+``C`` are shared by the heads of a group, and a gated group RMSNorm
+follows. Its recurrence runs in the chunked, matmul form of
+``kernels/ssd.py``. ``models/nemotron_h.py`` stacks it with expert and
+attention blocks.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -163,3 +173,108 @@ class MambaForCausalLM(Layer):
         if labels is None:
             return logits
         return F.cross_entropy(logits[:, :-1], labels[:, 1:])
+
+
+class Mamba2Mixer(Layer):
+    """The Mamba-2 mixer, on h [b, s, hidden] (the block's norm and
+    residual are the caller's):
+
+        [z | xBC | dt] = h W_in                      (no bias)
+        xBC = silu(causal_depthwise_conv1d(xBC) + b_conv)
+        x, B, C = split(xBC);  dt = softplus(dt + dt_bias)
+        y = SSD(x, dt, A = -exp(A_log), B, C, D)     (kernels/ssd.py)
+        out = GroupRMSNorm(y * silu(z)) W_out        (gate first)
+
+    ``dt``, ``A`` and the recurrence are float32; the device phases are
+    the scopes ``ssm_in``, ``ssm_scan`` and ``ssm_out``."""
+
+    def __init__(self, hidden_size, num_heads, head_dim, state_size,
+                 n_groups, conv_kernel=4, chunk_size=128, norm_eps=1e-5,
+                 init_std=0.02, time_step_min=0.001, time_step_max=0.1,
+                 time_step_floor=1e-4):
+        super().__init__()
+        self.num_heads, self.head_dim = num_heads, head_dim
+        self.state_size, self.n_groups = state_size, n_groups
+        self.chunk_size, self.norm_eps = chunk_size, norm_eps
+        d_in = num_heads * head_dim
+        conv_dim = d_in + 2 * n_groups * state_size
+        init = I.Normal(0.0, init_std)
+        self.in_proj = Linear(hidden_size, d_in + conv_dim + num_heads,
+                              weight_attr=init, bias_attr=False)
+        self.conv_weight = self.create_parameter(
+            (conv_dim, conv_kernel),
+            default_initializer=I.Uniform(-0.5, 0.5))
+        self.conv_bias = self.create_parameter((conv_dim,), is_bias=True)
+
+        def dt_bias_init(key, shape, dtype):
+            # softplus(dt_bias) is log-uniform in [min, max], floored
+            u = jax.random.uniform(key, shape, jnp.float32)
+            dt = jnp.exp(u * (jnp.log(time_step_max) - jnp.log(
+                time_step_min)) + jnp.log(time_step_min))
+            dt = jnp.maximum(dt, time_step_floor)
+            return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+        self.dt_bias = self.create_parameter(
+            (num_heads,), default_initializer=dt_bias_init)
+        self.A_log = self.create_parameter(
+            (num_heads,),
+            default_initializer=lambda key, shape, dtype: jnp.log(
+                jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
+            ).astype(dtype))
+        self.D = self.create_parameter(
+            (num_heads,), default_initializer=I.Constant(1.0))
+        self.norm_weight = self.create_parameter(
+            (d_in,), default_initializer=I.Constant(1.0))
+        self.out_proj = Linear(d_in, hidden_size, weight_attr=init,
+                               bias_attr=False)
+
+    def forward(self, h):
+        nh, g = self.num_heads, self.n_groups
+        with jax.named_scope("ssm_in"):
+            zxbcdt = self.in_proj(h)
+        y = _mamba2_core(
+            zxbcdt, self.conv_weight.value, self.conv_bias.value,
+            self.dt_bias.value, self.A_log.value, self.D.value,
+            self.norm_weight.value,
+            (nh, self.head_dim, g, self.state_size, self.chunk_size,
+             self.norm_eps))
+        with jax.named_scope("ssm_out"):
+            return self.out_proj(y)
+
+
+@functools.partial(jax.checkpoint, static_argnums=(7,))
+def _mamba2_core(zxbcdt, taps, conv_bias, dt_bias, A_log, D, norm_weight,
+                 sizes):
+    """Between the two projections: conv, the SSD, the gated norm.
+    Rematerialised in the backward pass as one piece: its float32
+    intermediates (the padded conv input, the SSD's chunk decays and
+    scores, y, the gated product) are several times the bf16 tensor it
+    starts from and hold no weight product, so what a block saves is
+    ``in_proj``'s output and this function's."""
+    from ..kernels.ssd import ssd_chunked
+
+    nh, p, g, n, chunk, eps = sizes
+    b, s, _ = zxbcdt.shape
+    d_in, f32 = nh * p, jnp.float32
+    z, xBC, dt = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * g * n], axis=-1)
+    with jax.named_scope("ssm_in"):
+        # causal depthwise conv; tap k-1 weighs the current step
+        k = taps.shape[1]
+        padded = jnp.pad(xBC.astype(f32), ((0, 0), (k - 1, 0), (0, 0)))
+        xBC = sum(padded[:, i:i + s] * taps[:, i].astype(f32)
+                  for i in range(k))
+        xBC = F.silu(xBC + conv_bias.astype(f32)).astype(zxbcdt.dtype)
+        x, B, C = jnp.split(xBC, [d_in, d_in + g * n], axis=-1)
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+        A = -jnp.exp(A_log.astype(f32))
+    with jax.named_scope("ssm_scan"):
+        y = ssd_chunked(x.reshape(b, s, nh, p), dt, A,
+                        B.reshape(b, s, g, n), C.reshape(b, s, g, n), D,
+                        chunk)
+    with jax.named_scope("ssm_out"):
+        # the gated group RMSNorm, gate first
+        y = y.reshape(b, s, d_in) * F.silu(z.astype(f32))
+        yg = y.reshape(b, s, g, d_in // g)
+        yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True) + eps)
+        return (yg.reshape(b, s, d_in)
+                * norm_weight.astype(f32)).astype(zxbcdt.dtype)

@@ -35,8 +35,14 @@ SPANS = {
     "pt.train.sample_fetch": (
         "trainer host", "a sampled step only: telemetry's read of the "
         "loss and the gradient norm, which waits for every step "
-        "dispatched ahead, then gauges and the watchdog",
-        ("interval_steps",), ("telemetry_idle_ms.train",)),
+        "dispatched ahead, then gauges and the watchdog; for a model "
+        "with expert layers also that step's routing counts, summed "
+        "over the expert blocks (moe_rows_max: the fullest held "
+        "expert of the worst block)",
+        ("interval_steps", "moe_rows_routed", "moe_rows_held",
+         "moe_rows_max"),
+        ("telemetry_idle_ms.train", "moe_held_rows_share.train",
+         "moe_expert_load_max_over_mean.train")),
     "pt.train.sync_to_model": (
         "trainer host", "rebinding the model's parameters to the "
         "step's outputs", (), ("idle_attributed_share.train",)),
@@ -84,4 +90,13 @@ SCOPES = {
     "attn_out": ("attention", "output projection and residual"),
     "mlp": ("mlp", "post-attention norm, MLP, residual"),
     "head_loss": ("head", "final norm, lm_head, the loss"),
+    "ssm_in": ("ssm", "a Mamba-2 block's norm, in_proj, causal conv, "
+               "softplus of dt"),
+    "ssm_scan": ("ssm", "the chunked SSD (kernels/ssd.py)"),
+    "ssm_out": ("ssm", "gated group norm, out_proj, residual"),
+    "moe_router": ("moe", "an expert block's norm, router scores, "
+                   "top-k, weights"),
+    "moe_experts": ("moe", "the held experts' rows: sort, gather, the "
+                    "grouped products, the weighted combine"),
+    "moe_shared": ("moe", "the shared expert, residual"),
 }

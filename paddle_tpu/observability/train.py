@@ -91,9 +91,12 @@ class TrainTelemetry:
         return step % self.sample_every == 0
 
     def on_step(self, step: int, loss, grad_norm, tokens: int,
-                wall_s: float):
+                wall_s: float, counters: Optional[dict] = None):
         """``loss``/``grad_norm`` are device scalars (async futures) —
-        they are fetched ONLY on sampled steps."""
+        they are fetched ONLY on sampled steps. ``counters`` are more
+        such scalars the compiled step returned (an expert model's
+        routing counts); a sampled step reads them and they ride as
+        arguments of its ``pt.train.sample_fetch`` span."""
         wall_ms = wall_s * 1e3
         self._steps.inc()
         if tokens:
@@ -111,7 +114,11 @@ class TrainTelemetry:
         # step dispatched ahead, and a trace should say so by name)
         with jax.profiler.TraceAnnotation(
                 "pt.train.sample_fetch",
-                interval_steps=self._interval_steps):
+                interval_steps=self._interval_steps) as span:
+            if counters:
+                rec.update({k: int(v) for k, v in
+                            jax.device_get(counters).items()})
+                span.set_metadata(**{k: rec[k] for k in counters})
             return self._sample(step, loss, grad_norm, rec)
 
     def _sample(self, step: int, loss, grad_norm, rec: dict):

@@ -233,6 +233,16 @@ class TrainStep:
         # the program's output arity is a compile-time shape)
         emit_gnorm = want_tel or bool(flags.flag("check_nan_inf"))
         self._emit_gnorm = emit_gnorm
+        # a model with expert layers hands back its step's routing
+        # counts (``step_counters()``: device scalars of the forward
+        # pass just traced); they leave the compiled step beside the
+        # gradient norm. A model without the method compiles the
+        # program it always did.
+        merge_k = (self.strategy.gradient_merge_k_steps
+                   if getattr(self.strategy, "gradient_merge", False) else 1)
+        emit_counters = (emit_gnorm and merge_k <= 1
+                         and callable(getattr(model, "step_counters", None)))
+        self._emit_counters = emit_counters
         self.telemetry = None
         if want_tel and not abstract:
             self.telemetry = (
@@ -242,8 +252,6 @@ class TrainStep:
 
         model_ref = model
         loss_ref = loss_fn
-        merge_k = (self.strategy.gradient_merge_k_steps
-                   if getattr(self.strategy, "gradient_merge", False) else 1)
         self.gradient_merge_k = merge_k
 
         def loss_of(p, batch, rng):
@@ -264,7 +272,14 @@ class TrainStep:
                 with jax.named_scope("master_cast"):
                     for n, dt in master_dtypes.items():
                         params[n] = opt_state["master"][n].astype(dt)
-            if merge_k <= 1:
+            if emit_counters:
+                def loss_and_counters(p, batch, rng):
+                    loss = loss_of(p, batch, rng)
+                    return loss, model_ref.step_counters()
+
+                (loss, counters), grads = jax.value_and_grad(
+                    loss_and_counters, has_aux=True)(params, batch, rng)
+            elif merge_k <= 1:
                 loss, grads = jax.value_and_grad(loss_of)(
                     params, batch, rng)
             else:
@@ -329,6 +344,8 @@ class TrainStep:
                 # XLA dead-code-eliminates the cast-back
                 new_params = {n: v for n, v in new_params.items()
                               if n not in master_dtypes}
+            if emit_counters:
+                return new_params, new_state, loss, gnorm, counters
             if emit_gnorm:
                 return new_params, new_state, loss, gnorm
             return new_params, new_state, loss
@@ -339,6 +356,8 @@ class TrainStep:
                          repl)
         if emit_gnorm:
             out_shardings = out_shardings + (repl,)
+        if emit_counters:
+            out_shardings = out_shardings + (repl,)  # a dict of scalars
         self._step = jax.jit(
             step_fn,
             in_shardings=(
@@ -413,10 +432,13 @@ class TrainStep:
             with jax.profiler.TraceAnnotation("pt.train.shard_batch"):
                 batch = self.shard_batch(batch)
         self._rng_key, sub = jax.random.split(self._rng_key)
-        gnorm = None
+        gnorm = counters = None
         with jax.profiler.TraceAnnotation("pt.train.dispatch"), \
                 mesh_context(self.mesh):
-            if self._emit_gnorm:
+            if self._emit_counters:
+                self.params, self.opt_state, loss, gnorm, counters = \
+                    self._step(self.params, self.opt_state, batch, sub)
+            elif self._emit_gnorm:
                 self.params, self.opt_state, loss, gnorm = self._step(
                     self.params, self.opt_state, batch, sub
                 )
@@ -470,7 +492,7 @@ class TrainStep:
             # sampled step (TrainTelemetry fetches them only then)
             tel.on_step(
                 self.step_count, loss, gnorm, tokens=tokens,
-                wall_s=time.perf_counter() - t0)
+                wall_s=time.perf_counter() - t0, counters=counters)
         with jax.profiler.TraceAnnotation("pt.train.sync_to_model"):
             if not self._master_dtypes:
                 self.sync_to_model()

@@ -24,7 +24,8 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import distributed as dist, observability as obs
 from paddle_tpu import optimizer as opt
-from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
+                               NemotronHConfig, NemotronHForCausalLM)
 from paddle_tpu.observability.spans import SCOPES, SPANS
 from paddle_tpu.trainer import TrainStep
 
@@ -41,9 +42,25 @@ def _telemetry_on():
     pt.flags.set_flags({"FLAGS_telemetry": prev})
 
 
-def _tiny_step(telemetry=True, **kw):
+def _tiny_model(family="llama"):
+    if family == "llama":
+        return LlamaForCausalLM(LlamaConfig.tiny(use_flash_attention=False))
+    return NemotronHForCausalLM(NemotronHConfig.tiny(
+        use_flash_attention=False))
+
+
+# the phases each family's step has: a dense decoder has no state-space
+# and no expert blocks, the hybrid stack has no MLP
+FAMILY_SCOPES = {
+    "llama": {s for s, (phase, _) in SCOPES.items()
+              if phase not in ("ssm", "moe")},
+    "nemotron_h": set(SCOPES) - {"mlp"},
+}
+
+
+def _tiny_step(telemetry=True, family="llama", **kw):
     pt.seed(0)
-    model = LlamaForCausalLM(LlamaConfig.tiny(use_flash_attention=False))
+    model = _tiny_model(family)
     model.to(dtype="bfloat16")  # float32 masters beside bf16 parameters
     mesh = dist.build_mesh(devices=jax.devices()[:1])
     optimizer = opt.AdamW(1e-3, multi_precision=True,
@@ -159,11 +176,13 @@ def test_engine_tick_holds_dispatch_sync_emit_with_integer_arguments(
 
 
 # ---- device phases: metadata, and nothing but metadata
-def _lowered(scoped: bool, monkeypatch):
+def _lowered(scoped: bool, monkeypatch, family="llama"):
     if not scoped:  # the test's own null context: no switch in the program
         monkeypatch.setattr(jax, "named_scope",
                             lambda name: contextlib.nullcontext())
-    ts = _tiny_step(master_residency="master_only")
+    # a rematerialised function's trace is cached with its scopes
+    jax.clear_caches()
+    ts = _tiny_step(master_residency="master_only", family=family)
     ids = jax.ShapeDtypeStruct((2, 16), jnp.int32)
     low = ts.lower({"input_ids": ids, "labels": ids})
     monkeypatch.undo()
@@ -179,14 +198,22 @@ def _without_metadata(hlo_text: str) -> list:
     return [re.sub(r",? ?metadata=\{[^}]*\}", "", ln) for ln in keep]
 
 
+def test_the_families_cover_the_scope_table():
+    assert set().union(*FAMILY_SCOPES.values()) == set(SCOPES)
+
+
+@pytest.mark.parametrize("family,backward", [
+    ("llama", ("mlp",)), ("nemotron_h", ("ssm_scan", "moe_experts"))],
+    ids=["llama", "nemotron_h"])
 def test_every_scope_is_in_some_op_name_and_changes_nothing_else(
-        monkeypatch):
-    scoped = _lowered(True, monkeypatch)
-    plain = _lowered(False, monkeypatch)
+        family, backward, monkeypatch):
+    scopes = FAMILY_SCOPES[family]
+    scoped = _lowered(True, monkeypatch, family)
+    plain = _lowered(False, monkeypatch, family)
     # as traced, every scope is on some operation ...
     traced = scoped.as_text(debug_info=True)
     untraced = plain.as_text(debug_info=True)
-    for scope in SCOPES:
+    for scope in scopes:
         on_a_path = r'loc\("jit\([^"]*[/(]%s[/)][^"]*"' % scope
         assert re.search(on_a_path, traced), scope
         assert not re.search(on_a_path, untraced), scope
@@ -196,11 +223,14 @@ def test_every_scope_is_in_some_op_name_and_changes_nothing_else(
     names = set(re.findall(r'op_name="([^"]*)"', compiled))
     # XLA merges telemetry's norm with the clip's own: grad_norm may be
     # gone from the compiled program, the others have to be there
-    for scope in set(SCOPES) - {"grad_norm"}:
+    for scope in scopes - {"grad_norm"}:
         assert any(re.search(r"[/(]%s[/)]" % scope, n) for n in names), \
             scope
-    # a backward operation carries its scope inside the transforms
-    assert any(re.search(r"transpose\(jvp\(mlp\)\)", n) for n in names)
+    # a backward operation carries its scope inside the transforms (the
+    # rematerialised SSD and the experts' hand-written backward too)
+    for scope in backward:
+        assert any(re.search(r"transpose\(jvp\((.*[/(])?%s[/)]" % scope, n)
+                   for n in names), scope
     assert _without_metadata(compiled) == _without_metadata(
         plain.compile().as_text())
 
