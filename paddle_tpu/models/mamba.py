@@ -247,10 +247,10 @@ def _mamba2_core(zxbcdt, taps, conv_bias, dt_bias, A_log, D, norm_weight,
                  sizes):
     """Between the two projections: conv, the SSD, the gated norm.
     Rematerialised in the backward pass as one piece: its float32
-    intermediates (the padded conv input, the SSD's chunk decays and
-    scores, y, the gated product) are several times the bf16 tensor it
-    starts from and hold no weight product, so what a block saves is
-    ``in_proj``'s output and this function's."""
+    intermediates (the padded conv input, the SSD's y and the states
+    before each chunk, the gated product) are several times the bf16
+    tensor it starts from and hold no weight product, so what a block
+    saves is ``in_proj``'s output and this function's."""
     from ..kernels.ssd import ssd_chunked
 
     nh, p, g, n, chunk, eps = sizes

@@ -23,6 +23,7 @@ from paddle_tpu.kernels import (
     paged_attention,
     pallas_attention,
     quant_matmul,
+    ssd,
 )
 
 BF16 = jnp.bfloat16
@@ -57,7 +58,7 @@ def chip_compile(one_chip, monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache
 
     for mod in (pallas_attention, paged_attention, decode_attention,
-                quant_matmul):
+                quant_matmul, ssd):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     saved = {k: getattr(jax.config, k) for k in
              ("jax_enable_compilation_cache",
@@ -243,6 +244,62 @@ def test_flash_compiles_under_four_chip_mesh(topo, chip_compile,
             fwd_bwd, (qkv, qkv, qkv),
             NamedSharding(mesh, P(("dp", "fsdp"), None, "tp", None)))
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mamba2_core_holds_the_two_ssd_kernels_and_no_chunk_tensor(
+        chip_compile):
+    """A Mamba-2 block of the cell ``nemotron3-nano-train-8k`` between
+    its two projections (conv, SSD, gated norm: ``_mamba2_core``, the
+    stretch the backward pass makes again), forward and backward at the
+    published widths and 1 x 8192 tokens: the SSD is two custom calls,
+    the forward rule's and the backward's (the rematerialised stretch is
+    traced once), and nothing float32 of ``[chunks, heads, Q, Q]``, the
+    einsum form's decays and scores, is left among the program's
+    arrays. The kernels ask for no more VMEM than the flash kernels'
+    budget; the compiler refuses a kernel over its limit."""
+    import json
+    import math
+    import pathlib
+    import re
+
+    from paddle_tpu.models.mamba import _mamba2_core
+
+    root = pathlib.Path(__file__).parent.parent / "chipbench"
+    w = json.loads((root / "configs" /
+                    "nemotron-3-nano-30b-a3b-train.json").read_text())
+    sizes = json.loads((root / "traffic" / "train-8k.json").read_text())
+    b, s = sizes["batch"], sizes["sequence"]
+    nh, p, g, n, chunk = (w["mamba_num_heads"], w["mamba_head_dim"],
+                          w["n_groups"], w["ssm_state_size"],
+                          w["chunk_size"])
+    assert (nh, p, g, n, chunk) == (64, 64, 8, 128, 128)
+    d_in, conv_dim = nh * p, nh * p + 2 * g * n
+
+    def fwd_bwd(*a):
+        return jax.grad(lambda *a: _mamba2_core(
+            *a, (nh, p, g, n, chunk, 1e-5)).astype(jnp.float32).sum(),
+            range(7))(*a)
+
+    f32 = jnp.float32
+    text = chip_compile(fwd_bwd, [
+        ((b, s, 2 * d_in + 2 * g * n + nh), BF16),
+        ((conv_dim, w["conv_kernel"]), f32), ((conv_dim,), f32),
+        ((nh,), f32), ((nh,), f32), ((nh,), f32), ((d_in,), f32),
+    ]).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    per_chunk = (s // chunk) * nh * chunk * chunk
+    big = {m.group(0) for m in re.finditer(
+        r"f32\[([\d,]+),%d,%d\]" % (chunk, chunk), text)
+        if math.prod(map(int, m.group(1).split(","))) * chunk * chunk
+        >= per_chunk}
+    assert not big, sorted(big)
+    # ... and both carry the scope the cell's SSD metric reads (a
+    # backward rule keeps its caller's)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and "custom-call(" in ln]
+    assert len(calls) == 2 and all(
+        re.search(r'op_name="[^"]*ssm_scan', ln) for ln in calls), calls
+    assert ssd._VMEM_LIMIT_BYTES <= pallas_attention._TILE_VMEM_BYTES
 
 
 def test_nemotron_nine_block_step_compiles_and_fits(topo, chip_compile,
