@@ -1,10 +1,10 @@
 """Where this repo keeps JAX's persistent compilation cache.
 
-One rule, for ``chip_smoke.py`` and ``bench.py`` alike: where
-``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and no
-directory is set in code; where it is not, the cache is ``.jax_cache/``
-at the root of the checkout (listed in ``.gitignore``). The directory is
-part of the cache key, so it never moves within a checkout.
+``chip_smoke.py``'s rule: where ``JAX_COMPILATION_CACHE_DIR`` is set,
+JAX reads it itself and no directory is set in code; where it is not,
+the cache is ``.jax_cache/`` at the root of the checkout (listed in
+``.gitignore``). The directory is part of the cache key, so it never
+moves within a checkout.
 """
 
 from __future__ import annotations

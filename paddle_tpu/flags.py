@@ -385,19 +385,6 @@ define_flag("router_retry_schedule", "1,2,4",
             "8/16/32/32/... ticks. Deterministic: the only randomness "
             "is a per-replica jitter drawn from a stream seeded on "
             "(router seed, replica index)")
-define_flag("flash_attention_block_q", 1024,
-            "Pallas flash-attention q GRID block (rows of q a grid "
-            "step holds in VMEM; clamped to the padded sequence). The "
-            "default is the kernel's own choice (DEFAULT_Q_BLOCK): "
-            "with equal q and k blocks a causal kernel works each "
-            "tile in 128-row strips that skip what lies above the "
-            "diagonal, so a large block costs no masked work; "
-            "unequal blocks are worked whole")
-define_flag("flash_attention_block_k", 1024,
-            "Pallas flash-attention k/v GRID block (the online-"
-            "softmax streaming granularity; clamped to the padded "
-            "sequence). Default is the kernel's own choice "
-            "(DEFAULT_K_BLOCK); see flash_attention_block_q")
 define_flag("moe_capacity_factor", 1.25,
             "default MoE expert capacity factor when a layer doesn't "
             "pass one explicitly (capacity = factor * tokens * top_k "

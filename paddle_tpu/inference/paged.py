@@ -229,10 +229,7 @@ def gather_kv(cache: PagedLayerCache, state: PagedState
 
 
 def _use_pallas_decode(cache: PagedLayerCache) -> bool:
-    import os
-
-    import jax as _jax
-
+    from ..kernels import _backend
     from ..kernels.decode_attention import decode_tiles_ok
 
     if cache.k_scale is not None:
@@ -241,10 +238,7 @@ def _use_pallas_decode(cache: PagedLayerCache) -> bool:
         # and this dispatch's fallback is the dense dequant reference
         return False
     page_size, d = cache.k_pages.shape[2], cache.k_pages.shape[3]
-    aligned = decode_tiles_ok(d, page_size)
-    if os.environ.get("PADDLE_TPU_FORCE_PALLAS"):
-        return aligned
-    return aligned and _jax.default_backend() == "tpu"
+    return _backend.use_kernel(decode_tiles_ok(d, page_size))
 
 
 def paged_attention(q, cache: PagedLayerCache, state: PagedState,

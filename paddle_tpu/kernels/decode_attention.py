@@ -44,9 +44,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import flags
+from . import _backend
 from .paged_attention import (
     NEG_INF,
-    _interpret,
     append_tile_rows,
     kernel_quant_rows,
     kernel_rope_rot,
@@ -107,7 +107,7 @@ def fused_decode_active(head_dim: int, minor: int, dtype=None) -> bool:
     val = str(flags.flag("fused_decode")).lower()
     if val in ("off", "0", "false", "no"):
         return False
-    if jax.default_backend() != "tpu":
+    if _backend.interpret():
         return val in ("on", "1", "true", "yes")
     if val in ("on", "1", "true", "yes"):
         return True
@@ -376,7 +376,7 @@ def fused_contiguous_decode_attention(q, k_new, v_new, ck, cv, seq_lens,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(jnp.asarray(seq_lens, jnp.int32),
       jnp.asarray(positions, jnp.int32),
       *operands)

@@ -19,6 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from . import _backend
+
 
 def _reference_attention(q, k, v, causal=False, scale=None, bias=None,
                          window=0):
@@ -46,20 +48,12 @@ def _reference_attention(q, k, v, causal=False, scale=None, bias=None,
 
 
 def _use_pallas(q) -> bool:
-    import os
-
     b, s, h, d = q.shape
     # seq must tile into 128-blocks; head_dim only needs sublane (8)
     # alignment — the kernel zero-pads d to the lane width internally
     # (exact; see pallas_attention._fold), so 64/96-dim heads (GPT/ViT)
     # take the flash path instead of dense XLA attention.
-    aligned = s % 128 == 0 and d % 8 == 0
-    if os.environ.get("PADDLE_TPU_FORCE_PALLAS"):
-        # CI/dryrun override: run the Pallas kernel in interpret mode off
-        # TPU so the graft entry exercises the real kernel code path
-        return aligned
-    # Pallas kernel wants MXU/VPU-aligned tiles
-    return aligned and jax.default_backend() == "tpu"
+    return _backend.use_kernel(s % 128 == 0 and d % 8 == 0)
 
 
 def flash_attention(
@@ -147,17 +141,12 @@ def _mesh_axes(mesh, names, n: int):
 
 def _pallas_flash_attention(q, k, v, causal=False, scale=None,
                             segment_ids=None, window=0):
-    from .. import flags
     from ..distributed.sharding import constraints_suppressed, current_mesh
     from .pallas_attention import mha as pallas_mha
 
     def attend(q, k, v, segment_ids=None):
-        # VMEM tile shape knobs (PT_FLAGS_flash_attention_block_{q,k});
-        # mha clamps them to the actual (padded) sequence internally
         return pallas_mha(
             q, k, v, causal=causal, sm_scale=scale,
-            q_block=int(flags.flag("flash_attention_block_q")),
-            k_block=int(flags.flag("flash_attention_block_k")),
             segment_ids=segment_ids, window=window)
 
     mesh = current_mesh()

@@ -49,9 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from . import _backend
 
 
 # VMEM the fused path may assume per grid step: the backward holds
@@ -175,7 +173,7 @@ def _gn_fwd_pallas(x3, gamma, beta, num_groups, eps, act):
             jax.ShapeDtypeStruct((n, g), f32),
             jax.ShapeDtypeStruct((n, g), f32),
         ),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(x3, gamma.reshape(1, c).astype(f32), beta.reshape(1, c).astype(f32),
       gmat)
 
@@ -214,7 +212,7 @@ def _gn_bwd_pallas(x3, dy3, gamma, beta, mean, rstd, num_groups, act):
             jax.ShapeDtypeStruct((n, 1, c), f32),
             jax.ShapeDtypeStruct((n, 1, c), f32),
         ),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(x3, dy3, gamma.reshape(1, c).astype(f32),
       beta.reshape(1, c).astype(f32), gmat, mean, rstd)
     return dx, dgam.sum(axis=(0, 1)), dbeta.sum(axis=(0, 1))

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import _backend
+
 NEG_INF = -1e30
 # THE int8-KV quantization epsilon (scale = max(absmax/127, eps)) —
 # one constant shared by the in-kernel quantize-on-append below and
@@ -39,10 +41,6 @@ NEG_INF = -1e30
 # a divergent eps would silently break the fused-vs-unfused
 # bit-identical-pools contract
 KV_QUANT_EPS = 1e-8
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _decode_kernel(bt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
@@ -157,7 +155,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(seq_lens, jnp.int32), q, k_pages, v_pages)
     return out[:, :, :group, :]
@@ -479,7 +477,7 @@ def fused_paged_decode_attention(q, k_new, v_new, k_pages, v_pages,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(jnp.asarray(block_tables, jnp.int32),
       jnp.asarray(seq_lens, jnp.int32),
       jnp.asarray(positions, jnp.int32),

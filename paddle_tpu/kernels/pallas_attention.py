@@ -71,6 +71,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import _backend
+
 # Blocks are the GRID tiles: few grid steps and large DMAs (2048-blocks
 # do not fit VMEM). The causal triangle is cut finer than that inside
 # the kernels, see _tile_strips.
@@ -89,11 +91,6 @@ _TILE_VMEM_BYTES = 16 << 20
 # accumulator and the double-buffered output block); past this budget
 # the two-pass backward runs. 16 MiB is s = 4096 at rep 4, d 128, bf16.
 _FUSED_DQ_VMEM_BUDGET = 16 << 20
-
-
-def _interpret() -> bool:
-    # run the kernel in interpreter mode off-TPU (CPU CI parity tests)
-    return jax.default_backend() != "tpu"
 
 
 def _causal_j_max(i: int, q_block: int, k_block: int):
@@ -419,7 +416,7 @@ def _mha_fwd_impl(q, k, v, qseg, kseg, sm_scale, causal, q_block, k_block,
             scratch_shapes=scratch,
             cost_estimate=cost,
             compiler_params=params,
-            interpret=_interpret(),
+            interpret=_backend.interpret(),
         )(*inputs)
     lse_spec = pl.BlockSpec((1, 1, q_block, LANES),
                             lambda b, r, i, j: (b, r, i, 0))
@@ -435,7 +432,7 @@ def _mha_fwd_impl(q, k, v, qseg, kseg, sm_scale, causal, q_block, k_block,
         scratch_shapes=scratch,
         cost_estimate=cost,
         compiler_params=params,
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(*inputs)
     return o, lse[:, :, :, 0]
 
@@ -656,7 +653,7 @@ def _mha_bwd_impl(q, k, v, o, do, lse, qseg, kseg, sm_scale, causal,
                 vmem_limit_bytes=(
                     _TILE_VMEM_BYTES + dq_vmem if fused else None),
             ),
-            interpret=_interpret(),
+            interpret=_backend.interpret(),
         )(*inputs)
 
     if dq_vmem <= _FUSED_DQ_VMEM_BUDGET:

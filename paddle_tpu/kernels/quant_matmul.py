@@ -24,9 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from . import _backend
 
 
 def quantize_weight_int8_grouped(w: jax.Array, group_size: int = 128):
@@ -175,7 +173,7 @@ def weight_only_matmul_pallas(x, qweight, scale, *, group_size=128,
         out_specs=pl.BlockSpec((m_block, n_block), lambda i, j, kb: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((m_block, n_block), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(x, qweight, scale3)
 
 

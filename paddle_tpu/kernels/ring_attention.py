@@ -28,7 +28,6 @@ with the flash kernel's custom VJP per block (dlse folded into delta).
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -36,14 +35,14 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from . import _backend
+
 NEG_INF = -1e30
 
 
 def _use_flash(sq, sk, d) -> bool:
-    aligned = sq % 128 == 0 and sk % 128 == 0 and d % 128 == 0
-    if os.environ.get("PADDLE_TPU_FORCE_PALLAS"):
-        return aligned
-    return aligned and jax.default_backend() == "tpu"
+    return _backend.use_kernel(
+        sq % 128 == 0 and sk % 128 == 0 and d % 128 == 0)
 
 
 def _attn_lse(q, k, v, causal, scale):

@@ -40,9 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from . import _backend
 
 
 def _scan_kernel(u_ref, delta_ref, b_ref, c_ref, at_ref, *out_refs,
@@ -127,7 +125,7 @@ def _scan_fwd_pallas(u, delta, B, C, at, chunk, d_block, with_states):
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=y_spec,
             out_shape=jax.ShapeDtypeStruct((b, s, d), f32),
-            scratch_shapes=scratch, interpret=_interpret(),
+            scratch_shapes=scratch, interpret=_backend.interpret(),
         )(*args)
     h0_spec = pl.BlockSpec((1, 1, n, d_block),
                            lambda ib, id_, ic: (ib, ic, 0, id_))
@@ -137,7 +135,7 @@ def _scan_fwd_pallas(u, delta, B, C, at, chunk, d_block, with_states):
             jax.ShapeDtypeStruct((b, s, d), f32),
             jax.ShapeDtypeStruct((b, n_chunks, n, d), f32),
         ),
-        scratch_shapes=scratch, interpret=_interpret(),
+        scratch_shapes=scratch, interpret=_backend.interpret(),
     )(*args)
 
 
@@ -271,7 +269,7 @@ def _scan_bwd_pallas(u, delta, B, C, at, h0s, g, chunk, d_block):
             pltpu.VMEM((chunk, n, d_block), f32),   # recomputed states
             pltpu.VMEM((n, d_block), f32),          # dat accumulator
         ],
-        interpret=_interpret(),
+        interpret=_backend.interpret(),
     )(u.astype(f32), delta.astype(f32), B.astype(f32), C.astype(f32),
       at, h0s, g.astype(f32))
     return du, ddelta, db.sum(0), dc.sum(0), dat.sum(0)

@@ -68,11 +68,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import _backend
+
 F32 = jnp.float32
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _dot(a, b, contract):
@@ -285,7 +283,7 @@ def _fwd_call(x, B, C, dtr, csr, dtc, csc, d_row, *, chunk):
         out_shape=[shape(x.shape, F32),
                    shape((b, s // chunk, g * heads * p, n), F32)],
         scratch_shapes=[pltpu.VMEM((heads * p, n), F32)],
-        compiler_params=_PARAMS, interpret=_interpret(),
+        compiler_params=_PARAMS, interpret=_backend.interpret(),
     )(x, B, C, dtr, csr, dtc, csc, d_row)
 
 
@@ -311,7 +309,7 @@ def _bwd_call(x, B, C, dtr, csr, dtc, csc, d_row, states, dy, *, chunk):
                    shape((b, g, 1, heads * p), F32)],
         scratch_shapes=[pltpu.VMEM((heads * p, n), F32),
                         pltpu.VMEM((1, heads * p), F32)],
-        compiler_params=_PARAMS, interpret=_interpret(),
+        compiler_params=_PARAMS, interpret=_backend.interpret(),
     )(x, B, C, dtr, csr, dtc, csc, d_row, states, dy,
       jnp.repeat(jnp.eye(heads, dtype=F32), p, axis=0))
 

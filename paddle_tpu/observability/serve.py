@@ -13,8 +13,8 @@ distinct series on the same ``/metrics`` scrape, and one engine's
 
 ``window_reset()`` clears the raw percentile windows (histogram-side)
 and peak trackers without touching the cumulative Prometheus totals,
-so a benchmark sweep (benchmarks/suite.py ``_run_load``) reads
-per-window percentiles from the same registry a live scrape sees.
+so a load sweep reads per-window percentiles from the same registry a
+live scrape sees.
 """
 
 from __future__ import annotations

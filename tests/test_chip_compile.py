@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.kernels import (
+    _backend,
     decode_attention,
     paged_attention,
     pallas_attention,
@@ -57,9 +58,7 @@ def chip_compile(one_chip, monkeypatch):
     numerics tests; Mosaic refuses an fp32-precision bf16 matmul)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    for mod in (pallas_attention, paged_attention, decode_attention,
-                quant_matmul, ssd):
-        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    monkeypatch.setattr(_backend, "interpret", lambda: False)
     saved = {k: getattr(jax.config, k) for k in
              ("jax_enable_compilation_cache",
               "jax_default_matmul_precision")}
@@ -218,8 +217,7 @@ def test_flash_is_one_forward_and_one_backward_call(chip_compile):
         assert sum(bool(rx.search(ln)) for ln in lines) == 1, name
 
 
-def test_flash_compiles_under_four_chip_mesh(topo, chip_compile,
-                                             monkeypatch):
+def test_flash_compiles_under_four_chip_mesh(topo, chip_compile):
     """The ZeRO-3 train step's attention on the 2x2 host: a bare Pallas
     call there is refused ("Mosaic kernels cannot be automatically
     partitioned. Please wrap the call in a shard_map"), so
@@ -230,8 +228,6 @@ def test_flash_compiles_under_four_chip_mesh(topo, chip_compile,
     from paddle_tpu.distributed.sharding import mesh_context
     from paddle_tpu.kernels.flash_attention import flash_attention
 
-    # _use_pallas asks default_backend(), which is the CPU here
-    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
     mesh = dist.build_mesh(fsdp=4, devices=list(topo.devices))
     qkv = ((4, 2048, HEADS, D), BF16)
 
@@ -302,8 +298,7 @@ def test_mamba2_core_holds_the_two_ssd_kernels_and_no_chunk_tensor(
     assert ssd._VMEM_LIMIT_BYTES <= pallas_attention._TILE_VMEM_BYTES
 
 
-def test_nemotron_nine_block_step_compiles_and_fits(topo, chip_compile,
-                                                   monkeypatch):
+def test_nemotron_nine_block_step_compiles_and_fits(topo, chip_compile):
     """The whole train step of the cell ``nemotron3-nano-train-8k`` as
     the benchmark builds it (nine blocks MEMEM*EME at the published
     widths, 8 of 128 experts held, an eighth of the vocabulary, 1 x 8192
@@ -320,7 +315,6 @@ def test_nemotron_nine_block_step_compiles_and_fits(topo, chip_compile,
     from paddle_tpu.models import NemotronHConfig, NemotronHForCausalLM
     from paddle_tpu.trainer import TrainStep
 
-    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
     root = pathlib.Path(__file__).parent.parent / "chipbench"
     w = json.loads((root / "configs" /
                     "nemotron-3-nano-30b-a3b-train.json").read_text())

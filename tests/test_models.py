@@ -108,3 +108,91 @@ def test_mamba_lm_trains():
         params, state, loss = step(params, state)
         losses.append(float(loss))
     assert losses[-1] < losses[0] * 0.9, losses
+
+
+# ---------------- every family through TrainStep ----------------
+
+def _vit_job():
+    from paddle_tpu.nn import functional as F
+
+    cfg = ViTConfig.tiny()
+    rng = np.random.default_rng(0)
+    data = {
+        "input": jnp.asarray(rng.standard_normal(
+            (4, cfg.num_channels, cfg.image_size, cfg.image_size)),
+            jnp.float32),
+        "label": jnp.asarray(rng.integers(0, cfg.num_classes, (4,))),
+    }
+    return ViT(cfg), data, lambda logits, label: F.cross_entropy(
+        logits, label).mean()
+
+
+def _unet_job():
+    from paddle_tpu.core.module import Layer
+    from paddle_tpu.models import UNet2DConditionModel, UNetConfig
+
+    cfg = UNetConfig.tiny(sample_size=8)
+    unet = UNet2DConditionModel(cfg)
+
+    class DenoisingMSE(Layer):
+        """The model returns its own loss (TrainStep's self-loss
+        path): the MSE of the predicted against the given noise."""
+
+        def __init__(self):
+            super().__init__()
+            self.unet = unet
+
+        def forward(self, sample, timestep, context, target):
+            pred = self.unet(sample, timestep, context)
+            return jnp.mean((pred.astype(jnp.float32)
+                             - target.astype(jnp.float32)) ** 2)
+
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal(
+        (1, cfg.in_channels, cfg.sample_size, cfg.sample_size)),
+        jnp.float32)
+    data = {
+        "sample": x,
+        "timestep": jnp.asarray(rng.integers(0, 1000, (1,))),
+        "context": jnp.asarray(rng.standard_normal(
+            (1, 77, cfg.cross_attention_dim)), jnp.float32),
+        "target": x,
+    }
+    return DenoisingMSE(), data, None
+
+
+def _token_job(model, vocab_size, batch, seq):
+    ids = jnp.asarray(np.random.default_rng(0).integers(
+        0, vocab_size, (batch, seq)))
+    return model, {"input_ids": ids, "labels": ids}, None
+
+
+def _moe_job():
+    from paddle_tpu.models import ErnieMoEConfig, ErnieMoEForCausalLM
+
+    cfg = ErnieMoEConfig.tiny(hidden_dropout_prob=0.0,
+                              attention_probs_dropout_prob=0.0)
+    return _token_job(ErnieMoEForCausalLM(cfg), cfg.vocab_size, 2, 128)
+
+
+def _mamba_job():
+    cfg = MambaConfig.tiny(use_chunked_scan=True, scan_chunk=32)
+    return _token_job(MambaForCausalLM(cfg), cfg.vocab_size, 2, 64)
+
+
+@pytest.mark.parametrize("family", ["vit", "unet", "moe", "mamba"])
+def test_family_trains_through_trainstep(family):
+    """The families TrainStep meets nowhere else, each with its own
+    batch kind (images and labels with a ``loss_fn=`` adapter; latents,
+    timesteps and context under a self-loss wrapper; token ids): two
+    ``TrainStep.run`` calls on a one-device mesh, both losses finite
+    and the second not the first (the update reached the parameters)."""
+    pt.seed(0)
+    model, data, loss_fn = {"vit": _vit_job, "unet": _unet_job,
+                            "moe": _moe_job, "mamba": _mamba_job}[family]()
+    mesh = dist.build_mesh(devices=jax.devices()[:1])
+    ts = TrainStep(model, opt.AdamW(1e-4, multi_precision=True), mesh,
+                   loss_fn=loss_fn)
+    first, second = float(ts.run(data)), float(ts.run(data))
+    assert np.isfinite(first) and np.isfinite(second)
+    assert second != first

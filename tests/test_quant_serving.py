@@ -201,7 +201,8 @@ def test_int8_weight_spec_parity(paged, serving_flags):
     outs, snaps = spec_parity_outputs(
         model,
         lambda: tiny_ecfg(paged, weight_dtype="int8"),
-        prompts, serving_flags, flags_extra={"prefix_cache": True})
+        prompts, serving_flags, flags_extra={"prefix_cache": True},
+        replay=True)
     assert_spec_parity(outs, snaps)
 
 
@@ -217,7 +218,8 @@ def test_int8_kv_spec_parity(paged, serving_flags):
         model,
         lambda: tiny_ecfg(paged, cache_dtype="int8",
                           weight_dtype="int8"),
-        prompts, serving_flags, flags_extra={"prefix_cache": True})
+        prompts, serving_flags, flags_extra={"prefix_cache": True},
+        replay=True)
     assert_spec_parity(outs, snaps)
 
 
@@ -516,10 +518,18 @@ def test_int8_weight_serving_program_set_pinned(compile_counter,
     unit = rng.integers(1, cfg.vocab_size, 4)
     prompts = [np.concatenate([unit] * 4),
                rng.integers(1, cfg.vocab_size, 11)]
+    class RepeatDrafter(Drafter):
+        """Always proposes, so a verify pass is dispatched whatever
+        this seed's model emits (the n-gram drafter found nothing)."""
+
+        def propose(self, history, k):
+            return np.full((k,), int(history[-1]), np.int64)
+
     serving_flags({"spec_decode": "ngram", "prefix_cache": True})
     eng = ContinuousBatchingEngine(
         model, tiny_ecfg(True, cache_dtype="int8",
-                         weight_dtype="int8"))
+                         weight_dtype="int8"),
+        drafter=RepeatDrafter())
     eng.run(prompts, max_new_tokens=20)
     # full-cover readmission: prefix adopt + COW page copy
     eng.run([prompts[0]], max_new_tokens=20)

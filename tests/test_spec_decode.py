@@ -35,6 +35,7 @@ from paddle_tpu.inference.spec_decode import Drafter, NgramDrafter
 # PR 9: the quant parity tests reuse the same comparison instead of
 # copy-pasting it); serving_flags comes from conftest now
 from serving_utils import (
+    ReplayDrafter,
     assert_spec_parity,
     drain as _drain,
     mixed_prompts as _mixed_prompts,
@@ -442,7 +443,12 @@ def test_step_adaptive_parity_spec_on_and_off(serving_flags):
     for mode in ("off", "ngram"):
         serving_flags({"spec_decode": mode})
         for sched in ("chunk", "adaptive"):
-            eng = ContinuousBatchingEngine(model, _ecfg(True))
+            # the spec arms draft the off arm's own continuation: a
+            # verify pass is certain, and all four arms must still agree
+            drafter = ReplayDrafter(prompts, outs[("off", "chunk")]) \
+                if mode == "ngram" else None
+            eng = ContinuousBatchingEngine(model, _ecfg(True),
+                                           drafter=drafter)
             rids = [eng.add_request(p, max_new_tokens=12)
                     for p in prompts]
             if sched == "chunk":

@@ -532,29 +532,3 @@ def test_dump_cli_trace(capsys, obs_flags):
     assert dump.main(["--trace-jsonl"]) == 0
     lines = capsys.readouterr().out.strip().splitlines()
     assert any(json.loads(l)["name"] == "queued" for l in lines)
-
-
-# ---------------- goodput bench scenario ----------------
-
-def test_goodput_scenario_emits_per_qps_rows():
-    """bench_serve7b's closed-loop load generator: one JSON row per
-    QPS step with goodput-under-SLO + p99 TTFT/TPOT."""
-    from benchmarks.suite import _goodput_scenario
-
-    model, cfg = _model(10)
-    ecfg = _ecfg(True, max_slots=2, max_len=64, page_size=8)
-    out = _goodput_scenario(model, ecfg, tpu=False)
-    assert out["slo_class"] == "interactive"
-    assert len(out["sweep"]) == 2
-    json.dumps(out)  # ledger-serializable
-    for row in out["sweep"]:
-        assert row["qps"] > 0
-        assert row["n_requests"] == out["n_requests_per_step"]
-        assert row["slo_met"] + row["slo_violated"] == row["n_requests"]
-        assert row["goodput"] == pytest.approx(
-            row["slo_met"] / row["n_requests"])
-        assert row["p99_ttft_ms"] > 0
-        assert row["p99_tpot_ms"] is None or row["p99_tpot_ms"] > 0
-        assert row["served_tokens_per_sec"] > 0
-        assert row["goodput_tokens_per_sec"] <= \
-            row["served_tokens_per_sec"]
