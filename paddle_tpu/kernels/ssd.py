@@ -42,9 +42,15 @@ float32 state or cotangent enters a product, that too are rounded to it
 just before, as XLA's default precision does on the chip) and
 accumulates in float32. ``y`` leaves in float32.
 
-Memory: nothing of ``[.., Q, Q]`` is kept. ``Mamba2Mixer`` rematerialises
-the whole stretch between its two projections, this function included,
-so the states' residual lives only inside one block's backward pass.
+Memory: nothing of ``[.., Q, Q]`` is kept. What the backward kernel reads
+is the forward rule's residual: the kernel's operands (``x``, ``B``,
+``C`` in their type, ``dt`` and the cumulative sums in both layouts) and
+the float32 states, 134 MB a block at the benchmark's shape. The rule
+marks ``dt``, the cumulative sums and the states ``RESIDUAL_NAMES``
+(``jax.ad_checkpoint.checkpoint_name``), so that a caller under
+``jax.checkpoint`` can keep them by its policy and the forward kernel
+runs once: ``models/mamba.py:_mamba2_core`` does, and makes ``x``, ``B``,
+``C`` again from what it keeps of its own.
 
 Shapes: any the recurrence has; on the chip the blocks must tile
 (``chunk`` and ``n`` multiples of 128, a group's ``heads * p`` a
@@ -65,12 +71,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _backend
 
 F32 = jnp.float32
+# what of the forward rule's residual only this module can make, by
+# name: dt and the cumulative sums in both layouts, and D's row; the
+# state before each chunk
+RESIDUAL_NAMES = ("ssd_decays", "ssd_states")
 
 
 def _dot(a, b, contract):
@@ -325,7 +336,9 @@ def _ssd(x, B, C, dtr, csr, dtc, csc, d_row, chunk):
 
 def _ssd_fwd(x, B, C, dtr, csr, dtc, csc, d_row, chunk):
     y, states = _fwd_call(x, B, C, dtr, csr, dtc, csc, d_row, chunk=chunk)
-    return y, (x, B, C, dtr, csr, dtc, csc, d_row, states)
+    decays, states = (checkpoint_name(v, name) for name, v in zip(
+        RESIDUAL_NAMES, ((dtr, csr, dtc, csc, d_row), states)))
+    return y, (x, B, C, *decays, states)
 
 
 def _ssd_bwd(chunk, res, dy):

@@ -28,11 +28,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..core import initializer as I
 from ..core.module import Layer
 from ..distributed.parallel_layers import VocabParallelEmbedding
 from ..distributed.sharding import shard_activation
+from ..kernels.ssd import RESIDUAL_NAMES as _SSD_RESIDUAL_NAMES, ssd_chunked
 from ..nn import functional as F
 from ..nn.layer.common import LayerList, Linear
 from ..nn.layer.norm import RMSNorm
@@ -242,17 +244,29 @@ class Mamba2Mixer(Layer):
             return self.out_proj(y)
 
 
-@functools.partial(jax.checkpoint, static_argnums=(7,))
+# what ``_mamba2_core``'s forward keeps for its backward pass: the conv's
+# float32 pre-activation, the SSD's ``y``, decays and states, each group's
+# mean and rsqrt. The kernels' bf16 ``x``, ``B``, ``C`` and the gated
+# product are made again from these, element-wise: with them kept too the
+# benchmark's step no longer fits one v5e by the compiler's count, and
+# XLA makes weight products again
+_MAMBA2_KEPT = ("mamba2_conv_pre", "mamba2_y", "mamba2_group_mean",
+                "mamba2_group_rsqrt", *_SSD_RESIDUAL_NAMES)
+
+
+@functools.partial(
+    jax.checkpoint, static_argnums=(7,),
+    policy=jax.checkpoint_policies.save_only_these_names(*_MAMBA2_KEPT))
 def _mamba2_core(zxbcdt, taps, conv_bias, dt_bias, A_log, D, norm_weight,
                  sizes):
-    """Between the two projections: conv, the SSD, the gated norm.
-    Rematerialised in the backward pass as one piece: its float32
-    intermediates (the padded conv input, the SSD's y and the states
-    before each chunk, the gated product) are several times the bf16
-    tensor it starts from and hold no weight product, so what a block
-    saves is ``in_proj``'s output and this function's."""
-    from ..kernels.ssd import ssd_chunked
-
+    """Between the two projections: conv, the SSD, the gated norm. The
+    conv's sums, the SSD's kernel and cumulative sums and the norm's
+    group sums run once: the forward keeps what the backward reads of
+    them (``_MAMBA2_KEPT``), and what the backward makes again is
+    element-wise and fuses into its readers (casts, ``silu``,
+    ``softplus``, ``exp``, the gate's product, the pad's slices). A
+    caller short of memory at other shapes recomputes whole blocks
+    (``distributed/sharding.py:recompute``)."""
     nh, p, g, n, chunk, eps = sizes
     b, s, _ = zxbcdt.shape
     d_in, f32 = nh * p, jnp.float32
@@ -263,7 +277,8 @@ def _mamba2_core(zxbcdt, taps, conv_bias, dt_bias, A_log, D, norm_weight,
         padded = jnp.pad(xBC.astype(f32), ((0, 0), (k - 1, 0), (0, 0)))
         xBC = sum(padded[:, i:i + s] * taps[:, i].astype(f32)
                   for i in range(k))
-        xBC = F.silu(xBC + conv_bias.astype(f32)).astype(zxbcdt.dtype)
+        xBC = checkpoint_name(xBC + conv_bias.astype(f32), "mamba2_conv_pre")
+        xBC = F.silu(xBC).astype(zxbcdt.dtype)
         x, B, C = jnp.split(xBC, [d_in, d_in + g * n], axis=-1)
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
         A = -jnp.exp(A_log.astype(f32))
@@ -273,8 +288,11 @@ def _mamba2_core(zxbcdt, taps, conv_bias, dt_bias, A_log, D, norm_weight,
                         chunk)
     with jax.named_scope("ssm_out"):
         # the gated group RMSNorm, gate first
-        y = y.reshape(b, s, d_in) * F.silu(z.astype(f32))
+        y = checkpoint_name(y.reshape(b, s, d_in), "mamba2_y")
+        y = y * F.silu(z.astype(f32))
         yg = y.reshape(b, s, g, d_in // g)
-        yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True) + eps)
+        mean = checkpoint_name(jnp.mean(yg * yg, -1, keepdims=True) + eps,
+                               "mamba2_group_mean")
+        yg = yg * checkpoint_name(jax.lax.rsqrt(mean), "mamba2_group_rsqrt")
         return (yg.reshape(b, s, d_in)
                 * norm_weight.astype(f32)).astype(zxbcdt.dtype)

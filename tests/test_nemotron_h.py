@@ -139,3 +139,45 @@ def test_dense_model_step_returns_no_counters():
     ids = jax.ShapeDtypeStruct((2, 16), jnp.int32)
     out = ts.lower({"input_ids": ids, "labels": ids}).out_info
     assert len(out) == 4  # params, state, loss, grad_norm
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+def test_mamba2_core_keeps_what_recomputing_it_whole_would_give(dtype):
+    """``_mamba2_core`` keeps what its backward pass reads. Its gradients,
+    on all seven arguments at the benchmark's rehearsal widths, are those
+    of the same function made again whole in the backward pass (a plain
+    ``jax.checkpoint`` with no policy): a value kept is the value made
+    again, so they agree to the last bit."""
+    from chipbench.run import load_json
+    from paddle_tpu.models.mamba import _mamba2_core
+
+    w = load_json("configs", "nemotron-3-nano-30b-a3b-train.json")[
+        "rehearsal"]
+    nh, p, g, n = (w["mamba_num_heads"], w["mamba_head_dim"], w["n_groups"],
+                   w["ssm_state_size"])
+    d_in, conv_dim = nh * p, nh * p + 2 * g * n
+    sizes = (nh, p, g, n, w["chunk_size"], w["layer_norm_epsilon"])
+    keys = iter(jax.random.split(jax.random.PRNGKey(35), 8))
+
+    def draw(shape, scale=1.0, dtype=jnp.float32):
+        return (scale * jax.random.normal(next(keys), shape)).astype(dtype)
+
+    args = (draw((2, 128, d_in + conv_dim + nh), dtype=dtype),
+            draw((conv_dim, w["conv_kernel"]), 0.5), draw((conv_dim,), 0.1),
+            draw((nh,)), draw((nh,), 0.5), 1 + draw((nh,), 0.1),
+            1 + draw((d_in,), 0.1))
+    cot = draw((2, 128, d_in))
+
+    def grad_of(core):
+        return jax.grad(lambda *a: jnp.sum(
+            core(*a, sizes).astype(jnp.float32) * cot), range(7))
+
+    whole = jax.checkpoint(_mamba2_core.__wrapped__, static_argnums=(7,))
+    kernels = [str(jax.make_jaxpr(grad_of(core))(*args)).count("pallas_call")
+               for core in (_mamba2_core, whole)]
+    assert kernels == [2, 3], kernels
+    for got, want in zip(jax.jit(grad_of(_mamba2_core))(*args),
+                         jax.jit(grad_of(whole))(*args)):
+        assert float(jnp.abs(want).max()) > 0
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -227,7 +227,8 @@ def test_every_scope_is_in_some_op_name_and_changes_nothing_else(
         assert any(re.search(r"[/(]%s[/)]" % scope, n) for n in names), \
             scope
     # a backward operation carries its scope inside the transforms (the
-    # rematerialised SSD and the experts' hand-written backward too)
+    # SSD's backward rule under the mixer's ``jax.checkpoint`` and the
+    # experts' hand-written backward too)
     for scope in backward:
         assert any(re.search(r"transpose\(jvp\((.*[/(])?%s[/)]" % scope, n)
                    for n in names), scope
