@@ -37,10 +37,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import _backend
+
+# what the forward rule leaves for the backward beside its own arguments,
+# by name: the state before each chunk. A caller under ``jax.checkpoint``
+# keeps it by its policy, and the forward kernel is not run again
+# (``models/mamba.py:_s6_core``)
+RESIDUAL_NAMES = ("s6_states",)
 
 
 def _scan_kernel(u_ref, delta_ref, b_ref, c_ref, at_ref, *out_refs,
@@ -290,7 +297,8 @@ def _chunked_fwd(u, delta, A, B, C, D, chunk, d_block):
     y, h0s = _scan_fwd_pallas(u, delta, B, C, at, chunk, d_block,
                               with_states=True)
     out = y + u.astype(f32) * D[None, None].astype(f32)
-    return out, (u, delta, A, B, C, D, h0s)
+    return out, (u, delta, A, B, C, D,
+                 checkpoint_name(h0s, RESIDUAL_NAMES[0]))
 
 
 def _chunked_bwd(chunk, d_block, res, g):
@@ -312,7 +320,8 @@ def _chunked_bwd(chunk, d_block, res, g):
 _chunked_scan.defvjp(_chunked_fwd, _chunked_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "d_block"))
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("chunk", "d_block"))
 def chunked_selective_scan(u, delta, A, B, C, D, *, chunk=128,
                            d_block=None):
     """y[b,s,d] for h_t = exp(Δ_t A)·h_{t-1} + Δ_t u_t B_t, y_t = C_t·h_t

@@ -17,6 +17,7 @@ from .llama import (  # noqa: F401
 )
 from .mamba import Mamba2Mixer, MambaConfig, MambaForCausalLM  # noqa: F401
 from .nemotron_h import NemotronHConfig, NemotronHForCausalLM  # noqa: F401
+from .phi4flash import Phi4FlashConfig, Phi4FlashForCausalLM  # noqa: F401
 from .rwkv import RWKVConfig, RWKVForCausalLM  # noqa: F401
 from .t5 import (  # noqa: F401
     T5Config,

@@ -34,6 +34,11 @@ from ..core import initializer as I
 from ..core.module import Layer
 from ..distributed.parallel_layers import VocabParallelEmbedding
 from ..distributed.sharding import shard_activation
+from ..kernels.selective_scan import (
+    RESIDUAL_NAMES as _S6_RESIDUAL_NAMES,
+    associative_selective_scan as selective_scan,
+    chunked_selective_scan,
+)
 from ..kernels.ssd import RESIDUAL_NAMES as _SSD_RESIDUAL_NAMES, ssd_chunked
 from ..nn import functional as F
 from ..nn.layer.common import LayerList, Linear
@@ -55,6 +60,13 @@ class MambaConfig:
     # divisible by scan_chunk
     use_chunked_scan: bool = False
     scan_chunk: int = 128
+    # initialisation: matrices normal(0, initializer_range); softplus of
+    # dt_proj's bias is log-uniform in [time_step_min, time_step_max],
+    # floored
+    initializer_range: float = 0.02
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
 
     @property
     def d_inner(self):
@@ -70,29 +82,63 @@ class MambaConfig:
         return cls(**kw)
 
 
-# canonical implementation lives beside the Pallas kernel; re-exported
-# here under its historical name
-from ..kernels.selective_scan import (  # noqa: E402
-    associative_selective_scan as selective_scan,
-)
+def _dt_bias_init(time_step_min, time_step_max, time_step_floor):
+    """An initializer: softplus(bias) is log-uniform in [min, max],
+    floored (both Mamba layers start their step sizes so)."""
+    def init(key, shape, dtype):
+        u = jax.random.uniform(key, shape, jnp.float32)
+        dt = jnp.exp(u * (jnp.log(time_step_max) - jnp.log(
+            time_step_min)) + jnp.log(time_step_min))
+        dt = jnp.maximum(dt, time_step_floor)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+    return init
 
 
 class MambaMixer(Layer):
+    """The Mamba-1 (S6) mixer, on h [b, s, hidden] (the block's norm and
+    residual are the caller's):
+
+        x, z = split(h W_in)                          (no bias)
+        x = silu(causal_depthwise_conv1d(x) + b_conv)
+        dt, B, C = split(x W_x)                       (dt_rank, n, n)
+        delta = softplus(dt W_dt + b_dt);  A = -exp(A_log)
+        s_t = exp(delta_t A) s_{t-1} + (delta_t x_t) B_t^T
+        y_t = s_t C_t + D x_t
+        out = (y * silu(z)) W_out
+
+    ``forward(h, return_scan_output=True)`` also hands out ``y`` before
+    the gate (what a later layer's gated memory unit reads). The conv's
+    sums, ``delta`` and ``A`` are float32. The device phases are the
+    scopes ``s6_in``, ``s6_scan`` and ``s6_out``.
+
+    The recurrence is ``kernels/selective_scan.chunked_selective_scan``
+    where ``use_chunked_scan`` is set and the sequence is a multiple of
+    ``scan_chunk``. **Otherwise it falls back, silently, to the float32
+    associative scan**, which writes two ``[b, s, d_inner, n]`` float32
+    tensors (``2 x b x s x d x n x 4 B``: 2 x 2.7 GB at 1 x 8192 x 5120
+    x 16) and exists for ``MambaForCausalLM``'s CPU tests only; a model
+    meant for the chip checks the sequence itself and raises
+    (``models/phi4flash.py``)."""
+
     def __init__(self, config: MambaConfig):
         super().__init__()
         cfg = config
         d_in = cfg.d_inner
-        init = I.Normal(0.0, 0.02)
+        init = I.Normal(0.0, cfg.initializer_range)
         self.in_proj = Linear(cfg.hidden_size, 2 * d_in, weight_attr=init,
                               bias_attr=False)
         # depthwise causal conv over the sequence
+        bound = cfg.conv_kernel ** -0.5
         self.conv_weight = self.create_parameter(
-            (d_in, cfg.conv_kernel), default_initializer=I.Uniform(-0.5, 0.5)
-        )
+            (d_in, cfg.conv_kernel),
+            default_initializer=I.Uniform(-bound, bound))
         self.conv_bias = self.create_parameter((d_in,), is_bias=True)
         self.x_proj = Linear(d_in, cfg.dt_rank + 2 * cfg.state_size,
                              weight_attr=init, bias_attr=False)
-        self.dt_proj = Linear(cfg.dt_rank, d_in, weight_attr=init)
+        self.dt_proj = Linear(
+            cfg.dt_rank, d_in, weight_attr=init,
+            bias_attr=_dt_bias_init(cfg.time_step_min, cfg.time_step_max,
+                                    cfg.time_step_floor))
         self.A_log = self.create_parameter(
             (d_in, cfg.state_size),
             default_initializer=lambda key, shape, dtype: jnp.log(
@@ -108,39 +154,64 @@ class MambaMixer(Layer):
                                bias_attr=False)
         self.config = config
 
-    def forward(self, x):
+    def forward(self, x, return_scan_output=False):
         cfg = self.config
-        b, s, _ = x.shape
-        xz = self.in_proj(x)
-        xs, z = jnp.split(xz, 2, axis=-1)  # [b, s, d_in] each
-        # causal depthwise conv along seq
-        k = cfg.conv_kernel
-        pad = jnp.pad(xs, ((0, 0), (k - 1, 0), (0, 0)))
-        w = self.conv_weight.value  # [d_in, k]
-        xs = sum(
-            pad[:, i:i + s, :] * w[:, i][None, None, :] for i in range(k)
-        ) + self.conv_bias.value
-        xs = F.silu(xs)
-        proj = self.x_proj(xs)
-        dt, B, C = jnp.split(
-            proj, [cfg.dt_rank, cfg.dt_rank + cfg.state_size], axis=-1
-        )
-        delta = jax.nn.softplus(self.dt_proj(dt))
-        A = -jnp.exp(self.A_log.value.astype(jnp.float32))
-        if cfg.use_chunked_scan and s % cfg.scan_chunk == 0:
-            from ..kernels.selective_scan import chunked_selective_scan
+        with jax.named_scope("s6_in"):
+            xz = self.in_proj(x)
+        gated, y = _s6_core(
+            xz, self.conv_weight.value, self.conv_bias.value,
+            self.x_proj.weight.value, self.dt_proj.weight.value,
+            self.dt_proj.bias.value, self.A_log.value, self.D.value,
+            (cfg.dt_rank, cfg.state_size,
+             cfg.scan_chunk if cfg.use_chunked_scan else 0))
+        with jax.named_scope("s6_out"):
+            out = self.out_proj(gated)
+        return (out, y) if return_scan_output else out
 
-            y = chunked_selective_scan(
-                xs, delta, A, B, C, self.D.value,
-                chunk=cfg.scan_chunk,
-            ).astype(x.dtype)
+
+# what ``_s6_core``'s forward keeps for its backward pass beside its
+# arguments: the scan's output and the states between chunks, so that
+# the forward kernel runs once
+_S6_KEPT = ("s6_y", *_S6_RESIDUAL_NAMES)
+
+
+@functools.partial(
+    jax.checkpoint, static_argnums=(8,),
+    policy=jax.checkpoint_policies.save_only_these_names(*_S6_KEPT))
+def _s6_core(xz, taps, conv_bias, x_proj_w, dt_w, dt_b, A_log, D, sizes):
+    """Between ``in_proj`` and ``out_proj``: conv, the two small
+    projections, the recurrence, the gate. Returns the gated product and
+    the scan's own output ``y`` (before the gate), both in ``xz``'s
+    dtype. The backward pass makes the stretch again from ``xz`` (the
+    conv, ``x_proj`` and ``dt_proj``: 0.1% of a layer's products, and
+    element-wise passes), all but the scan's kernel, whose ``y`` and
+    states it keeps (``_S6_KEPT``): kept whole, the float32 conv sums,
+    ``delta`` and their activations are 0.7 GB a layer at 8192 x 5120."""
+    dt_rank, n, chunk = sizes
+    b, s, _ = xz.shape
+    f32, dtype = jnp.float32, xz.dtype
+    xs, z = jnp.split(xz, 2, axis=-1)  # [b, s, d_in] each
+    with jax.named_scope("s6_in"):
+        # causal depthwise conv; tap k-1 weighs the current step
+        k = taps.shape[1]
+        padded = jnp.pad(xs.astype(f32), ((0, 0), (k - 1, 0), (0, 0)))
+        xs = sum(padded[:, i:i + s] * taps[:, i].astype(f32)
+                 for i in range(k)) + conv_bias.astype(f32)
+        xs = F.silu(xs).astype(dtype)
+        dt, B, C = jnp.split(xs @ x_proj_w, [dt_rank, dt_rank + n], axis=-1)
+        delta = jax.nn.softplus(
+            jnp.matmul(dt, dt_w, preferred_element_type=f32)
+            + dt_b.astype(f32))
+        A = -jnp.exp(A_log.astype(f32))
+    with jax.named_scope("s6_scan"):
+        if chunk and s % chunk == 0:
+            y = chunked_selective_scan(xs, delta, A, B, C, D, chunk=chunk)
         else:
-            y = selective_scan(
-                xs.astype(jnp.float32), delta.astype(jnp.float32), A,
-                B.astype(jnp.float32), C.astype(jnp.float32),
-                self.D.value.astype(jnp.float32),
-            ).astype(x.dtype)
-        return self.out_proj(y * F.silu(z))
+            y = selective_scan(xs.astype(f32), delta, A, B.astype(f32),
+                               C.astype(f32), D.astype(f32))
+        y = checkpoint_name(y.astype(dtype), "s6_y")
+    with jax.named_scope("s6_out"):
+        return y * F.silu(z), y
 
 
 class MambaBlock(Layer):
@@ -208,16 +279,9 @@ class Mamba2Mixer(Layer):
             default_initializer=I.Uniform(-0.5, 0.5))
         self.conv_bias = self.create_parameter((conv_dim,), is_bias=True)
 
-        def dt_bias_init(key, shape, dtype):
-            # softplus(dt_bias) is log-uniform in [min, max], floored
-            u = jax.random.uniform(key, shape, jnp.float32)
-            dt = jnp.exp(u * (jnp.log(time_step_max) - jnp.log(
-                time_step_min)) + jnp.log(time_step_min))
-            dt = jnp.maximum(dt, time_step_floor)
-            return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-
         self.dt_bias = self.create_parameter(
-            (num_heads,), default_initializer=dt_bias_init)
+            (num_heads,), default_initializer=_dt_bias_init(
+                time_step_min, time_step_max, time_step_floor))
         self.A_log = self.create_parameter(
             (num_heads,),
             default_initializer=lambda key, shape, dtype: jnp.log(

@@ -99,4 +99,12 @@ SCOPES = {
     "moe_experts": ("moe", "the held experts' rows: sort, gather, the "
                     "grouped products, the weighted combine"),
     "moe_shared": ("moe", "the shared expert, residual"),
+    "s6_in": ("s6", "a Mamba-1 block's norm, in_proj, causal conv, "
+              "x_proj, dt_proj, softplus"),
+    "s6_scan": ("s6", "the chunked selective scan's kernel pair "
+                "(kernels/selective_scan.py) and what XLA makes around "
+                "it"),
+    "s6_out": ("s6", "the gate, out_proj, residual"),
+    "gmu": ("gmu", "a gated memory unit: norm, both products, the gate "
+            "on another layer's scan output, residual"),
 }

@@ -369,6 +369,89 @@ def test_nemotron_nine_block_step_compiles_and_fits(topo, chip_compile):
     assert total < 16.91e9 - 0.5e9, total
 
 
+def test_phi4flash_six_layer_step_compiles_and_fits(topo, chip_compile):
+    """The whole train step of the cell ``phi4-mini-flash-train-8k`` as
+    the benchmark builds it (published layers 0, 1, 16, 17, 18, 19 at the
+    published widths, an eighth of the vocabulary with the head tied,
+    1 x 8192 tokens, AdamW with fp32 masters), compiled for a described
+    v5e: the selective scan's kernel pair lowers at d_inner 5120, state
+    16 (it had never been compiled for the chip), flash attention lowers
+    with a window, with scores' heads padded to the values' 128 and as
+    cross-attention on another layer's keys and values, and the program
+    fits one chip beside its 9.76 GB of state. A Mamba-1 layer runs its
+    scan's forward kernel once (its output and states are kept), both
+    kernels keep the scope ``s6_scan`` that the cell's metrics read and
+    the names their roofline metrics match, and XLA itself makes nothing
+    again (no instruction named ``.remat``)."""
+    import json
+    import pathlib
+    import re
+
+    import paddle_tpu as pt
+    from paddle_tpu import distributed as dist, optimizer as opt
+    from paddle_tpu.core import meta
+    from paddle_tpu.models import Phi4FlashConfig, Phi4FlashForCausalLM
+    from paddle_tpu.trainer import TrainStep
+
+    root = pathlib.Path(__file__).parent.parent / "chipbench"
+    w = json.loads((root / "configs" /
+                    "phi-4-mini-flash-reasoning-train.json").read_text())
+    sizes = json.loads((root / "traffic" / "train-8k.json").read_text())
+    cfg = Phi4FlashConfig(
+        vocab_size=w["vocab_size"],
+        num_hidden_layers=w["published_num_hidden_layers"],
+        published_layer_indices=tuple(w["published_layer_indices"]))
+    assert (cfg.hidden_size, cfg.intermediate_size, cfg.d_inner,
+            cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.sliding_window,
+            cfg.scan_chunk) == (
+        w["hidden_size"], w["intermediate_size"],
+        w["mamba_expand"] * w["hidden_size"], w["mamba_d_state"],
+        w["mamba_dt_rank"], w["sliding_window"], w["scan_chunk"])
+    with meta.meta_init():
+        model = Phi4FlashForCausalLM(cfg)
+    model.to(pt.bfloat16)
+    mesh = dist.build_mesh(devices=[topo.devices[0]])
+    ts = TrainStep(
+        model, opt.AdamW(1e-4, multi_precision=True,
+                         grad_clip=opt.ClipGradByGlobalNorm(1.0)),
+        mesh, abstract=True)
+    ids = jax.ShapeDtypeStruct((sizes["batch"], sizes["sequence"]),
+                               jnp.int32)
+    compiled = ts.lower({"input_ids": ids, "labels": ids}).compile()
+    text = compiled.as_text()
+    # a trace names an event by the instruction's text, its name first
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    scans = [ln for ln in calls if re.search(r'op_name="[^"]*s6_scan', ln)]
+    # 2 Mamba-1 layers x (forward, backward); 3 attention layers x 2
+    # maps x (forward, one fused backward call)
+    assert (len(scans), len(calls)) == (4, 16), (len(scans), len(calls))
+    def matched(metric):
+        """The calls a metric's kernel patterns match (``kernels_of``
+        names the metric files whose pattern is taken as it stands)."""
+        args = json.loads((root / "metrics" / f"{metric}.json").read_text())[
+            "args"]
+        if "kernel" in args:
+            return [ln for ln in calls if re.search(args["kernel"], ln)]
+        return sum((matched(m) for m in args["kernels_of"]), [])
+
+    fwd, bwd, flash = (matched(m) for m in (
+        "s6_scan_fwd_roofline.train", "s6_scan_bwd_roofline.train",
+        "diff_attn_device_ms.train"))
+    assert len(fwd) == len(bwd) == 2 and not set(fwd) & set(bwd)
+    assert sorted(fwd + bwd) == sorted(scans)
+    assert len(flash) == 12 and not set(flash) & set(scans)
+    by_xla = sorted(set(re.findall(r"%([\w.\-]+\.remat[\d.]*) = ", text)))
+    assert not by_xla, by_xla
+    m = compiled.memory_analysis()
+    total = m.argument_size_in_bytes + m.temp_size_in_bytes \
+        + m.output_size_in_bytes - m.alias_size_in_bytes
+    assert m.argument_size_in_bytes >= w["bytes_reckoned"]["state_bytes"]
+    # 15.75 GiB (16.91e9 B) is what a v5e chip gives a program; half a
+    # gigabyte of it is left as margin. The step reads 15.47e9 B here
+    assert total < 16.91e9 - 0.5e9, total
+
+
 @pytest.mark.parametrize("page,dtype", [(64, BF16), (16, BF16),
                                         (32, jnp.int8), (128, jnp.int8)])
 def test_auto_only_picks_compiled_decode_kernels(page, dtype, monkeypatch):

@@ -25,7 +25,8 @@ import paddle_tpu as pt
 from paddle_tpu import distributed as dist, observability as obs
 from paddle_tpu import optimizer as opt
 from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
-                               NemotronHConfig, NemotronHForCausalLM)
+                               NemotronHConfig, NemotronHForCausalLM,
+                               Phi4FlashConfig, Phi4FlashForCausalLM)
 from paddle_tpu.observability.spans import SCOPES, SPANS
 from paddle_tpu.trainer import TrainStep
 
@@ -45,16 +46,24 @@ def _telemetry_on():
 def _tiny_model(family="llama"):
     if family == "llama":
         return LlamaForCausalLM(LlamaConfig.tiny(use_flash_attention=False))
+    if family == "phi4flash":  # all eight published layers: every kind
+        return Phi4FlashForCausalLM(Phi4FlashConfig.tiny())
     return NemotronHForCausalLM(NemotronHConfig.tiny(
         use_flash_attention=False))
 
 
 # the phases each family's step has: a dense decoder has no state-space
-# and no expert blocks, the hybrid stack has no MLP
+# and no expert blocks, the Mamba-2 hybrid stack has no MLP, and the
+# Mamba-1 hybrid (S6 scans, gated memory units) has neither of the
+# other's recurrent or expert phases
+_RECURRENT = {"nemotron_h": ("ssm", "moe"), "phi4flash": ("s6", "gmu")}
 FAMILY_SCOPES = {
     "llama": {s for s, (phase, _) in SCOPES.items()
-              if phase not in ("ssm", "moe")},
-    "nemotron_h": set(SCOPES) - {"mlp"},
+              if phase not in sum(_RECURRENT.values(), ())},
+    "nemotron_h": {s for s, (phase, _) in SCOPES.items()
+                   if phase not in _RECURRENT["phi4flash"]} - {"mlp"},
+    "phi4flash": {s for s, (phase, _) in SCOPES.items()
+                  if phase not in _RECURRENT["nemotron_h"]},
 }
 
 
@@ -203,8 +212,9 @@ def test_the_families_cover_the_scope_table():
 
 
 @pytest.mark.parametrize("family,backward", [
-    ("llama", ("mlp",)), ("nemotron_h", ("ssm_scan", "moe_experts"))],
-    ids=["llama", "nemotron_h"])
+    ("llama", ("mlp",)), ("nemotron_h", ("ssm_scan", "moe_experts")),
+    ("phi4flash", ("s6_scan", "gmu"))],
+    ids=["llama", "nemotron_h", "phi4flash"])
 def test_every_scope_is_in_some_op_name_and_changes_nothing_else(
         family, backward, monkeypatch):
     scopes = FAMILY_SCOPES[family]
@@ -227,8 +237,8 @@ def test_every_scope_is_in_some_op_name_and_changes_nothing_else(
         assert any(re.search(r"[/(]%s[/)]" % scope, n) for n in names), \
             scope
     # a backward operation carries its scope inside the transforms (the
-    # SSD's backward rule under the mixer's ``jax.checkpoint`` and the
-    # experts' hand-written backward too)
+    # SSD's and the selective scan's backward rules under their mixers'
+    # ``jax.checkpoint`` and the experts' hand-written backward too)
     for scope in backward:
         assert any(re.search(r"transpose\(jvp\((.*[/(])?%s[/)]" % scope, n)
                    for n in names), scope
