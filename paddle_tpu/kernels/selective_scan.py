@@ -12,23 +12,54 @@ Why a kernel when ``jax.lax.associative_scan`` already runs on TPU: the
 associative formulation materializes the discretized operands
 ``dA, dBu`` — two ``[b, s, d, n]`` f32 tensors, a ``2n``-fold blowup of
 the activations — and streams them through HBM O(log s) times. This
-kernel never forms them: the sequence is processed in chunks with the
-``[n, d]`` recurrent state resident in VMEM scratch across the
-(sequential) chunk grid dimension, so HBM traffic is just the
-``[b, s, d]``/``[b, s, n]`` inputs once and the output once — the same
-streaming structure the reference's CUDA scan uses, mapped onto the
-Pallas grid. Layout: state is kept ``[n, d]`` with d on lanes (n is
-small, e.g. 16), so every VPU op runs full-width.
+kernel never forms them: HBM traffic is the ``[b, s, d]``/``[b, s, n]``
+operands once (``u``, ``B``, ``C`` in the dtype they come in, widened
+in VMEM) and the outputs once, with the ``D`` skip and every sum over
+channels (``dB``, ``dC``, ``dD``) made inside.
+
+Layout and grid. The state is ``[n, d_block]``, channels on lanes (n is
+small, 16: two sublane groups), float32, like everything computed here.
+The grid is (batch, chunk, block of channels), the blocks innermost: a
+grid step is one chunk of ``chunk`` time steps of one block of
+``d_block`` channels, and the state of every block lives in VMEM
+scratch (``[d / d_block, n, d_block]``) from chunk to chunk. A chunk's
+``B`` and ``C`` rows are brought to sublanes and spread over a vreg's
+lanes once, at its first block (``[chunk, n, 128]`` each, one relayout
+of the whole block), and read by all its blocks.
+
+The time loop runs over slabs of 8 steps (one sublane group of the
+``[chunk, d_block]`` operands; 4, 2 or 1 where the chunk is no multiple
+of 8), a slab's steps unrolled into one block (``_steps``; the step is
+traced once). A step is ``h = exp(delta_t A) * h + (delta_t u_t) B_t``
+(``delta u`` made for the whole chunk ahead of the loop) and the
+read-out product with its sum over ``n``; within the block only ``h``
+waits on the step before, so a step's loads, ``exp`` and products run
+under the steps before it. The recurrence itself is not rearranged: no
+cumulative product, no scan inside a slab.
 
 Backward (recompute-based, like the reference CUDA bwd): the forward
 additionally saves the recurrent state at each chunk BOUNDARY —
 ``[b, s/chunk, n, d]``, a ``chunk``-fold reduction vs ``[b, s, d, n]``.
 The backward kernel walks chunks in reverse; within a chunk it first
-re-runs the forward recurrence from the saved boundary state (states
-live in a VMEM scratch, never HBM), then runs the reverse-time
-cotangent recurrence  gh_{t} = C_t⊗g_t + dA_{t+1}·gh_{t+1}  emitting
-du/dδ/dB/dC in place and accumulating dA in scratch. No ``[b, s, d, n]``
-tensor exists in either pass.
+re-runs the forward recurrence from the saved boundary state, keeping
+the chunk's states and decays in VMEM scratch (never HBM), then runs the
+reverse-time cotangent recurrence  gh_{t} = C_t⊗g_t + dA_{t+1}·gh_{t+1},
+slab by slab. Its sums over ``n`` (for du, dδ) are stored row by row
+and turned into du (with g·D, rounded once) and dδ after the loop in
+whole-block passes. Its sums over CHANNELS (dB, dC) leave the loop as
+products folded to one vreg of lanes (elementwise adds), gathered in
+scratch over the chunk's blocks and summed along lanes once a chunk;
+dA and dD gather in their outputs' own blocks over the chunks. No
+``[b, s, d, n]`` tensor exists in either pass.
+
+VMEM a grid step, float32 words: forward ``(d/d_block) n d_block`` of
+state, ``chunk d_block`` and two ``chunk n 128``, beside its blocks
+(under 4 MB at chunk 128, d_block 512, d 5120); backward ``(2 chunk +
+1) n d_block`` for the states and decays (8.4 MB there), four ``chunk
+d_block``, four ``chunk n 128`` and two carries of the forward's state
+size: 14 MB beside its blocks, for which both calls ask 32 MiB
+(``_VMEM_LIMIT_BYTES``, the default scoped limit being 16) and
+``_pick_d_block`` narrows the block where the chunk is longer.
 """
 
 from __future__ import annotations
@@ -50,37 +81,99 @@ from . import _backend
 RESIDUAL_NAMES = ("s6_states",)
 
 
-def _scan_kernel(u_ref, delta_ref, b_ref, c_ref, at_ref, *out_refs,
-                 chunk, with_states):
-    if with_states:
-        y_ref, h0_ref, h_scratch = out_refs
-    else:
-        y_ref, h_scratch = out_refs
-        h0_ref = None
-    ic = pl.program_id(2)
+F32 = jnp.float32
+_LANES = 128
+
+
+def _slab(chunk):
+    """Time steps a loop iteration: one aligned sublane group of the
+    ``[chunk, d_block]`` operands where the chunk allows it."""
+    return next(t for t in (8, 4, 2, 1) if chunk % t == 0)
+
+
+def _bc_width(d_block):
+    """Lanes over which a step's ``B_t`` / ``C_t`` column is spread once
+    a chunk (a vreg's worth; the loop repeats it over the block)."""
+    return _LANES if d_block % _LANES == 0 else d_block
+
+
+def _spread(src_ref, out_scr):
+    """``src_ref [1, chunk, n]`` -> ``out_scr [chunk, n, w]``: every
+    step's row brought to sublanes and repeated along lanes, the whole
+    chunk in one relayout, once a chunk (its channel blocks all read
+    it)."""
+    out_scr[...] = jnp.broadcast_to(src_ref[0].astype(F32)[:, :, None],
+                                    out_scr.shape)
+
+
+def _over_block(tile, d_block):
+    """``[n, w]`` -> ``[n, d_block]``: whole vregs side by side."""
+    reps = d_block // tile.shape[1]
+    return tile if reps == 1 else jnp.concatenate([tile] * reps, axis=1)
+
+
+def _fold(x, w):
+    """``[n, d_block]`` -> ``[n, w]``: the block's lane groups added
+    elementwise (what is left of a sum over channels is taken once a
+    chunk, outside the time loop)."""
+    parts = [x[:, k:k + w] for k in range(0, x.shape[1], w)]
+    return functools.reduce(jnp.add, parts)
+
+
+def _row(ref, t):
+    """Row ``t`` of a ``[chunk, d_block]`` float32 ref as ``[1, d]``."""
+    return ref[pl.ds(t, 1), :]
+
+
+def _steps(chunk, step, carry):
+    """``step(t, carry)`` for t in [0, chunk): a loop over slabs, a
+    slab's steps unrolled into one block, so that the scheduler may
+    start a step's loads, exponentials and products while the steps
+    before it still wait on the state."""
+    slab = _slab(chunk)
+
+    def slab_body(si, carry):
+        t0 = pl.multiple_of(si * slab, slab)
+        return jax.lax.fori_loop(
+            0, slab, lambda j, c: step(t0 + j, c), carry, unroll=True)
+
+    return jax.lax.fori_loop(0, chunk // slab, slab_body, carry)
+
+
+def _scan_kernel(u_ref, delta_ref, b_ref, c_ref, at_ref, d_ref, y_ref,
+                 *refs, chunk):
+    # h0_ref: the output for the states between chunks, where asked for
+    *h0_ref, h_scr, dtu_scr, bb_scr, cb_scr = refs
+    ic, id_ = pl.program_id(1), pl.program_id(2)
+    d_block = dtu_scr.shape[1]
 
     @pl.when(ic == 0)
     def _reset():
-        h_scratch[:] = jnp.zeros_like(h_scratch)
+        h_scr[id_] = jnp.zeros(h_scr.shape[1:], F32)
 
-    if h0_ref is not None:
-        # state entering this chunk (end of previous chunk) — the
-        # backward's recompute anchor
-        h0_ref[0, 0] = h_scratch[...]
+    @pl.when(id_ == 0)
+    def _new_chunk():
+        _spread(b_ref, bb_scr)
+        _spread(c_ref, cb_scr)
 
-    at = at_ref[...]  # [n, d_block]
+    for ref in h0_ref:
+        # the state entering this chunk: the backward's anchor
+        ref[0, 0] = h_scr[id_]
 
-    def body(t, h):
-        # all [n, d] with d on lanes
-        dt = delta_ref[0, t][None, :]          # [1, d]
-        da = jnp.exp(dt * at)                  # [n, d]
-        dbu = (dt * u_ref[0, t][None, :]) * b_ref[0, t][:, None]
-        h = da * h + dbu
-        y = jnp.sum(h * c_ref[0, t][:, None], axis=0)  # [d]
-        y_ref[0, t] = y.astype(y_ref.dtype)
+    at = at_ref[...]                                    # [n, d_block]
+    dref = delta_ref.at[0]
+    dtu_scr[...] = delta_ref[0] * u_ref[0].astype(F32)  # whole chunk
+
+    def step(t, h):
+        h = (jnp.exp(_row(dref, t) * at) * h
+             + _row(dtu_scr, t) * _over_block(bb_scr[t], d_block))
+        y_ref[0, pl.ds(t, 1), :] = jnp.sum(
+            h * _over_block(cb_scr[t], d_block), axis=0, keepdims=True)
         return h
 
-    h_scratch[:] = jax.lax.fori_loop(0, chunk, body, h_scratch[...])
+    h_scr[id_] = _steps(chunk, step, h_scr[id_])
+    # the D skip, in float32 like y: the caller rounds the sum once
+    y_ref[0] += u_ref[0].astype(F32) * d_ref[...]
 
 
 def associative_selective_scan(u, delta, A, B, C, D):
@@ -106,218 +199,227 @@ def associative_selective_scan(u, delta, A, B, C, D):
     return y + u * D[None, None]
 
 
-def _scan_fwd_pallas(u, delta, B, C, at, chunk, d_block, with_states):
-    """Run the forward kernel. Returns y (and chunk-boundary states when
-    ``with_states``). ``at`` is A.T ([n, d]) in f32."""
+# what a call may take of VMEM (the flash kernels ask the same): the
+# backward's states and decays of a chunk may fill half of it
+# (``_pick_d_block``); its other scratch and its blocks, twice over,
+# are under 9 MB at chunk 128, d_block 512
+_VMEM_LIMIT_BYTES = 32 << 20
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+    vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
+def _scan_fwd_pallas(u, delta, B, C, at, D, chunk, d_block, with_states):
+    """Run the forward kernel. Returns y + u D in float32 (and the state
+    before each chunk when ``with_states``). ``at`` is A.T ([n, d]) in
+    f32."""
     b, s, d = u.shape
     n = at.shape[0]
-    n_chunks = s // chunk
-    grid = (b, d // d_block, n_chunks)
-    f32 = jnp.float32
+    n_chunks, nd = s // chunk, d // d_block
+    w = _bc_width(d_block)
+    grid = (b, n_chunks, nd)
+    seq = pl.BlockSpec((1, chunk, d_block), lambda ib, ic, id_: (ib, ic, id_))
+    col = pl.BlockSpec((1, chunk, n), lambda ib, ic, id_: (ib, ic, 0))
     in_specs = [
-        pl.BlockSpec((1, chunk, d_block), lambda ib, id_, ic: (ib, ic, id_)),
-        pl.BlockSpec((1, chunk, d_block), lambda ib, id_, ic: (ib, ic, id_)),
-        pl.BlockSpec((1, chunk, n), lambda ib, id_, ic: (ib, ic, 0)),
-        pl.BlockSpec((1, chunk, n), lambda ib, id_, ic: (ib, ic, 0)),
-        pl.BlockSpec((n, d_block), lambda ib, id_, ic: (0, id_)),
+        seq, seq, col, col,
+        pl.BlockSpec((n, d_block), lambda ib, ic, id_: (0, id_)),
+        pl.BlockSpec((1, d_block), lambda ib, ic, id_: (0, id_)),
     ]
-    y_spec = pl.BlockSpec((1, chunk, d_block),
-                          lambda ib, id_, ic: (ib, ic, id_))
-    scratch = [pltpu.VMEM((n, d_block), f32)]
-    kernel = functools.partial(_scan_kernel, chunk=chunk,
-                               with_states=with_states)
-    args = (u.astype(f32), delta.astype(f32), B.astype(f32), C.astype(f32),
-            at)
-    if not with_states:
-        return pl.pallas_call(
-            kernel, grid=grid, in_specs=in_specs, out_specs=y_spec,
-            out_shape=jax.ShapeDtypeStruct((b, s, d), f32),
-            scratch_shapes=scratch, interpret=_backend.interpret(),
-        )(*args)
-    h0_spec = pl.BlockSpec((1, 1, n, d_block),
-                           lambda ib, id_, ic: (ib, ic, 0, id_))
+    scratch = [
+        pltpu.VMEM((nd, n, d_block), F32),      # the state, block by block
+        pltpu.VMEM((chunk, d_block), F32),      # delta * u
+        pltpu.VMEM((chunk, n, w), F32),         # B over lanes
+        pltpu.VMEM((chunk, n, w), F32),         # C over lanes
+    ]
+    out_specs, out_shape = seq, jax.ShapeDtypeStruct((b, s, d), F32)
+    if with_states:
+        out_specs = (seq, pl.BlockSpec((1, 1, n, d_block),
+                                       lambda ib, ic, id_: (ib, ic, 0, id_)))
+        out_shape = (out_shape,
+                     jax.ShapeDtypeStruct((b, n_chunks, n, d), F32))
     return pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs, out_specs=(y_spec, h0_spec),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, s, d), f32),
-            jax.ShapeDtypeStruct((b, n_chunks, n, d), f32),
-        ),
-        scratch_shapes=scratch, interpret=_backend.interpret(),
-    )(*args)
+        functools.partial(_scan_kernel, chunk=chunk),
+        grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch,
+        compiler_params=_PARAMS,
+        interpret=_backend.interpret(),
+    )(u, delta.astype(F32), B, C, at, D.astype(F32)[None])
 
 
-def _scan_bwd_kernel(u_ref, delta_ref, b_ref, c_ref, at_ref, h0_ref, g_ref,
-                     du_ref, ddelta_ref, db_ref, dc_ref, dat_ref,
-                     gh_scratch, hs_scratch, dat_scratch, *, chunk,
-                     n_chunks):
-    """One reverse-ordered chunk of the cotangent recurrence.
-
-    gh ("grad of h") carries dL/dh_t across the chunk boundary in VMEM
-    scratch; hs_scratch holds the chunk's recomputed states (the only
-    place full per-step states ever exist — VMEM, [chunk, n, d_block]).
-    """
-    ic = pl.program_id(2)  # 0 = LAST chunk (reverse iteration)
+def _scan_bwd_kernel(u_ref, delta_ref, b_ref, c_ref, at_ref, d_ref, h0_ref,
+                     g_ref, du_ref, ddelta_ref, db_ref, dc_ref, dat_ref, dd_ref,
+                     gh_scr, hs_scr, da_scr, dtu_scr, g_scr, s1_scr, s2_scr,
+                     bb_scr, cb_scr, pb_scr, pc_scr, *, chunk):
+    """One chunk of one block of channels, the chunks coming last to
+    first. ``gh_scr`` carries dL/dh across the chunk boundary;
+    ``hs_scr`` holds the chunk's states made again from the saved one
+    and ``da_scr`` their decays (VMEM only: the one place a state per
+    step exists); ``pb_scr`` / ``pc_scr`` gather the products whose sums
+    over channels are dB and dC, lane group on lane group and block on
+    block, and are summed along lanes once a chunk."""
+    ic, id_, nd = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    d_block = dtu_scr.shape[1]
+    w = pb_scr.shape[2]
 
     @pl.when(ic == 0)
     def _reset():
-        gh_scratch[:] = jnp.zeros_like(gh_scratch)
-        dat_scratch[:] = jnp.zeros_like(dat_scratch)
+        gh_scr[id_] = jnp.zeros(gh_scr.shape[1:], F32)
+        dat_ref[0, id_] = jnp.zeros(dat_ref.shape[2:], F32)
+        dd_ref[0, id_] = jnp.zeros(dd_ref.shape[2:], F32)
 
-    at = at_ref[...]      # [n, d]
-    h0 = h0_ref[0, 0]     # [n, d] state entering this chunk
+    @pl.when(id_ == 0)
+    def _new_chunk():
+        _spread(b_ref, bb_scr)
+        _spread(c_ref, cb_scr)
+        pb_scr[...] = jnp.zeros_like(pb_scr)
+        pc_scr[...] = jnp.zeros_like(pc_scr)
 
-    # ---- pass 1: recompute post-step states h_t for t in [0, chunk) ----
-    def fwd_body(t, h):
-        dt = delta_ref[0, t][None, :]
-        da = jnp.exp(dt * at)
-        dbu = (dt * u_ref[0, t][None, :]) * b_ref[0, t][:, None]
-        h = da * h + dbu
-        hs_scratch[t] = h
+    at = at_ref[...]                                    # [n, d_block]
+    dref = delta_ref.at[0]
+    dtu_scr[...] = delta_ref[0] * u_ref[0].astype(F32)
+    g_scr[...] = g_ref[0].astype(F32)
+
+    # ---- pass 1: the states h_t again, hs_scr[t + 1] = h_t ----
+    h0 = hs_scr[0] = h0_ref[0, 0]
+
+    def fwd_step(t, h):
+        da = jnp.exp(_row(dref, t) * at)
+        da_scr[t] = da
+        h = da * h + _row(dtu_scr, t) * _over_block(bb_scr[t], d_block)
+        hs_scr[t + 1] = h
         return h
 
-    jax.lax.fori_loop(0, chunk, fwd_body, h0)
+    _steps(chunk, fwd_step, h0)
 
-    # ---- pass 2: reverse cotangent recurrence ----
-    def bwd_body(rt, gh):
+    # ---- pass 2: the cotangent recurrence, last step first ----
+    def bwd_step(rt, carry):
+        gh, dat = carry
         t = chunk - 1 - rt
-        g = g_ref[0, t][None, :]               # [1, d]
-        dt = delta_ref[0, t][None, :]          # [1, d]
-        bt = b_ref[0, t][:, None]              # [n, 1]
-        ct = c_ref[0, t][:, None]              # [n, 1]
-        ut = u_ref[0, t][None, :]              # [1, d]
-        h_t = hs_scratch[t]                    # [n, d]
-        h_prev = jnp.where(t == 0, h0, hs_scratch[jnp.maximum(t - 1, 0)])
-        da = jnp.exp(dt * at)                  # [n, d]
+        g = _row(g_scr, t)                           # [1, d]
+        da = da_scr[t]
+        # dC_t = sum over channels of h_t g
+        pc_scr[t] += _fold(hs_scr[t + 1] * g, w)
+        gh = gh + _over_block(cb_scr[t], d_block) * g   # dL/dh_t
+        # through dbu = (delta u) (x) B
+        s1_scr[pl.ds(t, 1), :] = jnp.sum(
+            gh * _over_block(bb_scr[t], d_block), axis=0, keepdims=True)
+        pb_scr[t] += _fold(gh * _row(dtu_scr, t), w)
+        # through da = exp(delta (x) at), applied to h_{t-1}
+        ghh = gh * hs_scr[t] * da
+        s2_scr[pl.ds(t, 1), :] = jnp.sum(ghh * at, axis=0, keepdims=True)
+        return da * gh, dat + ghh * _row(dref, t)
 
-        # dC_t[n] = Σ_d h_t·g
-        dc_ref[0, 0, t] = jnp.sum(h_t * g, axis=1)
-        gh = gh + ct * g                       # dL/dh_t, full
+    gh, dat = _steps(chunk, bwd_step,
+                     (gh_scr[id_], jnp.zeros(gh_scr.shape[1:], F32)))
+    gh_scr[id_] = gh
+    dat_ref[0, id_] += dat
+    # du = delta s1 + g D, rounded once; ddelta = u s1 + s2; dD = sum g u
+    s1, g, u = s1_scr[...], g_scr[...], u_ref[0].astype(F32)
+    du_ref[0] = (delta_ref[0] * s1 + g * d_ref[...]).astype(du_ref.dtype)
+    ddelta_ref[0] = u * s1 + s2_scr[...]
+    dd_ref[0, id_] += jnp.sum(g * u, axis=0, keepdims=True)
 
-        # dbu branch: dbu = (δ·u) ⊗ B
-        ghb = gh * bt                          # [n, d]
-        sum_ghb = jnp.sum(ghb, axis=0)[None, :]  # [1, d]
-        du_ref[0, t] = (dt * sum_ghb)[0].astype(du_ref.dtype)
-        ddelta_dbu = ut * sum_ghb              # [1, d]
-        db_ref[0, 0, t] = jnp.sum(gh * (dt * ut), axis=1)
-
-        # da branch: da = exp(δ ⊗ at), applied to h_prev
-        ghh = gh * h_prev * da                 # [n, d]
-        ddelta_da = jnp.sum(ghh * at, axis=0)[None, :]
-        ddelta_ref[0, t] = (ddelta_dbu + ddelta_da)[0].astype(
-            ddelta_ref.dtype)
-        dat_scratch[:] += ghh * dt
-
-        # propagate to t-1
-        return da * gh
-
-    gh_scratch[:] = jax.lax.fori_loop(0, chunk, bwd_body, gh_scratch[...])
-
-    @pl.when(ic == n_chunks - 1)  # first chunk (reverse order) → flush dA
-    def _fin():
-        dat_ref[0] = dat_scratch[...]
+    @pl.when(id_ == nd - 1)
+    def _sums():
+        db_ref[0] = jnp.sum(pb_scr[...], axis=2)
+        dc_ref[0] = jnp.sum(pc_scr[...], axis=2)
 
 
-def _scan_bwd_pallas(u, delta, B, C, at, h0s, g, chunk, d_block):
+def _scan_bwd_pallas(u, delta, B, C, at, D, h0s, g, chunk, d_block):
     b, s, d = u.shape
     n = at.shape[0]
-    n_chunks = s // chunk
-    nd = d // d_block
-    f32 = jnp.float32
-    grid = (b, nd, n_chunks)
+    n_chunks, nd = s // chunk, d // d_block
+    w = _bc_width(d_block)
+    grid = (b, n_chunks, nd)
 
     def rev(ic):
         return n_chunks - 1 - ic
 
+    seq = pl.BlockSpec((1, chunk, d_block),
+                       lambda ib, ic, id_: (ib, rev(ic), id_))
+    col = pl.BlockSpec((1, chunk, n), lambda ib, ic, id_: (ib, rev(ic), 0))
     in_specs = [
-        pl.BlockSpec((1, chunk, d_block),
-                     lambda ib, id_, ic: (ib, rev(ic), id_)),   # u
-        pl.BlockSpec((1, chunk, d_block),
-                     lambda ib, id_, ic: (ib, rev(ic), id_)),   # delta
-        pl.BlockSpec((1, chunk, n),
-                     lambda ib, id_, ic: (ib, rev(ic), 0)),     # B
-        pl.BlockSpec((1, chunk, n),
-                     lambda ib, id_, ic: (ib, rev(ic), 0)),     # C
-        pl.BlockSpec((n, d_block), lambda ib, id_, ic: (0, id_)),  # at
+        seq, seq, col, col,
+        pl.BlockSpec((n, d_block), lambda ib, ic, id_: (0, id_)),   # at
+        pl.BlockSpec((1, d_block), lambda ib, ic, id_: (0, id_)),   # D
         pl.BlockSpec((1, 1, n, d_block),
-                     lambda ib, id_, ic: (ib, rev(ic), 0, id_)),  # h0s
-        pl.BlockSpec((1, chunk, d_block),
-                     lambda ib, id_, ic: (ib, rev(ic), id_)),   # g
+                     lambda ib, ic, id_: (ib, rev(ic), 0, id_)),    # h0s
+        seq,                                                        # g
     ]
     out_specs = (
-        pl.BlockSpec((1, chunk, d_block),
-                     lambda ib, id_, ic: (ib, rev(ic), id_)),   # du
-        pl.BlockSpec((1, chunk, d_block),
-                     lambda ib, id_, ic: (ib, rev(ic), id_)),   # ddelta
-        # dB/dC get a leading d-block axis (summed by the caller —
-        # different d-blocks each contribute)
-        pl.BlockSpec((1, 1, chunk, n),
-                     lambda ib, id_, ic: (id_, ib, rev(ic), 0)),  # db
-        pl.BlockSpec((1, 1, chunk, n),
-                     lambda ib, id_, ic: (id_, ib, rev(ic), 0)),  # dc
-        # dat: per-batch accumulator flushed on the last (reverse) chunk;
-        # caller sums over batch
-        pl.BlockSpec((1, n, d_block), lambda ib, id_, ic: (ib, 0, id_)),
+        seq, seq,       # du, ddelta
+        col, col,       # dB, dC: written once a chunk, after its blocks
+        # dA^T and dD: gathered over the chunks in the output's own
+        # block, one a batch row (the caller sums over those)
+        pl.BlockSpec((1, nd, n, d_block), lambda ib, ic, id_: (ib, 0, 0, 0)),
+        pl.BlockSpec((1, nd, 1, d_block), lambda ib, ic, id_: (ib, 0, 0, 0)),
     )
     out_shape = (
-        jax.ShapeDtypeStruct((b, s, d), f32),
-        jax.ShapeDtypeStruct((b, s, d), f32),
-        jax.ShapeDtypeStruct((nd, b, s, n), f32),
-        jax.ShapeDtypeStruct((nd, b, s, n), f32),
-        jax.ShapeDtypeStruct((b, n, d), f32),
+        jax.ShapeDtypeStruct((b, s, d), u.dtype),
+        jax.ShapeDtypeStruct((b, s, d), F32),
+        jax.ShapeDtypeStruct((b, s, n), F32),
+        jax.ShapeDtypeStruct((b, s, n), F32),
+        jax.ShapeDtypeStruct((b, nd, n, d_block), F32),
+        jax.ShapeDtypeStruct((b, nd, 1, d_block), F32),
     )
-    du, ddelta, db, dc, dat = pl.pallas_call(
-        functools.partial(_scan_bwd_kernel, chunk=chunk, n_chunks=n_chunks),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((n, d_block), f32),          # gh carry
-            pltpu.VMEM((chunk, n, d_block), f32),   # recomputed states
-            pltpu.VMEM((n, d_block), f32),          # dat accumulator
-        ],
+    scratch = [
+        pltpu.VMEM((nd, n, d_block), F32),          # gh carry
+        pltpu.VMEM((chunk + 1, n, d_block), F32),   # the states again
+        pltpu.VMEM((chunk, n, d_block), F32),       # their decays
+        pltpu.VMEM((chunk, d_block), F32),          # delta * u
+        pltpu.VMEM((chunk, d_block), F32),          # g widened
+        pltpu.VMEM((chunk, d_block), F32),          # sum_n gh B
+        pltpu.VMEM((chunk, d_block), F32),          # sum_n ghh A
+        pltpu.VMEM((chunk, n, w), F32),             # B over lanes
+        pltpu.VMEM((chunk, n, w), F32),             # C over lanes
+        pltpu.VMEM((chunk, n, w), F32),             # dB's products
+        pltpu.VMEM((chunk, n, w), F32),             # dC's products
+    ]
+    du, ddelta, db, dc, dat, dd = pl.pallas_call(
+        functools.partial(_scan_bwd_kernel, chunk=chunk),
+        grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=scratch,
+        compiler_params=_PARAMS,
         interpret=_backend.interpret(),
-    )(u.astype(f32), delta.astype(f32), B.astype(f32), C.astype(f32),
-      at, h0s, g.astype(f32))
-    return du, ddelta, db.sum(0), dc.sum(0), dat.sum(0)
+    )(u, delta.astype(F32), B, C, at, D.astype(F32)[None], h0s, g)
+    # [b, nd, n, d_block] -> [n, d]
+    dat = dat.sum(0).transpose(1, 0, 2).reshape(n, d)
+    return du, ddelta, db, dc, dat, dd.sum(0).reshape(d)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def _chunked_scan(u, delta, A, B, C, D, chunk, d_block):
-    f32 = jnp.float32
-    at = A.T.astype(f32)
-    y = _scan_fwd_pallas(u, delta, B, C, at, chunk, d_block,
-                         with_states=False)
-    return y + u.astype(f32) * D[None, None].astype(f32)
+    return _scan_fwd_pallas(u, delta, B, C, A.T.astype(F32), D, chunk,
+                            d_block, with_states=False)
 
 
 def _chunked_fwd(u, delta, A, B, C, D, chunk, d_block):
-    f32 = jnp.float32
-    at = A.T.astype(f32)
-    y, h0s = _scan_fwd_pallas(u, delta, B, C, at, chunk, d_block,
-                              with_states=True)
-    out = y + u.astype(f32) * D[None, None].astype(f32)
-    return out, (u, delta, A, B, C, D,
-                 checkpoint_name(h0s, RESIDUAL_NAMES[0]))
+    y, h0s = _scan_fwd_pallas(u, delta, B, C, A.T.astype(F32), D, chunk,
+                              d_block, with_states=True)
+    return y, (u, delta, A, B, C, D,
+               checkpoint_name(h0s, RESIDUAL_NAMES[0]))
 
 
 def _chunked_bwd(chunk, d_block, res, g):
     u, delta, A, B, C, D, h0s = res
-    f32 = jnp.float32
-    at = A.T.astype(f32)
-    du, ddelta, db, dc, dat = _scan_bwd_pallas(
-        u, delta, B, C, at, h0s, g, chunk, d_block)
-    # D-skip terms (outside the kernel: pure elementwise)
-    g32 = g.astype(f32)
-    du = du + g32 * D[None, None].astype(f32)
-    dD = jnp.sum(g32 * u.astype(f32), axis=(0, 1))
-    dA = dat.T  # at = A.T
-    return (du.astype(u.dtype), ddelta.astype(delta.dtype),
-            dA.astype(A.dtype), db.astype(B.dtype), dc.astype(C.dtype),
-            dD.astype(D.dtype))
+    du, ddelta, db, dc, dat, dD = _scan_bwd_pallas(
+        u, delta, B, C, A.T.astype(F32), D, h0s, g, chunk, d_block)
+    return (du, ddelta.astype(delta.dtype), dat.T.astype(A.dtype),
+            db.astype(B.dtype), dc.astype(C.dtype), dD.astype(D.dtype))
 
 
 _chunked_scan.defvjp(_chunked_fwd, _chunked_bwd)
+
+
+def _pick_d_block(d, n, chunk):
+    """Channels a grid step: the whole of a narrow layer, else the
+    widest multiple of a vreg's lanes up to 512 that divides ``d``, of
+    those whose states and decays of a chunk (the backward's scratch)
+    fit half the VMEM the call asks for."""
+    widths = [w for w in (d, 512, 384, 256, 128) if w <= 512 and d % w == 0]
+    fits = [w for w in widths
+            if 2 * (chunk + 1) * n * w * 4 <= _VMEM_LIMIT_BYTES // 2]
+    return (fits or widths[-1:] or [d])[0]
 
 
 @functools.partial(jax.jit, inline=True,
@@ -325,16 +427,16 @@ _chunked_scan.defvjp(_chunked_fwd, _chunked_bwd)
 def chunked_selective_scan(u, delta, A, B, C, D, *, chunk=128,
                            d_block=None):
     """y[b,s,d] for h_t = exp(Δ_t A)·h_{t-1} + Δ_t u_t B_t, y_t = C_t·h_t
-    (+ u·D skip). Shapes as ``associative_selective_scan``. Training-safe:
-    the custom VJP is recompute-based and never materializes [b,s,d,n]
-    (backward VMEM: chunk·n·d_block states per grid cell)."""
+    (+ u·D skip), float32. Shapes as ``associative_selective_scan``;
+    ``u``, ``B``, ``C`` in any float dtype (widened in the kernel, their
+    gradients rounded once to it), ``chunk`` the distance between the
+    states kept for the backward. Training-safe: the custom VJP is
+    recompute-based and never materializes [b,s,d,n] (backward VMEM:
+    2·chunk·n·d_block words of states and decays a grid step;
+    ``d_block`` defaults to ``_pick_d_block``)."""
     b, s, d = u.shape
-    n = A.shape[1]
     if d_block is None:
-        d_block = d if d <= 512 else 256
-        # keep the backward's recomputed-state scratch within VMEM budget
-        while chunk * n * d_block * 4 > 8 * 1024 * 1024 and d_block > 128:
-            d_block //= 2
+        d_block = _pick_d_block(d, A.shape[1], chunk)
     if s % chunk:
         raise ValueError(f"seq len {s} not divisible by chunk {chunk}")
     if d % d_block:

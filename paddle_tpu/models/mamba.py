@@ -113,7 +113,11 @@ class MambaMixer(Layer):
 
     The recurrence is ``kernels/selective_scan.chunked_selective_scan``
     where ``use_chunked_scan`` is set and the sequence is a multiple of
-    ``scan_chunk``. **Otherwise it falls back, silently, to the float32
+    ``scan_chunk`` (the distance between the states its backward starts
+    from). It is handed ``x``, ``B`` and ``C`` in the mixer's dtype and
+    widens them itself, walks the steps one by one in float32, adds the
+    ``D`` skip and returns float32, which is rounded once here.
+    **Otherwise it falls back, silently, to the float32
     associative scan**, which writes two ``[b, s, d_inner, n]`` float32
     tensors (``2 x b x s x d x n x 4 B``: 2 x 2.7 GB at 1 x 8192 x 5120
     x 16) and exists for ``MambaForCausalLM``'s CPU tests only; a model

@@ -102,8 +102,9 @@ SCOPES = {
     "s6_in": ("s6", "a Mamba-1 block's norm, in_proj, causal conv, "
               "x_proj, dt_proj, softplus"),
     "s6_scan": ("s6", "the chunked selective scan's kernel pair "
-                "(kernels/selective_scan.py) and what XLA makes around "
-                "it"),
+                "(kernels/selective_scan.py: the D skip and every sum "
+                "over channels inside) and what XLA still makes around "
+                "it: A's transpose, y's rounding, its cotangent widened"),
     "s6_out": ("s6", "the gate, out_proj, residual"),
     "gmu": ("gmu", "a gated memory unit: norm, both products, the gate "
             "on another layer's scan output, residual"),

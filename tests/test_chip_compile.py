@@ -441,6 +441,15 @@ def test_phi4flash_six_layer_step_compiles_and_fits(topo, chip_compile):
     assert len(fwd) == len(bwd) == 2 and not set(fwd) & set(bwd)
     assert sorted(fwd + bwd) == sorted(scans)
     assert len(flash) == 12 and not set(flash) & set(scans)
+    # the scan's kernels are handed u, B and C as the bf16 they are
+    # (delta float32), and gather dB and dC over the blocks of channels
+    # themselves: no partial a block is left for XLA to sum
+    b, s, d, n = (sizes["batch"], sizes["sequence"], cfg.d_inner,
+                  cfg.mamba_d_state)
+    handed = (f"bf16[{b},{s},{d}]{{2,1,0}}, f32[{b},{s},{d}]{{2,1,0}}, "
+              f"bf16[{b},{s},{n}]{{2,1,0}}, bf16[{b},{s},{n}]{{2,1,0}}, ")
+    assert all("operand_layout_constraints={" + handed in ln for ln in scans)
+    assert not re.search(rf"f32\[\d+,{b},{s},{n}\]", text)
     by_xla = sorted(set(re.findall(r"%([\w.\-]+\.remat[\d.]*) = ", text)))
     assert not by_xla, by_xla
     m = compiled.memory_analysis()
@@ -448,8 +457,37 @@ def test_phi4flash_six_layer_step_compiles_and_fits(topo, chip_compile):
         + m.output_size_in_bytes - m.alias_size_in_bytes
     assert m.argument_size_in_bytes >= w["bytes_reckoned"]["state_bytes"]
     # 15.75 GiB (16.91e9 B) is what a v5e chip gives a program; half a
-    # gigabyte of it is left as margin. The step reads 15.47e9 B here
+    # gigabyte of it is left as margin. The step reads 15.50e9 B here
     assert total < 16.91e9 - 0.5e9, total
+
+
+def test_selective_scan_pair_compiles_at_mamba130m_width(chip_compile):
+    """The selective scan's kernel pair at ``MambaConfig``'s other
+    user's width (Mamba-130m: d_inner 1536, state 16) over 2048 tokens,
+    operands as the mixer hands them: the blocks of channels the kernel
+    picks for itself (three of 512, seen in dA's gathered output) fit
+    the chip's VMEM in both directions."""
+    import re
+
+    from paddle_tpu.kernels import selective_scan
+
+    b, s, d, n = 1, 2048, 1536, 16
+    f32 = jnp.float32
+
+    def loss(*args):
+        return jnp.sum(selective_scan.chunked_selective_scan(*args,
+                                                             chunk=128))
+
+    compiled = chip_compile(
+        jax.grad(loss, argnums=tuple(range(6))),
+        [((b, s, d), BF16), ((b, s, d), f32), ((d, n), f32),
+         ((b, s, n), BF16), ((b, s, n), BF16), ((d,), f32)])
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln and "custom-call(" in ln]
+    assert len(calls) == 2, len(calls)
+    assert selective_scan._pick_d_block(d, n, 128) == 512
+    assert sum(bool(re.search(rf"f32\[{b},3,{n},512\]", ln))
+               for ln in calls) == 1
 
 
 @pytest.mark.parametrize("page,dtype", [(64, BF16), (16, BF16),
