@@ -16,6 +16,7 @@ the reference hand-codes, overlapped by XLA.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -448,11 +449,12 @@ class DroplessMoELayer(MoELayer):
 # holds, it routes over all of them and computes its own experts' part.
 # ---------------------------------------------------------------------
 def sigmoid_topk_routing(scores_in, correction_bias, top_k: int,
-                         scale: float = 1.0):
-    """Sigmoid-scored top-k routing (DeepSeek-V3 / Nemotron-H form).
-    ``scores_in`` [t, E] float32 router outputs. The choice is the top-k
-    of ``sigmoid + correction_bias``; the weights are the chosen
-    sigmoids WITHOUT the bias, renormalised over the k chosen, times
+                         scale: float = 1.0, eps: float = 1e-20):
+    """Sigmoid-scored top-k routing (DeepSeek-V3 / Nemotron-H / LFM2
+    form). ``scores_in`` [t, E] float32 router outputs. The choice is
+    the top-k of ``sigmoid + correction_bias``; the weights are the
+    chosen sigmoids WITHOUT the bias, renormalised over the k chosen
+    (``/ (sum + eps)``: the published codes differ in ``eps``), times
     ``scale``. Returns (expert_idx [t, k] int32, gates [t, k]
     float32)."""
     s = jax.nn.sigmoid(scores_in.astype(jnp.float32))
@@ -461,7 +463,7 @@ def sigmoid_topk_routing(scores_in, correction_bias, top_k: int,
     # spares a gather over [t, E] and its scatter in the backward pass
     chosen, idx = jax.lax.top_k(s + bias, top_k)
     g = chosen - bias[idx]
-    g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
+    g = g / (jnp.sum(g, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), g * scale
 
 
@@ -481,9 +483,11 @@ def _held_rows(expert_idx, first: int, n_held: int):
 def _expert_rows(xs, gate, w_e: dict, act):
     """One block of rows ``xs`` [rows, m] through ONE expert's matrices,
     weighed by ``gate`` (rows past the expert's last come in as zeros
-    with a zero weight)."""
-    return (act(xs @ w_e["w1"]) @ w_e["w2"]).astype(jnp.float32) \
-        * gate[:, None]
+    with a zero weight). Two matrices: ``act(xs w1) w2``; with a third,
+    the gated form ``(act(xs w3) * (xs w1)) w2``, as ``ExpertFFN``."""
+    h = xs @ w_e["w1"]
+    h = act(xs @ w_e["w3"]) * h if "w3" in w_e else act(h)
+    return (h @ w_e["w2"]).astype(jnp.float32) * gate[:, None]
 
 
 def _block(order, start, end, j, top_k: int, rows: int, t: int):
@@ -531,8 +535,13 @@ def _rows_of(acc, t: int):
     return acc[:t].reshape(t, -1)
 
 
-def _n_blocks(start, end, rows: int):
-    return (end - start + rows - 1) // rows
+def _n_blocks(start, end, rows: int, floor: int):
+    """Trips of an expert's walk: the blocks its rows fill and never
+    fewer than ``floor`` (a block past the expert's last row is all
+    zeros with zero weights: it adds nothing, and costs what a full one
+    costs)."""
+    n = (end - start + rows - 1) // rows
+    return jnp.maximum(n, floor) if floor else n
 
 
 def _spans(counts):
@@ -540,8 +549,8 @@ def _spans(counts):
     return ends - counts, ends
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _held_experts(act, top_k, rows, x, gates, order, counts, w):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _held_experts(act, top_k, rows, floor, x, gates, order, counts, w):
     """sum over the held rows of gate * expert(x[token]), [t, m] float32.
     A scan over the held experts; inside it each expert walks its own
     rows in blocks of ``rows``, as many as it got: the trip count is
@@ -556,19 +565,19 @@ def _held_experts(act, top_k, rows, x, gates, order, counts, w):
                 _take(x, tok), _take(gates, flat), w_e, act))
 
         return jax.lax.fori_loop(
-            0, _n_blocks(start, end, rows), body, out), None
+            0, _n_blocks(start, end, rows, floor), body, out), None
 
     out, _ = jax.lax.scan(per_expert, _row_acc(*x.shape, rows),
                           (w, *_spans(counts)))
     return _rows_of(out, x.shape[0])
 
 
-def _held_experts_fwd(act, top_k, rows, x, gates, order, counts, w):
-    out = _held_experts(act, top_k, rows, x, gates, order, counts, w)
+def _held_experts_fwd(act, top_k, rows, floor, x, gates, order, counts, w):
+    out = _held_experts(act, top_k, rows, floor, x, gates, order, counts, w)
     return out, (x, gates, order, counts, w)
 
 
-def _held_experts_bwd(act, top_k, rows, res, dout):
+def _held_experts_bwd(act, top_k, rows, floor, res, dout):
     x, gates, order, counts, w = res
     f32 = jnp.float32
 
@@ -588,7 +597,7 @@ def _held_experts_bwd(act, top_k, rows, res, dout):
                         lambda a, b: a + b.astype(f32), dw_e, dw_b))
 
         dx, dgates, dw_e = jax.lax.fori_loop(
-            0, _n_blocks(start, end, rows), body,
+            0, _n_blocks(start, end, rows, floor), body,
             (*acc, jax.tree_util.tree_map(
                 lambda v: jnp.zeros(v.shape, f32), w_e)))
         return (dx, dgates), jax.tree_util.tree_map(
@@ -604,12 +613,13 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def held_experts_apply(x, expert_idx, gates, w: dict, act, first: int,
-                       block_rows: int = 256):
+                       block_rows: int = 256, min_blocks: int = 0):
     """The held experts' part of a dropless top-k layer.
 
     x [t, m]; expert_idx, gates [t, k] over ALL the router's experts;
     ``w`` the held experts' stacked matrices (``w1`` [n_held, m, h],
-    ``w2`` [n_held, h, m]; two-matrix experts), which are the
+    ``w2`` [n_held, h, m], and for gated experts ``w3`` [n_held, m, h]:
+    ``_expert_rows`` has both forms), which are the
     experts ``first .. first + n_held - 1``. Returns
     (y [t, m] float32 = sum over the chosen AND held experts of
     gate * expert(x), counts [n_held]).
@@ -617,19 +627,34 @@ def held_experts_apply(x, expert_idx, gates, w: dict, act, first: int,
     Rows of absent experts cost nothing: the assignments are sorted
     with the held ones first, by expert, and each held expert (a scan
     over the stacked matrices) walks its own rows in blocks
-    of ``block_rows`` (gather, two plain products,
+    of ``block_rows`` (gather, two or three plain products,
     scatter-add), as many blocks as it got. Nothing is dropped,
     whatever the routing: a full expert takes more blocks. At most
     ``block_rows - 1`` rows of zeros an expert are multiplied in
-    vain."""
+    vain; with ``min_blocks`` every held expert walks at least that
+    many blocks, so that the layer's time does not follow small
+    differences of load (``HeldExpertsMoE.even_share_slack``)."""
     t, k = expert_idx.shape
     order, counts = _held_rows(expert_idx, first, w["w1"].shape[0])
     # room for an expert's last block to read a whole slice
     order = jnp.pad(order, (0, block_rows))
-    y = _held_experts(act, k, block_rows, x,
+    y = _held_experts(act, k, block_rows, min_blocks, x,
                       gates.reshape(-1).astype(jnp.float32), order,
                       counts, w)
     return y, counts
+
+
+def sum_routing_counts(counts: list) -> dict:
+    """A model's ``step_counters()`` from its sparse layers'
+    ``last_counts``: rows summed over the layers, ``moe_rows_max`` the
+    fullest held expert of the worst layer; ``{}`` without a layer."""
+    if not counts:
+        return {}
+    return {
+        "moe_rows_routed": sum(c["rows_routed"] for c in counts),
+        "moe_rows_held": sum(c["rows_held"] for c in counts),
+        "moe_rows_max": jnp.max(jnp.stack(
+            [c["rows_max"] for c in counts]))}
 
 
 class HeldExpertsMoE(Layer):
@@ -639,9 +664,22 @@ class HeldExpertsMoE(Layer):
     them); of the routed experts this layer holds ``held = (first,
     count)`` and computes their part of the result for the rows routed
     to them, and nothing for the rest; a shared expert, where
-    ``shared_hidden`` is given, sees every token. On one chip there is
-    no exchange: what the absent experts would add is added by the chips
-    that hold them.
+    ``shared_hidden`` is given, sees every token. The experts are
+    bias-free, ``act(x w1) w2`` or, with ``gated``, the three-matrix
+    ``(act(x w3) * (x w1)) w2``; ``norm_eps`` is what the router's
+    renormalisation adds to the sum of the chosen scores. On one chip
+    there is no exchange: what the absent experts would add is added by
+    the chips that hold them.
+
+    The walk is dropless and its trip count is whole blocks, so an even
+    share that just fills its blocks (1024 rows in blocks of 256) makes
+    each expert take four or five by the draw of the routers, and the
+    step's time follows the seed. ``even_share_slack`` (None: off) is a
+    capacity factor used as a floor, not a ceiling: every held expert
+    walks at least the blocks that ``slack`` times its even share
+    fills, and more when it got more. On a v5e at 8192 tokens, top-4 of
+    32 and a slack of 1.25 the step is 2% slower than the mean of the
+    bare walk and the same from seed to seed (PERF.md, PR 38).
 
     forward(x [b, s, m]) -> y [b, s, m]; the step's routing counts are
     left in ``last_counts`` ({"rows_routed", "rows_held", "rows_max"},
@@ -651,9 +689,12 @@ class HeldExpertsMoE(Layer):
                  top_k: int, held, activation: str = "relu2",
                  shared_hidden: Optional[int] = None,
                  routed_scale: float = 1.0, init_std: float = 0.02,
-                 expert_axis: str = "ep"):
+                 expert_axis: str = "ep", gated: bool = False,
+                 norm_eps: float = 1e-20,
+                 even_share_slack: Optional[float] = None):
         super().__init__()
         self.num_experts, self.top_k = num_experts, top_k
+        self.norm_eps = norm_eps
         self.first, n_held = held
         if self.first < 0 or self.first + n_held > num_experts:
             raise ValueError(f"held experts {held} outside 0..{num_experts}")
@@ -666,12 +707,23 @@ class HeldExpertsMoE(Layer):
         self.register_buffer("e_score_correction_bias",
                              jnp.zeros((num_experts,), jnp.float32))
         self.experts = ExpertFFN(n_held, d_model, d_hidden, expert_axis,
-                                 activation, init_std, bias=False)
+                                 activation, init_std, bias=False,
+                                 gated=gated)
         self.shared_experts = ExpertFFN(
             1, d_model, shared_hidden, None, activation, init_std,
-            bias=False) if shared_hidden else None
+            bias=False, gated=gated) if shared_hidden else None
         self.block_rows = 256  # rows of one product of a held expert
+        self.even_share_slack = even_share_slack
         self.last_counts = None
+
+    def min_blocks(self, tokens: int) -> int:
+        """The floor of every held expert's walk for a call of
+        ``tokens``: the blocks that ``even_share_slack`` times an even
+        share of the routed rows fills; 0 without a slack."""
+        if self.even_share_slack is None:
+            return 0
+        even = tokens * self.top_k / self.num_experts
+        return math.ceil(self.even_share_slack * even / self.block_rows)
 
     def route(self, xf):
         scores = jnp.matmul(
@@ -680,7 +732,7 @@ class HeldExpertsMoE(Layer):
             precision=jax.lax.Precision.HIGHEST)
         return sigmoid_topk_routing(
             scores, self._buffers["e_score_correction_bias"], self.top_k,
-            self.routed_scale)
+            self.routed_scale, self.norm_eps)
 
     def forward(self, x):
         b, s, m = x.shape
@@ -690,9 +742,11 @@ class HeldExpertsMoE(Layer):
         with jax.named_scope("moe_experts"):
             e = self.experts
             w = {"w1": e.w1.value, "w2": e.w2.value}
+            if e.w3 is not None:
+                w["w3"] = e.w3.value
             y, counts = held_experts_apply(
                 xf, expert_idx, gates, w, e.act, self.first,
-                self.block_rows)
+                self.block_rows, self.min_blocks(b * s))
             y = y.astype(x.dtype)
         self.last_counts = {
             "rows_routed": jnp.asarray(b * s * self.top_k, jnp.int32),

@@ -10,6 +10,7 @@ from .bert import (  # noqa: F401
 )
 from .ernie_moe import ErnieMoEConfig, ErnieMoEForCausalLM  # noqa: F401
 from .gpt import GPTConfig, GPTForCausalLM, GPTModel  # noqa: F401
+from .lfm2 import Lfm2MoeConfig, Lfm2MoeForCausalLM  # noqa: F401
 from .llama import (  # noqa: F401
     LlamaConfig,
     LlamaForCausalLM,

@@ -27,11 +27,10 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 
 from ..core import initializer as I
 from ..core.module import Layer
-from ..distributed.moe import HeldExpertsMoE
+from ..distributed.moe import HeldExpertsMoE, sum_routing_counts
 from ..distributed.parallel_layers import (
     ColumnParallelLinear,
     RowParallelLinear,
@@ -223,12 +222,6 @@ class NemotronHForCausalLM(Layer):
         over the expert blocks (``moe_rows_max``: the fullest held
         expert of the worst block). ``TrainStep`` returns them beside
         the gradient norm; telemetry reads them on sampled steps."""
-        counts = [blk.mixer.last_counts for blk in self.backbone.layers
-                  if blk.kind == "E"]
-        if not counts:
-            return {}
-        return {
-            "moe_rows_routed": sum(c["rows_routed"] for c in counts),
-            "moe_rows_held": sum(c["rows_held"] for c in counts),
-            "moe_rows_max": jnp.max(jnp.stack(
-                [c["rows_max"] for c in counts]))}
+        return sum_routing_counts([
+            blk.mixer.last_counts for blk in self.backbone.layers
+            if blk.kind == "E"])

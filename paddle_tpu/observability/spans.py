@@ -42,7 +42,9 @@ SPANS = {
         ("interval_steps", "moe_rows_routed", "moe_rows_held",
          "moe_rows_max"),
         ("telemetry_idle_ms.train", "moe_held_rows_share.train",
-         "moe_expert_load_max_over_mean.train")),
+         "moe_expert_load_max_over_mean.train",
+         "swiglu_experts_rows_share.train",
+         "swiglu_experts_load_max_over_mean.train")),
     "pt.train.sync_to_model": (
         "trainer host", "rebinding the model's parameters to the "
         "step's outputs", (), ("idle_attributed_share.train",)),
@@ -86,7 +88,8 @@ SCOPES = {
                   "norm, the step's extra output"),
     "optimizer": ("optimizer", "optimizer.update, the clip included"),
     "embed": ("head", "the token embedding"),
-    "attn_in": ("attention", "input norm, q/k/v projections, RoPE"),
+    "attn_in": ("attention", "input norm, q/k/v projections, the heads' "
+                "q/k norm, RoPE"),
     "attn_out": ("attention", "output projection and residual"),
     "mlp": ("mlp", "post-attention norm, MLP, residual"),
     "head_loss": ("head", "final norm, lm_head, the loss"),
@@ -97,7 +100,9 @@ SCOPES = {
     "moe_router": ("moe", "an expert block's norm, router scores, "
                    "top-k, weights"),
     "moe_experts": ("moe", "the held experts' rows: sort, gather, the "
-                    "grouped products, the weighted combine"),
+                    "grouped products (two an expert, three for gated "
+                    "experts), the weighted combine; the residual where "
+                    "there is no shared expert"),
     "moe_shared": ("moe", "the shared expert, residual"),
     "s6_in": ("s6", "a Mamba-1 block's norm, in_proj, causal conv, "
               "x_proj, dt_proj, softplus"),
@@ -108,4 +113,12 @@ SCOPES = {
     "s6_out": ("s6", "the gate, out_proj, residual"),
     "gmu": ("gmu", "a gated memory unit: norm, both products, the gate "
             "on another layer's scan output, residual"),
+    "sconv_in": ("sconv", "a gated short convolution's norm and in_proj "
+                 "(shortconv_device_ms.train)"),
+    "sconv_mix": ("sconv", "between its projections, in float32: B * x, "
+                  "the causal taps, C * z; no weight product "
+                  "(shortconv_device_ms.train, "
+                  "shortconv_mix_device_ms.train)"),
+    "sconv_out": ("sconv", "out_proj, residual "
+                  "(shortconv_device_ms.train)"),
 }

@@ -173,3 +173,119 @@ def test_ep_error_names_the_condition():
         moe.dropless_moe_ep_apply(jnp.zeros((8, 4)), jnp.zeros((4, 6)),
                                   w, jnp.zeros((6, 4)), w,
                                   jnp.zeros((6, 4)), jax.nn.relu, 2, mesh)
+
+
+# ---- the gated (three-matrix) form: (act(x w3) * (x w1)) w2
+def _gated_case(t=80, k=2, first=2, n_held=4, block_rows=16):
+    """Uneven routing over E experts with held ones ``first ..``: held
+    expert 1 gets no row, held expert 2 more than a block of 16."""
+    keys = jax.random.split(jax.random.PRNGKey(38), 6)
+    x = jax.random.normal(keys[0], (t, M))
+    w = {n: 0.3 * jax.random.normal(kk, (n_held, *shape))
+         for n, kk, shape in (("w1", keys[1], (M, H)), ("w2", keys[2], (H, M)),
+                              ("w3", keys[3], (M, H)))}
+    # choices: column 0 mostly held expert 2 (id first + 2), column 1
+    # spread over every expert but held expert 1 (id first + 1)
+    col0 = jnp.where(jnp.arange(t) % 5 == 0, 0, first + 2)
+    others = jnp.array([e for e in range(E) if e not in (first + 1,
+                                                         first + 2)])
+    col1 = others[jax.random.randint(keys[4], (t,), 0, others.size)]
+    idx = jnp.stack([col0, col1], axis=1).astype(jnp.int32)
+    gates = jax.random.uniform(keys[5], (t, k), minval=0.2, maxval=1.0)
+    return x, idx, gates, w, first, block_rows
+
+
+def _dense_gated(x, idx, gates, w, first):
+    """Every held expert over every token by einsum, masked to the
+    (token, choice) pairs that fall on it."""
+    h = jax.nn.silu(jnp.einsum("tm,emh->eth", x, w["w3"])) \
+        * jnp.einsum("tm,emh->eth", x, w["w1"])
+    y = jnp.einsum("eth,ehm->etm", h, w["w2"])
+    held = first + jnp.arange(w["w1"].shape[0])
+    weight = jnp.sum(jnp.where(idx[None] == held[:, None, None],
+                               gates[None], 0.0), axis=-1)  # [e, t]
+    return jnp.einsum("et,etm->tm", weight, y)
+
+
+@pytest.mark.parametrize("floor", [0, 3], ids=["bare", "floor_of_3"])
+def test_gated_held_experts_forward_and_backward_match_a_dense_einsum(floor):
+    """``floor``: every held expert walks at least three blocks of 16,
+    the empty one and the one with 16 rows or fewer too; the blocks past
+    an expert's last row add nothing, forward or backward."""
+    x, idx, gates, w, first, rows = _gated_case()
+    y, counts = moe.held_experts_apply(x, idx, gates, w, jax.nn.silu,
+                                       first, rows, floor)
+    assert int(counts[1]) == 0 and int(counts[2]) > rows  # 0 rows; blocks
+    assert int(counts.sum()) == int(jnp.sum(
+        (idx >= first) & (idx < first + 4)))
+    np.testing.assert_allclose(y, _dense_gated(x, idx, gates, w, first),
+                               atol=2e-5)
+    probe = jax.random.normal(jax.random.PRNGKey(9), y.shape)
+
+    def held(x, gates, w):
+        return jnp.sum(moe.held_experts_apply(
+            x, idx, gates, w, jax.nn.silu, first, rows, floor)[0] * probe)
+
+    def dense(x, gates, w):
+        return jnp.sum(_dense_gated(x, idx, gates, w, first) * probe)
+
+    got = jax.grad(held, (0, 1, 2))(x, gates, w)
+    want = jax.grad(dense, (0, 1, 2))(x, gates, w)
+    leaves = jax.tree_util.tree_leaves_with_path
+    for (path, g), (_, r) in zip(leaves(got), leaves(want)):
+        # float32 sums over 80 tokens: 1e-5 of the largest entry
+        np.testing.assert_allclose(
+            g, r, rtol=0, err_msg=str(path),
+            atol=1e-5 * float(jnp.abs(r).max()) + 1e-7)
+    # the third matrix has a cotangent of its own, and the empty
+    # expert's is exactly zero in all three
+    assert float(jnp.abs(got[2]["w3"][2]).max()) > 0
+    for n in ("w1", "w2", "w3"):
+        assert float(jnp.abs(got[2][n][1]).max()) == 0.0, n
+
+
+def test_two_matrix_path_does_not_see_the_third_matrix():
+    """Without ``w3`` the walk computes act(x w1) w2 as it always did:
+    the jaxpr of the two-matrix call holds two products a block, the
+    gated one three."""
+    x, idx, gates, w, first, rows = _gated_case()
+    two = {"w1": w["w1"], "w2": w["w2"]}
+
+    def dots(w):
+        text = str(jax.make_jaxpr(lambda x: moe.held_experts_apply(
+            x, idx, gates, w, moe.relu2, first, rows)[0])(x))
+        return text.count("dot_general")
+
+    assert (dots(two), dots(w)) == (2, 3)
+
+
+def test_router_epsilon_is_an_argument_and_defaults_to_1e_20():
+    scores = jnp.array([[-40.0, -41.0, -50.0]])  # sigmoids near 1e-18
+    s = jax.nn.sigmoid(scores)[0]
+    _, g0 = moe.sigmoid_topk_routing(scores, jnp.zeros(3), 2)
+    np.testing.assert_allclose(g0[0], s[:2] / (s[0] + s[1] + 1e-20),
+                               rtol=1e-5)
+    _, g6 = moe.sigmoid_topk_routing(scores, jnp.zeros(3), 2, eps=1e-6)
+    np.testing.assert_allclose(g6[0], s[:2] / (s[0] + s[1] + 1e-6),
+                               rtol=1e-5)
+    assert float(g0.sum()) > 0.9 and float(g6.sum()) < 1e-9
+    layer = moe.HeldExpertsMoE(M, E, H, K, held=(0, 4), activation="silu",
+                               gated=True, norm_eps=1e-6)
+    assert layer.experts.w3.value.shape == (4, M, H)
+    assert layer.shared_experts is None and layer.norm_eps == 1e-6
+
+
+def test_the_walks_floor_is_a_slack_over_the_even_share():
+    """Off by default (the two-matrix cell's walk is as it was); with a
+    slack, the blocks of 256 that slack x tokens x top_k / experts rows
+    fill: 8192 tokens, top-4 of 32 and 1.25 give 1280 rows, 5 blocks."""
+    def layer(**kw):
+        return moe.HeldExpertsMoE(M, 32, H, 4, held=(0, 8),
+                                  activation="silu", gated=True, **kw)
+
+    assert layer().min_blocks(8192) == 0
+    slack = layer(even_share_slack=1.25)
+    assert slack.block_rows == 256
+    assert [slack.min_blocks(t) for t in (8192, 16384, 256, 1)] == [
+        5, 10, 1, 1]
+    assert layer(even_share_slack=1.0).min_blocks(8192) == 4

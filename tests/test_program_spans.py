@@ -24,7 +24,8 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import distributed as dist, observability as obs
 from paddle_tpu import optimizer as opt
-from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
+from paddle_tpu.models import (Lfm2MoeConfig, Lfm2MoeForCausalLM,
+                               LlamaConfig, LlamaForCausalLM,
                                NemotronHConfig, NemotronHForCausalLM,
                                Phi4FlashConfig, Phi4FlashForCausalLM)
 from paddle_tpu.observability.spans import SCOPES, SPANS
@@ -48,22 +49,34 @@ def _tiny_model(family="llama"):
         return LlamaForCausalLM(LlamaConfig.tiny(use_flash_attention=False))
     if family == "phi4flash":  # all eight published layers: every kind
         return Phi4FlashForCausalLM(Phi4FlashConfig.tiny())
+    if family == "lfm2":  # both operators, a dense and three sparse layers
+        return Lfm2MoeForCausalLM(Lfm2MoeConfig.tiny(
+            num_dense_layers=1, use_flash_attention=False))
     return NemotronHForCausalLM(NemotronHConfig.tiny(
         use_flash_attention=False))
 
 
-# the phases each family's step has: a dense decoder has no state-space
-# and no expert blocks, the Mamba-2 hybrid stack has no MLP, and the
+# the phases each family's step has: a dense decoder has no state-space,
+# convolution or expert blocks, the Mamba-2 hybrid stack has no MLP, the
 # Mamba-1 hybrid (S6 scans, gated memory units) has neither of the
-# other's recurrent or expert phases
-_RECURRENT = {"nemotron_h": ("ssm", "moe"), "phi4flash": ("s6", "gmu")}
+# other's recurrent or expert phases, and the short-convolution stack
+# has experts (none shared) and a dense MLP but no recurrence
+_OWN = {"nemotron_h": ("ssm", "moe"), "phi4flash": ("s6", "gmu"),
+        "lfm2": ("sconv", "moe")}
+
+
+def _scopes_without(*families):
+    gone = sum((_OWN[f] for f in families), ())
+    return {s for s, (phase, _) in SCOPES.items() if phase not in gone}
+
+
 FAMILY_SCOPES = {
-    "llama": {s for s, (phase, _) in SCOPES.items()
-              if phase not in sum(_RECURRENT.values(), ())},
-    "nemotron_h": {s for s, (phase, _) in SCOPES.items()
-                   if phase not in _RECURRENT["phi4flash"]} - {"mlp"},
-    "phi4flash": {s for s, (phase, _) in SCOPES.items()
-                  if phase not in _RECURRENT["nemotron_h"]},
+    "llama": _scopes_without("nemotron_h", "phi4flash", "lfm2"),
+    "nemotron_h": _scopes_without("phi4flash")
+    - {"mlp", "sconv_in", "sconv_mix", "sconv_out"},
+    "phi4flash": _scopes_without("nemotron_h", "lfm2"),
+    "lfm2": {s for s, (phase, _) in SCOPES.items()
+             if phase not in ("ssm", "s6", "gmu")} - {"moe_shared"},
 }
 
 
@@ -213,8 +226,9 @@ def test_the_families_cover_the_scope_table():
 
 @pytest.mark.parametrize("family,backward", [
     ("llama", ("mlp",)), ("nemotron_h", ("ssm_scan", "moe_experts")),
-    ("phi4flash", ("s6_scan", "gmu"))],
-    ids=["llama", "nemotron_h", "phi4flash"])
+    ("phi4flash", ("s6_scan", "gmu")),
+    ("lfm2", ("sconv_mix", "moe_experts"))],
+    ids=["llama", "nemotron_h", "phi4flash", "lfm2"])
 def test_every_scope_is_in_some_op_name_and_changes_nothing_else(
         family, backward, monkeypatch):
     scopes = FAMILY_SCOPES[family]
