@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from ..core import initializer as I
 from ..core.module import Layer
+from ..kernels import _backend, grouped_experts
 from ..nn import functional as F
 from .sharding import shard_activation
 
@@ -612,6 +613,172 @@ def _held_experts_bwd(act, top_k, rows, floor, res, dout):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+# ---------------------------------------------------------------------
+# The same sum through grouped kernels (``kernels/grouped_experts.py``):
+# the held rows are gathered ONCE into a buffer sorted by expert and laid
+# out in tiles, a tile is one expert's, and what the walk does block by
+# block inside two nested loops is five kernel calls over the live tiles.
+# ---------------------------------------------------------------------
+def _tiles_walked(counts, rows: int, floor: int):
+    """Tiles of ``rows`` that each expert's stretch takes: what its rows
+    fill and never fewer than ``floor``."""
+    return jnp.maximum((counts + rows - 1) // rows, floor)
+
+
+# rows that one trip of the gather and combine loops moves, about: the
+# loops run over the live tiles only, in chunks of whole tiles. On a v5e
+# at the LFM2 cell's shape a layer's forward and backward take 6.55 ms
+# in chunks of 1024 or 512 rows, 6.86 in chunks of 256, 7.33 in 2048 and
+# over 8.3 in 4096 and more (PERF.md, PR 39)
+_CHUNK_ROWS = 1024
+
+
+def _chunk_tiles(tile: int) -> int:
+    return max(_CHUNK_ROWS // tile, 1)
+
+
+def _sorted_layout(order, counts, top_k: int, t: int, tile: int, floor: int):
+    """Where each held row sits in the sorted buffer. Expert ``e`` takes
+    ``max(ceil(c_e / tile), floor, 1)`` tiles from a tile boundary on;
+    the static bound on the tiles is every assignment on the held
+    experts, rounded up to whole chunks. Returns
+
+      flat  [rows]  each buffer row's flat assignment, ``t * top_k`` and
+                    more (past ``gates``' end) for a row that holds none
+      tok   [rows]  its token, ``t`` and more for a row that holds
+                    none: a gather reads zeros for it and a scatter-add
+                    drops it
+      tile_expert [tiles], n_live [1]   the kernels' scalars."""
+    n, n_held = t * top_k, counts.shape[0]
+    per = _chunk_tiles(tile)
+    tiles = math.ceil(n / tile) + n_held * max(floor, 1)
+    tiles = math.ceil(tiles / per) * per
+    mine = _tiles_walked(counts, tile, max(floor, 1))
+    tile_ends = jnp.cumsum(mine)
+    n_live = tile_ends[-1:]
+    i = jnp.arange(tiles, dtype=jnp.int32)
+    tile_expert = jnp.minimum(
+        jnp.sum(i[:, None] >= tile_ends, axis=1, dtype=jnp.int32),
+        n_held - 1)
+    starts, ends = _spans(counts)
+    lo = starts[tile_expert] + (i - (tile_ends - mine)[tile_expert]) * tile
+    # a tile's rows are one slice of ``order`` (padded, so that the last
+    # slice is whole), cut at the expert's end
+    padded = jnp.pad(order, (0, tile))
+    flat = jax.vmap(lambda lo: jax.lax.dynamic_slice(padded, (lo,), (tile,))
+                    )(jnp.clip(lo, 0, n))
+    ahead = jnp.arange(tile, dtype=jnp.int32)
+    # (a tile past the live ones starts past its expert's end: none valid)
+    valid = (lo[:, None] + ahead < ends[tile_expert][:, None]).reshape(-1)
+    flat = flat.reshape(-1)
+    return (jnp.where(valid, flat, n), jnp.where(valid, flat // top_k, t),
+            tile_expert, n_live.astype(jnp.int32))
+
+
+def _live_chunks(n_live, tile: int):
+    """(rows a trip, trips) of a loop over the live tiles."""
+    per = _chunk_tiles(tile)
+    return per * tile, (n_live[0] + per - 1) // per
+
+
+def _gather_live(sources: tuple, idx: tuple, chunk: int, n_chunks):
+    """``source[idx]`` for each pair, over the first ``n_chunks`` chunks
+    of the indices; the rows past them are never written, and never
+    read. An index past a source's end reads zeros."""
+    def body(c, outs):
+        lo = c * chunk
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                out, _take(a, jax.lax.dynamic_slice_in_dim(i, lo, chunk)),
+                lo, 0)
+            for out, a, i in zip(outs, sources, idx))
+
+    return jax.lax.fori_loop(0, n_chunks, body, tuple(
+        jax.lax.empty((i.shape[0], *a.shape[1:]), a.dtype)
+        for a, i in zip(sources, idx)))
+
+
+def _add_live_rows(acc, tok, v):
+    """``_add_rows`` for rows of which some hold no assignment: those
+    point past ``acc``'s end and are dropped, and a v5e does skip them
+    (10,240 rows of which 2,048 hold none: 0.82 ms against 1.04 with
+    each at a spare row of its own; PERF.md, PR 39)."""
+    return acc.at[tok].add(v.reshape(-1, *acc.shape[1:]), mode="drop")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _grouped_experts(top_k, tile, floor, x, gates, order, counts, w):
+    """``_held_experts`` for gated silu experts, through the kernels."""
+    return _grouped_experts_fwd(top_k, tile, floor, x, gates, order,
+                                counts, w)[0]
+
+
+def _grouped_experts_fwd(top_k, tile, floor, x, gates, order, counts, w):
+    t = x.shape[0]
+    flat, tok, te, n_live = _sorted_layout(order, counts, top_k, t, tile,
+                                           floor)
+    chunk, n_chunks = _live_chunks(n_live, tile)
+    xs, gs = _gather_live((x, gates[:, None]), (tok, flat), chunk, n_chunks)
+    a, b, h = grouped_experts.gate_up(xs, w["w1"], w["w3"], te, n_live,
+                                      tile=tile)
+    ys = grouped_experts.down(h, w["w2"], gs, te, n_live, tile=tile)
+
+    def combine(c, out):
+        lo = c * chunk
+        return _add_live_rows(
+            out, jax.lax.dynamic_slice_in_dim(tok, lo, chunk),
+            jax.lax.dynamic_slice_in_dim(ys, lo, chunk))
+
+    out = jax.lax.fori_loop(0, n_chunks, combine, _row_acc(*x.shape, 0))
+    return _rows_of(out, t), (xs, a, b, gs, flat, tok, te, n_live, w)
+
+
+def _grouped_experts_bwd(top_k, tile, floor, res, dout):
+    xs, a, b, gs, flat, tok, te, n_live, w = res
+    t, n_held = dout.shape[0], w["w1"].shape[0]
+    chunk, n_chunks = _live_chunks(n_live, tile)
+    dys, = _gather_live((dout.astype(xs.dtype),), (tok,), chunk, n_chunks)
+    da, db, hg, dg = grouped_experts.d_hidden(
+        dys, w["w2"], a, b, gs, te, n_live, tile=tile)
+    dxs = grouped_experts.d_rows(da, db, w["w1"], w["w3"], te, n_live,
+                                 tile=tile)
+    dw1, dw3 = grouped_experts.d_weights(
+        xs, (db, da), te, n_live, tile=tile, n_experts=n_held,
+        dtype=w["w1"].dtype)
+    dw2, = grouped_experts.d_weights(
+        hg, (dys,), te, n_live, tile=tile, n_experts=n_held,
+        dtype=w["w2"].dtype)
+
+    def scatter(c, acc):
+        dx, dgates = acc
+        lo = c * chunk
+        return (_add_live_rows(
+                    dx, jax.lax.dynamic_slice_in_dim(tok, lo, chunk),
+                    jax.lax.dynamic_slice_in_dim(dxs, lo, chunk)),
+                _add(dgates, jax.lax.dynamic_slice_in_dim(flat, lo, chunk),
+                     jax.lax.dynamic_slice_in_dim(dg, lo, chunk)))
+
+    dx, dgates = jax.lax.fori_loop(
+        0, n_chunks, scatter,
+        (_row_acc(t, xs.shape[1], 0), jnp.zeros((t * top_k,), jnp.float32)))
+    return (_rows_of(dx, t).astype(xs.dtype), dgates, None, None,
+            {"w1": dw1, "w2": dw2, "w3": dw3})
+
+
+_grouped_experts.defvjp(_grouped_experts_fwd, _grouped_experts_bwd)
+
+
+def _use_grouped(x, w: dict, act, block_rows: int) -> bool:
+    """Whether the held rows go through the grouped kernels: gated silu
+    experts whose widths are whole lanes, on the chip (or anywhere under
+    ``PADDLE_TPU_FORCE_PALLAS``, interpreted). Everything else takes the
+    walk, which is also the kernels' reference."""
+    return _backend.use_kernel(
+        "w3" in w and act in (F.silu, jax.nn.silu)
+        and grouped_experts.aligned(x.shape[1], w["w1"].shape[2],
+                                    block_rows))
+
+
 def held_experts_apply(x, expert_idx, gates, w: dict, act, first: int,
                        block_rows: int = 256, min_blocks: int = 0):
     """The held experts' part of a dropless top-k layer.
@@ -633,9 +800,18 @@ def held_experts_apply(x, expert_idx, gates, w: dict, act, first: int,
     ``block_rows - 1`` rows of zeros an expert are multiplied in
     vain; with ``min_blocks`` every held expert walks at least that
     many blocks, so that the layer's time does not follow small
-    differences of load (``HeldExpertsMoE.even_share_slack``)."""
+    differences of load (``HeldExpertsMoE.even_share_slack``).
+
+    Gated silu experts whose widths are whole lanes go, on the chip,
+    through the grouped kernels instead (``_use_grouped``): the same
+    rows in the same blocks, there tiles of one sorted buffer, and an
+    expert without a row takes one tile of zeros."""
     t, k = expert_idx.shape
     order, counts = _held_rows(expert_idx, first, w["w1"].shape[0])
+    if _use_grouped(x, w, act, block_rows):
+        return _grouped_experts(
+            k, block_rows, min_blocks, x,
+            gates.reshape(-1).astype(jnp.float32), order, counts, w), counts
     # room for an expert's last block to read a whole slice
     order = jnp.pad(order, (0, block_rows))
     y = _held_experts(act, k, block_rows, min_blocks, x,
@@ -644,17 +820,32 @@ def held_experts_apply(x, expert_idx, gates, w: dict, act, first: int,
     return y, counts
 
 
+def held_rows_walked(x, w: dict, act, counts, block_rows: int,
+                     min_blocks: int):
+    """The rows ``held_experts_apply`` multiplies for these counts, the
+    zeros that fill an expert's last block and the floor's included:
+    blocks times ``block_rows`` on the walk, live tiles times the tile
+    through the kernels. ``counts`` over it is the fill."""
+    least = max(min_blocks, 1) if _use_grouped(x, w, act, block_rows) \
+        else min_blocks
+    return jnp.sum(_tiles_walked(counts, block_rows, least)) * block_rows
+
+
 def sum_routing_counts(counts: list) -> dict:
     """A model's ``step_counters()`` from its sparse layers'
     ``last_counts``: rows summed over the layers, ``moe_rows_max`` the
-    fullest held expert of the worst layer; ``{}`` without a layer."""
+    fullest held expert of the worst layer, ``moe_rows_walked`` where
+    the layers leave it (gated ones do); ``{}`` without a layer."""
     if not counts:
         return {}
-    return {
+    out = {
         "moe_rows_routed": sum(c["rows_routed"] for c in counts),
         "moe_rows_held": sum(c["rows_held"] for c in counts),
         "moe_rows_max": jnp.max(jnp.stack(
             [c["rows_max"] for c in counts]))}
+    if all("rows_walked" in c for c in counts):
+        out["moe_rows_walked"] = sum(c["rows_walked"] for c in counts)
+    return out
 
 
 class HeldExpertsMoE(Layer):
@@ -683,7 +874,11 @@ class HeldExpertsMoE(Layer):
 
     forward(x [b, s, m]) -> y [b, s, m]; the step's routing counts are
     left in ``last_counts`` ({"rows_routed", "rows_held", "rows_max"},
-    device scalars of the trace that called forward)."""
+    device scalars of the trace that called forward). A gated layer
+    also leaves "rows_walked", the rows its experts multiplied
+    (``held_rows_walked``: "rows_held" over it is the fill); the
+    two-matrix layer traces exactly what it did before the kernels
+    came, counters included."""
 
     def __init__(self, d_model: int, num_experts: int, d_hidden: int,
                  top_k: int, held, activation: str = "relu2",
@@ -751,6 +946,10 @@ class HeldExpertsMoE(Layer):
         self.last_counts = {
             "rows_routed": jnp.asarray(b * s * self.top_k, jnp.int32),
             "rows_held": jnp.sum(counts), "rows_max": jnp.max(counts)}
+        if e.w3 is not None:
+            self.last_counts["rows_walked"] = held_rows_walked(
+                xf, w, e.act, counts, self.block_rows,
+                self.min_blocks(b * s))
         if self.shared_experts is not None:
             with jax.named_scope("moe_shared"):
                 y = y + self.shared_experts(xf[None])[0]

@@ -10,5 +10,6 @@ ring_attention, paged_attention (block-table decode + fused
 single-pass decode: in-kernel RoPE + KV-append + attention),
 decode_attention (the contiguous-cache fused variant + dispatch gate +
 lax references), group_norm (fused NHWC GroupNorm+SiLU, custom VJP),
-selective_scan, quant_matmul, rope, ulysses.
+selective_scan, ssd, grouped_experts (gated experts over a sorted row
+buffer), quant_matmul, rope, ulysses.
 """

@@ -302,7 +302,9 @@ class Lfm2MoeForCausalLM(Layer):
         """The routing counts of the forward pass just traced, summed
         over the sparse layers (``moe_rows_max``: the fullest held
         expert of the worst layer), as ``NemotronHForCausalLM`` has
-        them; ``TrainStep`` returns them beside the gradient norm."""
+        them, and ``moe_rows_walked``, the rows the held experts
+        multiplied, padding and floor included; ``TrainStep`` returns
+        them beside the gradient norm."""
         return sum_routing_counts([
             layer.feed_forward.last_counts for layer in self.model.layers
             if layer.is_sparse])

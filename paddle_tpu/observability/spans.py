@@ -38,9 +38,10 @@ SPANS = {
         "dispatched ahead, then gauges and the watchdog; for a model "
         "with expert layers also that step's routing counts, summed "
         "over the expert blocks (moe_rows_max: the fullest held "
-        "expert of the worst block)",
+        "expert of the worst block; moe_rows_walked, from gated layers: "
+        "the rows the held experts multiplied, padding included)",
         ("interval_steps", "moe_rows_routed", "moe_rows_held",
-         "moe_rows_max"),
+         "moe_rows_max", "moe_rows_walked"),
         ("telemetry_idle_ms.train", "moe_held_rows_share.train",
          "moe_expert_load_max_over_mean.train",
          "swiglu_experts_rows_share.train",

@@ -346,8 +346,10 @@ def test_nemotron_nine_block_step_compiles_and_fits(topo, chip_compile):
                                jnp.int32)
     compiled = ts.lower({"input_ids": ids, "labels": ids}).compile()
     text = compiled.as_text()
-    # flash attention is there, and the held experts' loops are loops
+    # flash attention is there, and the held experts' loops are loops:
+    # the two-matrix experts stay on the walk, no grouped kernel here
     assert "tpu_custom_call" in text and " while(" in text
+    assert "experts_gate_up" not in text and "experts_d_" not in text
     lines = text.splitlines()
     calls = [ln for ln in lines if "tpu_custom_call" in ln
              and "custom-call(" in ln]
@@ -367,6 +369,38 @@ def test_nemotron_nine_block_step_compiles_and_fits(topo, chip_compile):
     # 15.75 GiB (16.91e9 B) is what a v5e chip gives a program; half a
     # gigabyte of it is left as margin. The step reads 15.76e9 B here
     assert total < 16.91e9 - 0.5e9, total
+
+
+@pytest.mark.parametrize("name, m, h, top_k, gated, kernels", [
+    ("nemotron", 2688, 1856, 6, False, 0), ("lfm2", 2048, 1792, 4, True, 6)])
+def test_held_experts_take_the_grouped_kernels_only_when_gated_and_aligned(
+        name, m, h, top_k, gated, kernels, chip_compile):
+    """On the chip (here: the kernels' switch forced as there) the
+    Nemotron cell's two-matrix experts of 1856 lower to the walk, with
+    no kernel call, forward or backward; the LFM2 cell's lower to the
+    six grouped calls."""
+    import re
+
+    from paddle_tpu.distributed import moe
+
+    bf16 = jnp.bfloat16
+    w = {"w1": ((8, m, h), bf16), "w2": ((8, h, m), bf16)}
+    if gated:
+        w["w3"] = w["w1"]
+    act = jax.nn.silu if gated else moe.relu2
+
+    def step(x, idx, gates, *w_):
+        def loss(x, gates, w_):
+            return jnp.sum(moe.held_experts_apply(
+                x, idx, gates, dict(zip(sorted(w), w_)), act, 0)[0])
+        return jax.value_and_grad(loss, (0, 1, 2))(x, gates, w_)
+
+    specs = [((8192, m), bf16), ((8192, top_k), jnp.int32),
+             ((8192, top_k), jnp.float32)] + [w[k] for k in sorted(w)]
+    text = chip_compile(step, specs).as_text()
+    assert len(re.findall(r"custom-call\(.*tpu_custom_call", text)) \
+        == kernels, name
+    assert ("experts_gate_up" in text) == bool(kernels)
 
 
 def _calls_matched(calls, metric):
@@ -472,9 +506,10 @@ def test_lfm2_five_layer_step_compiles_and_fits(topo, chip_compile):
     of 32 SwiGLU experts held, a quarter of the vocabulary with the head
     tied, 1 x 8192 tokens, AdamW with fp32 masters), compiled for a
     described v5e: plain causal flash attention lowers at head size 64
-    (padded to the 128 lanes), the gated experts' three products run
-    under their device-counted loops, whose hand-written backward keeps
-    the scope ``moe_experts`` that the cell's metric reads, the short
+    (padded to the 128 lanes), the gated experts' rows go through the
+    grouped kernels over one sorted buffer (no loop over the experts or
+    their blocks is left), whose calls keep the scope ``moe_experts``
+    that the cell's metric reads in both passes, the short
     convolution's taps and gates carry ``sconv_mix`` forward and
     backward, and the program fits one chip beside its 7.11 GB of state
     with nothing made again by XLA (no instruction named ``.remat``)."""
@@ -521,20 +556,47 @@ def test_lfm2_five_layer_step_compiles_and_fits(topo, chip_compile):
              if "tpu_custom_call" in ln and "custom-call(" in ln]
 
     # one attention layer: flash forward and its two-call backward at
-    # s = 8192, rep 4; all three counted by the cell's attention metric
-    assert len(calls) == 3 and len(_calls_matched(
-        calls, "qknorm_attn_device_ms.train")) == 3, calls
-    # the held experts' loops are loops, and both passes carry the scope
-    assert " while(" in text
+    # s = 8192, rep 4: the only calls the cell's attention metric counts
+    flash = _calls_matched(calls, "qknorm_attn_device_ms.train")
+    assert len(flash) == 3, flash
+    # a sparse layer's held rows go through the grouped kernels: two
+    # calls forward, four backward (the weight gradients as two), each
+    # under the scope the experts' metric reads, in its own pass
+    grouped = [ln for ln in calls if ln not in flash]
+    kernels = {"experts_gate_up": "jvp", "experts_down": "jvp",
+               "experts_d_hidden": "transpose", "experts_d_rows": "transpose",
+               "experts_d_weights": "transpose"}
+    sparse = len(cfg.layer_types) - cfg.num_dense_layers
+    assert len(grouped) == 6 * sparse, len(grouped)
+    for ln in grouped:
+        op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
+        kernel, = [k for k in kernels
+                   if re.search(r"[/(]%s[/)]" % k, op_name)]
+        under = r"(.*[/(])?moe_experts[/)]"
+        if kernels[kernel] == "transpose":
+            assert re.search(r"transpose\(jvp\(" + under, op_name), op_name
+        else:
+            assert re.search(r"jvp\(" + under, op_name), op_name
+            assert "transpose(" not in op_name, op_name
+    assert sum(ln.count("experts_d_weights") > 0 for ln in grouped) \
+        == 2 * sparse
     names = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in ("moe_experts", "sconv_mix"):
         assert any(re.search(r"jvp\((.*[/(])?%s[/)]" % scope, n)
                    for n in names), scope
         assert any(re.search(r"transpose\(jvp\((.*[/(])?%s[/)]" % scope, n)
                    for n in names), scope
-    # three products an expert block: w3 is there, forward and backward
-    f = cfg.moe_intermediate_size
-    assert re.search(rf"bf16\[8,{cfg.hidden_size},{f}\]", text)
+    # the per-expert scan and its block loops are gone: no loop carries
+    # the stacked matrices or a float32 sum of a weight gradient (what
+    # loops are left move the live rows into and out of the sorted
+    # buffer, and sort)
+    f, hid = cfg.moe_intermediate_size, cfg.hidden_size
+    assert re.search(rf"bf16\[8,{hid},{f}\]", text)
+    whiles = [ln for ln in lines if " while(" in ln]
+    assert whiles
+    for ln in whiles:
+        assert not re.search(
+            rf"bf16\[8,{hid},{f}\]|f32\[{hid},{f}\]|f32\[{f},{hid}\]", ln), ln
     by_xla = sorted(set(re.findall(r"%([\w.\-]+\.remat[\d.]*) = ", text)))
     assert not by_xla, by_xla
     m = compiled.memory_analysis()

@@ -1,6 +1,6 @@
 """One place knows how a kernel runs (``kernels/_backend.py``): no other
 module of the kernels asks JAX for the backend or reads
-``PADDLE_TPU_FORCE_PALLAS``, and the three dispatchers that choose
+``PADDLE_TPU_FORCE_PALLAS``, and the four dispatchers that choose
 between a kernel and its XLA reference follow that one rule."""
 
 import pathlib
@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from paddle_tpu.distributed import moe
 from paddle_tpu.inference import paged
 from paddle_tpu.kernels import flash_attention, ring_attention
 
@@ -39,8 +40,22 @@ def _pool(page_size):
     return paged.PagedLayerCache(pages, pages)
 
 
+def _experts(m, h, gated):
+    """(x, the held experts' matrices, their activation, block rows)."""
+    w = {"w1": jax.ShapeDtypeStruct((8, m, h), jnp.bfloat16),
+         "w2": jax.ShapeDtypeStruct((8, h, m), jnp.bfloat16)}
+    if gated:
+        w["w3"] = w["w1"]
+    return (jax.ShapeDtypeStruct((8192, m), jnp.bfloat16), w,
+            jax.nn.silu if gated else moe.relu2, 256)
+
+
 # dispatcher -> (its gate, arguments that tile, arguments that do not)
 GATES = {
+    # the LFM2 cell's gated experts; the Nemotron cell's two matrices of
+    # a width that is no whole number of lanes
+    "experts": (moe._use_grouped, _experts(2048, 1792, True),
+                _experts(2688, 1856, False)),
     "flash": (flash_attention._use_pallas, (_qkv(256, 64),),
               (_qkv(200, 64),)),
     "ring": (ring_attention._use_flash, (256, 256, 128), (256, 256, 64)),

@@ -256,6 +256,9 @@ def test_trains_through_train_step_and_reports_routing_counts():
         assert sample["moe_rows_routed"] == 4 * 128 * 2
         assert 0 < sample["moe_rows_held"] < sample["moe_rows_routed"]
         assert sample["moe_rows_max"] * 16 >= sample["moe_rows_held"]
+        # an even share is 32 rows and the floor one block of 256 an
+        # expert: 4 sparse layers x 4 held experts each walk one
+        assert sample["moe_rows_walked"] == 4 * 4 * 256
     finally:
         pt.flags.set_flags({"FLAGS_telemetry": prev})
 
