@@ -1,10 +1,12 @@
 """Flight recorder + anomaly watchdog — the postmortem artifact.
 
-A ring buffer holds the last K step records (step index, wall time,
-loss / grad-norm / memory when sampled). When the watchdog sees a
-NaN/Inf loss or a grad-norm spike it dumps the whole window to a JSON
-file, so a blown-up run leaves evidence of the steps that led into the
-anomaly instead of just a stack trace. Dumps also attach the tail of
+A ring buffer holds the last K step records (step index, start time,
+host phases, the garbage collector's pauses and the compiles since the
+record before; loss / grad-norm / memory when sampled). When the
+watchdog sees a NaN/Inf loss, a grad-norm spike or a slow sampled
+interval it dumps the whole window to a JSON file, so a blown-up or
+stalled run leaves evidence of the steps that led into the anomaly
+instead of just a stack trace or a low rate. Dumps also attach the tail of
 every live lifecycle tracer (``tracing.recent_events``) — when a
 serving engine shares the process, the dump shows what the engine was
 DOING around the anomaly (which programs ran, which requests moved),
@@ -16,11 +18,16 @@ from __future__ import annotations
 import json
 import math
 import os
+import sys
 import time
 from collections import deque
 from typing import Optional
 
 from .registry import get_registry
+
+# a sampled interval whose time per step exceeds this many times the
+# running median of recent intervals' is a stall worth a dump
+SLOW_INTERVAL_FACTOR = 2.0
 
 
 class FlightRecorder:
@@ -88,8 +95,11 @@ class FlightRecorder:
 
 class AnomalyWatchdog:
     """Checks sampled step stats and triggers a flight-recorder dump on
-    NaN/Inf loss or a grad-norm spike (> ``spike_factor`` x the running
-    median of recent finite grad norms)."""
+    NaN/Inf loss, a grad-norm spike (> ``spike_factor`` x the running
+    median of recent finite grad norms) or a slow interval (time per
+    step between two sampled reads > ``SLOW_INTERVAL_FACTOR`` x the
+    running median of recent intervals'). Each dump names its path in
+    one line on stderr."""
 
     def __init__(self, recorder: FlightRecorder,
                  spike_factor: float = 10.0,
@@ -98,36 +108,48 @@ class AnomalyWatchdog:
         self.spike_factor = float(spike_factor)
         self.min_history = int(min_history)
         self._norms: deque = deque(maxlen=int(history))
+        self._step_s: deque = deque(maxlen=int(history))
         self.tripped: list = []  # (step, reason, dump_path)
 
-    def _median(self) -> Optional[float]:
-        if len(self._norms) < self.min_history:
+    def _median(self, values: deque) -> Optional[float]:
+        if len(values) < self.min_history:
             return None
-        vals = sorted(self._norms)
-        return vals[len(vals) // 2]
+        return sorted(values)[len(values) // 2]
 
     def check(self, step: int, loss: Optional[float],
-              grad_norm: Optional[float]) -> Optional[str]:
+              grad_norm: Optional[float],
+              step_s: Optional[float] = None) -> Optional[str]:
         """Returns the dump path when an anomaly fired, else None."""
-        reason = None
+        reason, extra = None, None
         if loss is not None and not math.isfinite(loss):
             reason = f"non-finite loss {loss} at step {step}"
         elif grad_norm is not None and not math.isfinite(grad_norm):
             reason = f"non-finite grad norm {grad_norm} at step {step}"
         elif grad_norm is not None:
-            med = self._median()
+            med = self._median(self._norms)
             if med is not None and med > 0 and \
                     grad_norm > self.spike_factor * med:
                 reason = (f"grad-norm spike {grad_norm:.4g} > "
                           f"{self.spike_factor:g}x median {med:.4g} "
                           f"at step {step}")
+        if reason is None and step_s is not None:
+            med = self._median(self._step_s)
+            if med is not None and step_s > SLOW_INTERVAL_FACTOR * med:
+                reason = "slow interval"
+                extra = {"step": step, "step_ms": step_s * 1e3,
+                         "median_step_ms": med * 1e3}
         if grad_norm is not None and math.isfinite(grad_norm):
             self._norms.append(grad_norm)
+        if step_s is not None:
+            self._step_s.append(step_s)
         if reason is None:
             return None
         get_registry().counter(
             "pt_train_anomalies_total",
-            "anomaly-watchdog trips (NaN/Inf loss, grad spikes)").inc()
-        path = self.recorder.dump(reason)
+            "anomaly-watchdog trips (NaN/Inf loss, grad spikes, slow "
+            "intervals)").inc()
+        path = self.recorder.dump(reason, extra)
         self.tripped.append((step, reason, path))
+        print(f"paddle_tpu: flight recorder dump ({reason}, step {step}): "
+              f"{path}", file=sys.stderr, flush=True)
         return path

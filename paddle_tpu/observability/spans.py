@@ -31,7 +31,12 @@ SPANS = {
     "pt.train.dispatch": (
         "trainer host", "the compiled step's call: cache lookup and "
         "dispatch; the runtime holds it back when about six steps are "
-        "queued", (), ("idle_attributed_share.train",)),
+        "queued. Once a TrainTelemetry counts compiles, a call that "
+        "traced, lowered, compiled or loaded a program from the "
+        "persistent cache carries how many backend compiles (a cache "
+        "load is one) and their milliseconds; a steady step carries no "
+        "argument",
+        ("compiles", "compile_ms"), ("idle_attributed_share.train",)),
     "pt.train.sample_fetch": (
         "trainer host", "a sampled step only: telemetry's read of the "
         "loss and the gradient norm, which waits for every step "
@@ -39,16 +44,29 @@ SPANS = {
         "with expert layers also that step's routing counts, summed "
         "over the expert blocks (moe_rows_max: the fullest held "
         "expert of the worst block; moe_rows_walked, from gated layers: "
-        "the rows the held experts multiplied, padding included)",
+        "the rows the held experts multiplied, padding included); "
+        "gc_ms, gc_count, compile_ms, compiles: the garbage collector's "
+        "pauses and the programs traced, compiled or loaded in the "
+        "process since the sample before",
         ("interval_steps", "moe_rows_routed", "moe_rows_held",
-         "moe_rows_max", "moe_rows_walked"),
+         "moe_rows_max", "moe_rows_walked", "gc_ms", "gc_count",
+         "compile_ms", "compiles"),
         ("telemetry_idle_ms.train", "moe_held_rows_share.train",
          "moe_expert_load_max_over_mean.train",
          "swiglu_experts_rows_share.train",
-         "swiglu_experts_load_max_over_mean.train")),
+         "swiglu_experts_load_max_over_mean.train", "host_gc_ms.train",
+         "compile_ms.train")),
     "pt.train.sync_to_model": (
         "trainer host", "rebinding the model's parameters to the "
         "step's outputs", (), ("idle_attributed_share.train",)),
+    "pt.gc": (
+        "trainer host", "a pause of Python's cyclic garbage collector, "
+        "on the thread that triggered it and inside whichever span was "
+        "open there (any process that built a TrainTelemetry: a serving "
+        "engine's ticks too); collected is set at its end",
+        ("generation", "collected"),
+        ("gc_idle_ms.train", "idle_attributed_share.train",
+         "idle_attributed_share.serve")),
     "pt.engine.tick": (
         "engine", "one scheduler tick, step() or step_chunk(), "
         "epilogue included; the arguments are the state the tick "

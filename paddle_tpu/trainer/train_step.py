@@ -428,12 +428,17 @@ class TrainStep:
         tel = self.telemetry
         bench = bool(flags.flag("benchmark"))
         t0 = time.perf_counter() if tel is not None or bench else 0.0
+        t_ns = time.time_ns() if tel is not None else 0
         if not sharded:
             with jax.profiler.TraceAnnotation("pt.train.shard_batch"):
                 batch = self.shard_batch(batch)
         self._rng_key, sub = jax.random.split(self._rng_key)
         gnorm = counters = None
-        with jax.profiler.TraceAnnotation("pt.train.dispatch"), \
+        t1 = time.perf_counter() if tel is not None else 0.0
+        # a dispatch that traced, compiled or loaded a program says so
+        host = observability.train.HOST_EVENTS
+        compiles, compile_ms = host["compiles"], host["compile_ms"]
+        with jax.profiler.TraceAnnotation("pt.train.dispatch") as span, \
                 mesh_context(self.mesh):
             if self._emit_counters:
                 self.params, self.opt_state, loss, gnorm, counters = \
@@ -446,6 +451,11 @@ class TrainStep:
                 self.params, self.opt_state, loss = self._step(
                     self.params, self.opt_state, batch, sub
                 )
+            if host["compile_ms"] != compile_ms:
+                span.set_metadata(
+                    compiles=host["compiles"] - compiles,
+                    compile_ms=host["compile_ms"] - compile_ms)
+        t2 = time.perf_counter() if tel is not None else 0.0
         self.step_count += 1
         if bench or flags.flag("check_nan_inf"):
             # debug knobs — BOTH force a host sync on the step's
@@ -492,7 +502,8 @@ class TrainStep:
             # sampled step (TrainTelemetry fetches them only then)
             tel.on_step(
                 self.step_count, loss, gnorm, tokens=tokens,
-                wall_s=time.perf_counter() - t0, counters=counters)
+                wall_s=time.perf_counter() - t0, counters=counters,
+                t_ns=t_ns, shard_s=t1 - t0, dispatch_s=t2 - t1)
         with jax.profiler.TraceAnnotation("pt.train.sync_to_model"):
             if not self._master_dtypes:
                 self.sync_to_model()

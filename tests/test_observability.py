@@ -2,10 +2,12 @@
 TrainStep sampling cadence + flight-recorder NaN dump, serving engine
 metrics smoke, collective byte accounting, and the dump CLI."""
 
+import gc
 import json
 import re
 import subprocess
 import sys
+import time
 import urllib.request
 
 import jax
@@ -180,7 +182,9 @@ def test_trainstep_sampling_cadence_and_gnorm(tmp_path):
         assert r["tokens_per_sec"] > 0
     # non-sampled records carry only host-side fields (no device sync)
     unsampled = [r for r in recs if "loss" not in r]
-    assert all(set(r) == {"step", "wall_ms", "tokens"} for r in unsampled)
+    assert all(set(r) == {"step", "t_ns", "wall_ms", "shard_ms",
+                          "dispatch_ms", "gc_ms", "compile_ms", "tokens"}
+               for r in unsampled)
     assert not tel.watchdog.tripped
 
 
@@ -219,6 +223,111 @@ def test_watchdog_grad_spike(tmp_path):
     path = wd.check(5, 0.5, 50.0)  # 50x the median
     assert path and "spike" in wd.tripped[0][1]
     assert json.loads(open(path).read())["n_records"] == 4
+
+
+@pytest.mark.fast
+def test_watchdog_slow_interval(tmp_path, capsys):
+    rec = obs.FlightRecorder(capacity=4, dump_dir=str(tmp_path))
+    wd = obs.AnomalyWatchdog(rec, min_history=5)
+    for s in range(5):  # too few intervals to judge: no check at all
+        rec.record(step=s)
+        assert wd.check(s, 0.5, 1.0, step_s=0.1 if s else 9.0) is None
+    assert wd.check(5, 0.5, 1.0, step_s=0.19) is None  # under 2x
+    path = wd.check(6, 0.5, 1.0, step_s=0.25)
+    assert path and wd.tripped == [(6, "slow interval", path)]
+    dump = json.loads(open(path).read())
+    assert dump["reason"] == "slow interval"
+    assert dump["extra"]["step"] == 6
+    assert dump["extra"]["step_ms"] == pytest.approx(250.0)
+    assert dump["extra"]["median_step_ms"] == pytest.approx(100.0)
+    # one line on stderr names the dump
+    err = capsys.readouterr().err.strip().splitlines()
+    assert len(err) == 1 and path in err[0] and "slow interval" in err[0]
+
+
+def _sleepy_run(tel, steps, sleep_s, slow_before=None):
+    """``steps`` steps of a tiny regression, each after a sleep; one
+    more sleep of a second before step ``slow_before``. The collector
+    is off: its pauses are what the check exists for."""
+    pt.seed(0)
+    mesh = dist.build_mesh(devices=jax.devices()[:1])
+    ts = TrainStep(_Reg(), opt.AdamW(1e-3), mesh, loss_fn=_mse,
+                   telemetry=tel)
+    batch = {"input": jnp.ones((4, 8)), "label": jnp.zeros((4, 8))}
+    gc.disable()
+    try:
+        for step in range(1, steps + 1):
+            time.sleep(sleep_s + (1.0 if step == slow_before else 0.0))
+            ts.run(batch)
+    finally:
+        gc.enable()
+    return ts
+
+
+def test_a_slow_interval_dumps_and_a_steady_run_does_not(tmp_path):
+    steady = obs.TrainTelemetry(sample_every=2, dump_dir=str(tmp_path / "a"))
+    _sleepy_run(steady, 16, 0.03)
+    assert steady.samples == 8 and not steady.watchdog.tripped
+    tel = obs.TrainTelemetry(sample_every=2, dump_dir=str(tmp_path / "b"))
+    _sleepy_run(tel, 16, 0.03, slow_before=14)  # a sampled step
+    (step, reason, path), = tel.watchdog.tripped
+    assert (step, reason) == (14, "slow interval")
+    dump = json.loads(open(path).read())
+    assert dump["extra"]["step_ms"] > 400
+    last = dump["records"][-1]
+    assert last["step"] == 14 and last["wall_ms"] < 1000
+    for key in ("t_ns", "shard_ms", "dispatch_ms", "gc_ms", "compile_ms"):
+        assert key in last, key
+
+
+def test_step_ms_histogram_observes_each_sampled_interval(tmp_path,
+                                                          monkeypatch):
+    hist = obs.get_registry().histogram("pt_train_step_ms", "")
+    before = hist.count()
+    tel = obs.TrainTelemetry(sample_every=2, dump_dir=str(tmp_path))
+    seen = []
+    real = tel._step_ms.observe
+    monkeypatch.setattr(tel._step_ms, "observe",
+                        lambda v: (seen.append(v), real(v)))
+    _sleepy_run(tel, 6, 0.02)
+    assert hist.count() - before == len(seen) == tel.samples == 3
+    # an interval's time per step: each step came after 20 ms of sleep
+    assert all(v >= 20.0 for v in seen[1:])
+
+
+def test_a_sampled_step_reads_its_scalars_once(tmp_path, monkeypatch):
+    from paddle_tpu.observability import train as obs_train
+
+    reads = []
+    real = jax.device_get
+
+    def counted(x):
+        reads.append(x)
+        return real(x)
+
+    monkeypatch.setattr(obs_train.jax, "device_get", counted)
+    tel = obs.TrainTelemetry(sample_every=3, dump_dir=str(tmp_path))
+    _sleepy_run(tel, 6, 0.0)
+    assert len(reads) == tel.samples == 2
+    loss, gnorm, counters = reads[0]
+    assert isinstance(loss, jax.Array) and isinstance(gnorm, jax.Array)
+    assert counters is None  # a model without expert layers
+
+
+def test_host_hooks_install_once_and_count_compiles(tmp_path):
+    from paddle_tpu.observability import train as obs_train
+
+    obs.TrainTelemetry(dump_dir=str(tmp_path))
+    obs.TrainTelemetry(dump_dir=str(tmp_path))
+    assert gc.callbacks.count(obs_train._on_gc) == 1
+    host = obs_train.HOST_EVENTS
+    x = jnp.arange(5.0)
+    compiles, ms = host["compiles"], host["compile_ms"]
+    jax.jit(lambda x: x * 3 + 1)(x).block_until_ready()
+    assert host["compiles"] == compiles + 1 and host["compile_ms"] > ms
+    count, ms = host["gc_count"], host["gc_ms"]
+    gc.collect()
+    assert host["gc_count"] == count + 1 and host["gc_ms"] > ms
 
 
 def test_log_memory_stats_flag(tmp_path):

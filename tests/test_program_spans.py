@@ -10,6 +10,7 @@ table covers every ``"pt.`` literal in the package.
 """
 
 import contextlib
+import gc
 import glob
 import os
 import re
@@ -133,7 +134,8 @@ def test_trainer_spans_nest_and_fetch_only_on_the_sampled_step(tmp_path):
     assert all(s[3]["tokens"] == 32 for s in steps)
     assert len({s[4] for s in steps}) == 1  # one thread
     for step in steps:
-        kids = [k[2] for k in _children(rows, step)]
+        # a collector's pause nests wherever it struck
+        kids = [k[2] for k in _children(rows, step) if k[2] != "pt.gc"]
         want = ["pt.train.shard_batch", "pt.train.dispatch"]
         if step[3]["step_num"] == 3:  # the sampled one
             want.append("pt.train.sample_fetch")
@@ -141,6 +143,84 @@ def test_trainer_spans_nest_and_fetch_only_on_the_sampled_step(tmp_path):
     fetch, = [r for r in rows if r[2] == "pt.train.sample_fetch"]
     assert fetch[3]["interval_steps"] == 3
     assert {r[2] for r in rows} <= set(SPANS)
+
+
+def _profile_start_ns(trace_dir) -> int:
+    """The wall-clock ns the trace's host times count from: the plane
+    ``Task Environment`` holds it as ``profile_start_time``."""
+    path, = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    env, = [p for p in data.planes if p.name == "Task Environment"]
+    return dict(env.stats)["profile_start_time"]
+
+
+def _tiny_batch(seq=16):
+    ids = np.random.default_rng(0).integers(0, 256, (2, seq), np.int32)
+    return {"input_ids": ids, "labels": ids}
+
+
+def test_a_collection_is_a_pt_gc_span_that_the_sampled_fetch_counts(
+        tmp_path):
+    ts = _tiny_step(telemetry=obs.TrainTelemetry(
+        sample_every=2, dump_dir=str(tmp_path / "dumps")))
+    batch = _tiny_batch()
+    ts.run(batch)
+    ts.run(batch)  # compiled; step 2's sample opens a fresh interval
+
+    def loop():
+        ts.run(batch)
+        gc.collect()  # a full collection between two steps ...
+        ts.run(batch)  # ... which step 4's sampled fetch reports
+
+    rows = _traced(tmp_path / "trace", loop)
+    pauses = [r for r in rows if r[2] == "pt.gc"]
+    full = [r for r in pauses if r[3]["generation"] == 2]
+    assert full and all(isinstance(r[3]["collected"], int) for r in full)
+    step_thread = {r[4] for r in rows if r[2] == "pt.train.step"}
+    assert {r[4] for r in full} == step_thread  # the loop's own thread
+    fetch, = [r for r in rows if r[2] == "pt.train.sample_fetch"]
+    assert fetch[3]["interval_steps"] == 2
+    # the interval's pauses cover every pause the trace holds in it
+    assert fetch[3]["gc_count"] >= len(pauses)
+    assert fetch[3]["gc_ms"] >= sum(r[1] - r[0] for r in pauses) / 1e6
+    assert fetch[3]["compiles"] == 0 and fetch[3]["compile_ms"] == 0
+    rec = ts.telemetry.recorder.records()[-1]
+    assert rec["step"] == 4 and rec["gc_ms"] > 0
+
+
+def test_a_new_shape_compiles_on_that_steps_dispatch_alone(tmp_path):
+    ts = _tiny_step(telemetry=obs.TrainTelemetry(
+        sample_every=100, dump_dir=str(tmp_path / "dumps")))
+    short, longer = _tiny_batch(16), _tiny_batch(32)
+    ts.run(short)  # compiles, outside the trace
+    rows = _traced(tmp_path / "trace", lambda: [
+        ts.run(b) for b in (short, longer, longer, short)])
+    dispatch = [r[3] for r in rows if r[2] == "pt.train.dispatch"]
+    assert len(dispatch) == 4
+    assert dispatch[1]["compiles"] >= 1 and dispatch[1]["compile_ms"] > 0
+    for steady in (dispatch[0], dispatch[2], dispatch[3]):
+        assert "compiles" not in steady and "compile_ms" not in steady
+    recs = ts.telemetry.recorder.records()[-4:]
+    assert [r["compile_ms"] > 0 for r in recs] == [False, True, False,
+                                                   False]
+
+
+def test_a_records_t_ns_lies_inside_its_own_step_span(tmp_path):
+    ts = _tiny_step(telemetry=obs.TrainTelemetry(
+        sample_every=2, dump_dir=str(tmp_path / "dumps")))
+    batch = _tiny_batch()
+    ts.run(batch)
+    rows = _traced(tmp_path / "trace",
+                   lambda: [ts.run(batch) for _ in range(4)])
+    start = _profile_start_ns(tmp_path / "trace")
+    steps = {r[3]["step_num"]: r for r in rows if r[2] == "pt.train.step"}
+    recs = ts.telemetry.recorder.records()[-4:]
+    assert sorted(steps) == [r["step"] for r in recs] == [2, 3, 4, 5]
+    for rec in recs:
+        s, e = steps[rec["step"]][:2]
+        assert s <= rec["t_ns"] - start <= e, (rec, s, e)
+        assert 0 <= rec["shard_ms"] + rec["dispatch_ms"] <= rec["wall_ms"]
 
 
 def test_engine_tick_holds_dispatch_sync_emit_with_integer_arguments(
@@ -325,3 +405,13 @@ def test_the_span_table_covers_every_pt_literal_in_the_package():
         for metric in row[3]:
             assert os.path.exists(os.path.join(
                 ROOT, "chipbench", "metrics", metric + ".json")), metric
+
+
+def test_the_table_lists_the_host_events_and_their_arguments():
+    layer, _, args, metrics = SPANS["pt.gc"]
+    assert layer == "trainer host" and args == ("generation", "collected")
+    assert "gc_idle_ms.train" in metrics
+    assert set(SPANS["pt.train.dispatch"][2]) == {"compiles", "compile_ms"}
+    fetch = SPANS["pt.train.sample_fetch"]
+    assert {"gc_ms", "gc_count", "compile_ms", "compiles"} <= set(fetch[2])
+    assert {"host_gc_ms.train", "compile_ms.train"} <= set(fetch[3])
