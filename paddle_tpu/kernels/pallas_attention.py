@@ -26,15 +26,16 @@ Design notes (per /opt/skills/guides/pallas_guide.md):
     whole tiles were 0.75; 0.52 at s = 4096, 0.51 at 8192. Tiles with
     nothing to prune run whole, as one pair of large matmuls; so do
     non-causal calls, non-square blocks and blocks of 128-256.
-  - backward is ONE kernel body (_bwd_kernel). While one kv group's dq
-    accumulator fits _FUSED_DQ_VMEM_BUDGET it runs fused: s/p/dp/ds once
-    per strip for all three gradients, dk/dv accumulating in VMEM over
-    (rep, q blocks) (which also sums the GQA group) and dq accumulating
-    in a float32 VMEM scratch over the k blocks, written once in the
-    input dtype. Beyond the budget it runs twice, a dq pass with k
-    innermost and a dk/dv pass. No gradient has HBM partials; every
-    gradient's HBM footprint equals its final size. delta =
-    rowsum(o · dO) is computed in the kernel from the o and dO blocks.
+  - backward is ONE fused kernel at every shape (_bwd_kernel): s/p/dp/ds
+    once per strip for all three gradients; dq accumulates in float32
+    VMEM scratch over the k blocks, dk/dv over (rep, q blocks), which
+    also sums the GQA group. Its grid streams q blocks innermost and
+    keeps the group's whole dq while that fits _WHOLE_DQ_VMEM_BUDGET,
+    else streams k blocks innermost and keeps the group's whole dk/dv,
+    whose size does not grow with the GQA group. No gradient has HBM
+    partials; every gradient's HBM footprint equals its final size.
+    delta = rowsum(o · dO) is computed in the kernel from the o and dO
+    blocks.
   - varlen/packed sequences via segment ids (parity with
     flash_attn_varlen): tokens attend only within equal segment id;
     padding can be given a sentinel segment.
@@ -59,6 +60,15 @@ work at the chip's 197 TFLOP/s is 0.35 / 0.87):
 Mosaic keeps to program order, so HOW the strips are written decides
 whether a strip's VPU work runs under its neighbours' matmuls; under
 128 rows a strip loses more at the MXU than it prunes.
+
+The backward at the four train cells' shapes (PR 41's chip runs, PERF.md
+section 6: bf16, d 128, causal, blocks 1024; ms a call, before PR 41 /
+this file, and the grid order not taken):
+  g 8, rep 4, s 8192 (LFM2): two calls 12.14 / k innermost 8.86
+  g 2, rep 16, s 8192 (Nemotron): two calls 12.07 / k innermost 8.79
+  g 16, rep 4, s 2048 (Mistral): 1.25 / q innermost 1.25; k 1.32
+  g 10, rep 2, s 8192, window 512 (Phi-4-mini-flash): 1.53 / 1.53; k 1.49
+  the same, full and cross-attention: 5.30 / 5.30; k innermost 5.55
 """
 
 from __future__ import annotations
@@ -85,12 +95,13 @@ LANES = 128
 _STRIP = LANES
 # what Mosaic grants a kernel on a v5e without being asked: every
 # kernel's per-tile blocks and temporaries fit it at the default blocks
-# (tests/test_chip_compile.py holds that)
+# (tests/test_chip_compile.py holds that); the backward asks for its
+# whole-length accumulator on top
 _TILE_VMEM_BYTES = 16 << 20
-# the fused backward keeps one kv group's whole dq on chip (a float32
-# accumulator and the double-buffered output block); past this budget
-# the two-pass backward runs. 16 MiB is s = 4096 at rep 4, d 128, bf16.
-_FUSED_DQ_VMEM_BUDGET = 16 << 20
+# the backward streams q blocks innermost while one kv group's whole dq
+# fits this (s = 4096 at rep 4, s = 8192 at rep 2; d 128, bf16), and k
+# blocks innermost beyond it (see _bwd_kernel)
+_WHOLE_DQ_VMEM_BUDGET = 16 << 20
 
 
 def _causal_j_max(i: int, q_block: int, k_block: int):
@@ -438,55 +449,57 @@ def _mha_fwd_impl(q, k, v, qseg, kseg, sm_scale, causal, q_block, k_block,
 
 
 # ---------------------------------------------------------------------------
-# backward. One kernel body, three uses:
-#   fused (emit_dq and emit_dkv; flash-v2 backward proper): grid
-#     (g, kb, rep, qb). s/p/dp/ds are computed ONCE per strip and feed
-#     all three gradients, 5 matmuls and one VPU chain. dk/dv accumulate
-#     in VMEM scratch over (rep, qb), which also sums the GQA group; dq,
-#     whose natural accumulation order is transposed, accumulates in a
-#     float32 VMEM scratch that holds one g's whole [rep, sq, d] across
-#     the kb axis and is written once, in the input dtype. Taken while
-#     that accumulator fits _FUSED_DQ_VMEM_BUDGET.
-#   dq pass (emit_dq alone): grid (g, rep, qb, kb), k innermost, one q
-#     block of dq in scratch.            } the two-pass backward: 7
-#   dk/dv pass (emit_dkv alone): the     } matmuls and the VPU chain
-#     fused grid without dq.             } twice
-# Every gradient's HBM footprint equals its final size. delta =
-# rowsum(o · dO) (less the lse cotangent, where a caller differentiates
-# lse) is computed in the kernel from the o and dO blocks.
+# backward: ONE kernel (flash-v2 backward proper). s/p/dp/ds are computed
+# once per strip and feed all three gradients: 5 matmuls and one VPU
+# chain. dk/dv accumulate over (rep, q blocks), which also sums the GQA
+# group, and dq over the k blocks, in float32 VMEM scratch, each written
+# once in the input dtype. One of the two accumulators must live across
+# the other's whole length; the grid order decides which:
+#   q innermost, grid (g, kb, rep, qb): the group's whole dq,
+#     rep·sq·d·(4 + 2·itemsize) bytes with its output block, while that
+#     fits _WHOLE_DQ_VMEM_BUDGET; 5% faster a call than k innermost at
+#     the Mistral and Phi-4-mini-flash cells' shapes (PR 41's chip runs);
+#   k innermost, grid (g, rep, qb, kb), beyond it: the group's whole
+#     dk/dv, 2·sk·d·(4 + 2·itemsize) bytes, 16 MiB at sk 8192, d 128,
+#     bf16 whatever rep (a v5e core's 128 MiB holds sk to about 48k).
+# Both sum dk/dv rep-major over the q blocks and dq over ascending k
+# blocks, so their gradients are bitwise equal; keep those orders: the
+# Nemotron cell's routers follow every rounding upstream of them
+# (ROADMAP G1). Every gradient's HBM footprint equals its final size.
+# delta = rowsum(o · dO) (less the lse cotangent, where a caller
+# differentiates lse) is computed in the kernel from the o and dO blocks.
 # ---------------------------------------------------------------------------
 def _bwd_kernel(*refs, sm_scale, causal, q_block, k_block, n_qb, n_kb, rep,
-                with_dlse, with_segments, window, emit_dq, emit_dkv):
+                with_dlse, with_segments, window, q_inner):
     refs = iter(refs)
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref = (
         next(refs) for _ in range(6))
     dlse_ref = next(refs) if with_dlse else None
     qseg_ref = next(refs) if with_segments else None
     kseg_ref = next(refs) if with_segments else None
-    dq_ref = next(refs) if emit_dq else None
-    dk_ref, dv_ref = (next(refs), next(refs)) if emit_dkv else (None, None)
-    dq_scratch = next(refs) if emit_dq else None
-    dk_scratch, dv_scratch = (
-        (next(refs), next(refs)) if emit_dkv else (None, None))
+    dq_ref, dk_ref, dv_ref, dq_scratch, dk_scratch, dv_scratch = refs
 
-    if emit_dkv:
+    if q_inner:
         j, r, i = (pl.program_id(a) for a in (1, 2, 3))
     else:
         r, i, j = (pl.program_id(a) for a in (1, 2, 3))
-    # the fused pass keeps every q block of one g; the dq pass just its own
-    slot = r * n_qb + i if emit_dkv else 0
+    # where the whole-length accumulator keeps this step's block
+    slot = r * n_qb + i if q_inner else 0
+    kv_at = () if q_inner else (j,)
 
-    if emit_dkv:
-        @pl.when(jnp.logical_and(r == 0, i == 0))
-        def _init_dkv():
-            dk_scratch[:] = jnp.zeros_like(dk_scratch)
-            dv_scratch[:] = jnp.zeros_like(dv_scratch)
+    def dkv_step(r_, i_, j_):
+        # the step that opens / closes the group's dk/dv accumulation
+        at = jnp.logical_and(r == r_, i == i_)
+        return at if q_inner else jnp.logical_and(at, j == j_)
 
-    if emit_dq:
-        @pl.when(j == 0)
-        def _init_dq():
-            dq_scratch[slot] = jnp.zeros(dq_scratch.shape[1:],
-                                         dq_scratch.dtype)
+    @pl.when(dkv_step(0, 0, 0))
+    def _init_dkv():
+        dk_scratch[:] = jnp.zeros_like(dk_scratch)
+        dv_scratch[:] = jnp.zeros_like(dv_scratch)
+
+    @pl.when(j == 0)
+    def _init_dq():
+        dq_scratch[slot] = jnp.zeros(dq_scratch.shape[1:], dq_scratch.dtype)
 
     def _products(strip):
         # the two matmuls of a strip that wait for no VPU work. Operands
@@ -525,37 +538,33 @@ def _bwd_kernel(*refs, sm_scale, causal, q_block, k_block, n_qb, n_kb, rep,
                 delta = delta - dlse_ref[0, 0, r0:r1, :1]
             p = jnp.exp(s - lse)  # [rows, cols] f32, ONCE for all grads
             ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-            if emit_dkv:
-                # dv += p^T do
-                dv_scratch[c0:c1] += jax.lax.dot_general(
-                    p.astype(q.dtype), do, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                # dk += ds^T q
-                dk_scratch[c0:c1] += jax.lax.dot_general(
-                    ds, q, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            if emit_dq:
-                dq_scratch[slot, r0:r1] += jax.lax.dot_general(
-                    ds, k_ref[0, c0:c1, :], (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
+            # dv += p^T do
+            dv_scratch[(*kv_at, slice(c0, c1))] += jax.lax.dot_general(
+                p.astype(q.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            # dk += ds^T q
+            dk_scratch[(*kv_at, slice(c0, c1))] += jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dq_scratch[slot, r0:r1] += jax.lax.dot_general(
+                ds, k_ref[0, c0:c1, :], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
     _for_tile_cases(
         _tile_cases(i, j, n_qb, n_kb, q_block, k_block, causal, window),
         _tile)
 
-    if emit_dq:
-        @pl.when(j == n_kb - 1)
-        def _fin_dq():
-            dq_ref[0, slot] = dq_scratch[slot].astype(dq_ref.dtype)
+    @pl.when(j == n_kb - 1)
+    def _fin_dq():
+        dq_ref[0, slot] = dq_scratch[slot].astype(dq_ref.dtype)
 
-    if emit_dkv:
-        @pl.when(jnp.logical_and(r == rep - 1, i == n_qb - 1))
-        def _fin_dkv():
-            dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
-            dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
+    @pl.when(dkv_step(rep - 1, n_qb - 1, n_kb - 1))
+    def _fin_dkv():
+        dk_ref[0] = dk_scratch[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scratch[:].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=_STATIC)
@@ -573,95 +582,81 @@ def _mha_bwd_impl(q, k, v, o, do, lse, qseg, kseg, sm_scale, causal,
     live = g * rep * sq * sk * causal_live_share(
         sq, sk, q_block, k_block, _strip_rows(q_block, k_block, causal),
         causal, window)
+    # the whole-length accumulators' float32 scratch and output blocks
     dq_vmem = rep * sq * d * (4 + 2 * q.dtype.itemsize)
+    dkv_vmem = 2 * sk * d * (4 + 2 * q.dtype.itemsize)
+    q_inner = dq_vmem <= _WHOLE_DQ_VMEM_BUDGET
 
-    def run_pass(emit_dq, emit_dkv):
-        k_inner = not emit_dkv
-
-        def step(*ids):  # grid ids -> (b, r, i, j)
-            if k_inner:
-                return ids
+    def step(*ids):  # grid ids -> (b, r, i, j)
+        if q_inner:
             return ids[0], ids[2], ids[3], ids[1]
+        return ids
 
-        def q_index(*ids):
-            b, r, i, j = step(*ids)
-            if not k_inner:
-                i = _clamp_i(i, j, q_block, k_block, causal, window)
-            return (b, r, i, 0)
+    def q_index(*ids):
+        b, r, i, j = step(*ids)
+        if q_inner:
+            i = _clamp_i(i, j, q_block, k_block, causal, window)
+        return (b, r, i, 0)
 
-        def kv_index(*ids):
-            b, r, i, j = step(*ids)
-            if k_inner:
-                j = _clamp_j(i, j, q_block, k_block, causal, window)
-            return (b, j, 0)
+    def kv_index(*ids):
+        b, r, i, j = step(*ids)
+        if not q_inner:
+            j = _clamp_j(i, j, q_block, k_block, causal, window)
+        return (b, j, 0)
 
-        q_spec = pl.BlockSpec((1, 1, q_block, d), q_index)
-        row_spec = pl.BlockSpec((1, 1, q_block, LANES), q_index)
-        kv_spec = pl.BlockSpec((1, k_block, d), kv_index)
-        in_specs = ([q_spec, kv_spec, kv_spec, q_spec, q_spec]
-                    + [row_spec] * len(rows))
-        inputs = [q, k, v, o, do] + rows
-        if qseg is not None:
-            in_specs.append(pl.BlockSpec(
-                (1, q_block, LANES),
-                lambda *ids: (ids[0], q_index(*ids)[2], 0)))
-            in_specs.append(pl.BlockSpec(
-                (1, k_block), lambda *ids: kv_index(*ids)[:2]))
-            inputs += [qseg, kseg]
+    q_spec = pl.BlockSpec((1, 1, q_block, d), q_index)
+    row_spec = pl.BlockSpec((1, 1, q_block, LANES), q_index)
+    kv_spec = pl.BlockSpec((1, k_block, d), kv_index)
+    in_specs = ([q_spec, kv_spec, kv_spec, q_spec, q_spec]
+                + [row_spec] * len(rows))
+    inputs = [q, k, v, o, do] + rows
+    if qseg is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, q_block, LANES), lambda *ids: (ids[0], q_index(*ids)[2], 0)))
+        in_specs.append(pl.BlockSpec(
+            (1, k_block), lambda *ids: kv_index(*ids)[:2]))
+        inputs += [qseg, kseg]
 
-        out_specs, out_shape, scratch = [], [], []
-        if emit_dq:
-            slots = rep * n_qb if emit_dkv else 1
-            out_specs.append(pl.BlockSpec(
-                (1, slots, q_block, d),
-                (lambda b, j, r, i: (b, 0, 0, 0)) if emit_dkv else
-                (lambda b, r, i, j: (b, r * n_qb + i, 0, 0))))
-            out_shape.append(jax.ShapeDtypeStruct(
-                (g, rep * n_qb, q_block, d), q.dtype))
-            scratch.append(pltpu.VMEM((slots, q_block, d), jnp.float32))
-        if emit_dkv:
-            out_specs += [kv_spec] * 2
-            out_shape += [jax.ShapeDtypeStruct((g, sk, d), q.dtype)] * 2
-            scratch += [pltpu.VMEM((k_block, d), jnp.float32)] * 2
-        fused = emit_dq and emit_dkv
-        matmuls = 2 + emit_dq + 2 * emit_dkv
-        return pl.pallas_call(
-            functools.partial(
-                _bwd_kernel, sm_scale=sm_scale, causal=causal,
-                q_block=q_block, k_block=k_block, n_qb=n_qb, n_kb=n_kb,
-                rep=rep, with_dlse=dlse is not None,
-                with_segments=qseg is not None, window=window,
-                emit_dq=emit_dq, emit_dkv=emit_dkv,
-            ),
-            grid=(g, rep, n_qb, n_kb) if k_inner else (g, n_kb, rep, n_qb),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=scratch,
-            cost_estimate=pl.CostEstimate(
-                flops=int(2 * matmuls * live * d),
-                bytes_accessed=(5 * q.size + 4 * g * sk * d)
-                * q.dtype.itemsize,
-                transcendentals=int(live),
-            ),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=(
-                    "parallel",
-                    "arbitrary" if fused else "parallel",
-                    "parallel" if k_inner else "arbitrary",
-                    "arbitrary"),
-                vmem_limit_bytes=(
-                    _TILE_VMEM_BYTES + dq_vmem if fused else None),
-            ),
-            interpret=_backend.interpret(),
-        )(*inputs)
-
-    if dq_vmem <= _FUSED_DQ_VMEM_BUDGET:
-        dq, dk, dv = run_pass(True, True)
-    else:
-        (dq,) = run_pass(True, False)
-        dk, dv = run_pass(False, True)
-    return dq.reshape(g, rep, sq, d), dk, dv
+    slots = rep * n_qb if q_inner else 1
+    dq_spec = pl.BlockSpec(
+        (1, slots, q_block, d),
+        (lambda b, j, r, i: (b, 0, 0, 0)) if q_inner else
+        (lambda b, r, i, j: (b, r * n_qb + i, 0, 0)))
+    if q_inner:
+        dkv_spec, dkv_shape, dkv_scratch = kv_spec, (g, sk, d), (k_block, d)
+    else:  # one block of the group's whole kv length
+        dkv_spec = pl.BlockSpec((1, n_kb, k_block, d),
+                                lambda b, r, i, j: (b, 0, 0, 0))
+        dkv_shape, dkv_scratch = (g, n_kb, k_block, d), (n_kb, k_block, d)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, sm_scale=sm_scale, causal=causal,
+            q_block=q_block, k_block=k_block, n_qb=n_qb, n_kb=n_kb,
+            rep=rep, with_dlse=dlse is not None,
+            with_segments=qseg is not None, window=window, q_inner=q_inner,
+        ),
+        grid=(g, n_kb, rep, n_qb) if q_inner else (g, rep, n_qb, n_kb),
+        in_specs=in_specs,
+        out_specs=[dq_spec, dkv_spec, dkv_spec],
+        out_shape=[jax.ShapeDtypeStruct((g, rep * n_qb, q_block, d), q.dtype)]
+        + [jax.ShapeDtypeStruct(dkv_shape, q.dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((slots, q_block, d), jnp.float32)]
+        + [pltpu.VMEM(dkv_scratch, jnp.float32)] * 2,
+        cost_estimate=pl.CostEstimate(
+            flops=int(2 * 5 * live * d),
+            bytes_accessed=(5 * q.size + 4 * g * sk * d) * q.dtype.itemsize,
+            transcendentals=int(live),
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_TILE_VMEM_BYTES + (
+                dq_vmem if q_inner else dkv_vmem),
+        ),
+        interpret=_backend.interpret(),
+    )(*inputs)
+    return (dq.reshape(g, rep, sq, d), dk.reshape(g, sk, d),
+            dv.reshape(g, sk, d))
 
 
 # ---------------------------------------------------------------------------

@@ -354,9 +354,9 @@ def test_nemotron_nine_block_step_compiles_and_fits(topo, chip_compile):
     calls = [ln for ln in lines if "tpu_custom_call" in ln
              and "custom-call(" in ln]
     scans = [ln for ln in calls if re.search(r'op_name="[^"]*ssm_scan', ln)]
-    # 4 M blocks x (forward, backward); flash forward and its two-call
-    # backward at s = 8192
-    assert (len(scans), len(calls)) == (8, 11), (len(scans), len(calls))
+    # 4 M blocks x (forward, backward); flash forward and its one
+    # backward at s = 8192, rep 16
+    assert (len(scans), len(calls)) == (8, 10), (len(scans), len(calls))
     again = [ln.split(" = ")[0].strip() for ln in lines
              if "rematted_computation" in ln and re.search(
                  r" (custom-call|reduce-window|reduce)\(", ln)]
@@ -555,10 +555,10 @@ def test_lfm2_five_layer_step_compiles_and_fits(topo, chip_compile):
     calls = [ln.strip() for ln in lines
              if "tpu_custom_call" in ln and "custom-call(" in ln]
 
-    # one attention layer: flash forward and its two-call backward at
+    # one attention layer: flash forward and its one backward at
     # s = 8192, rep 4: the only calls the cell's attention metric counts
     flash = _calls_matched(calls, "qknorm_attn_device_ms.train")
-    assert len(flash) == 3, flash
+    assert len(flash) == 2, flash
     # a sparse layer's held rows go through the grouped kernels: two
     # calls forward, four backward (the weight gradients as two), each
     # under the scope the experts' metric reads, in its own pass

@@ -285,51 +285,6 @@ def test_flash_attention_window_fallback_paths():
                         dropout_p=0.5)
 
 
-@pytest.fixture
-def two_pass(monkeypatch):
-    """force() makes the dq and dk/dv passes run in place of the fused
-    backward: no dq accumulator fits a budget of 0. The kernels' calls
-    are jitted and the budget is baked into a cached trace, so JAX's
-    caches are cleared with every change of it."""
-    from paddle_tpu.kernels import pallas_attention as pa
-
-    def force():
-        monkeypatch.setattr(pa, "_FUSED_DQ_VMEM_BUDGET", 0)
-        jax.clear_caches()
-
-    yield force
-    monkeypatch.undo()
-    jax.clear_caches()
-
-
-def test_mha_grad_two_pass_path_matches_fused(two_pass):
-    """The fused backward is taken while one kv group's dq accumulator
-    fits _FUSED_DQ_VMEM_BUDGET, the two-pass backward beyond it; both
-    must produce the same gradients at the same blocks."""
-    from paddle_tpu.kernels import pallas_attention as pa
-
-    rng = np.random.default_rng(11)
-    q = jnp.asarray(rng.standard_normal((1, 768, 2, 64)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((1, 768, 2, 64)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((1, 768, 2, 64)), jnp.float32)
-    # rep 1, s 768, d padded to 128, f32: 384 KiB of scratch and 768 KiB
-    # of double-buffered output block
-    assert 768 * 128 * (4 + 2 * 4) <= pa._FUSED_DQ_VMEM_BUDGET
-
-    def grads():
-        def f(q, k, v):
-            return jnp.sum(
-                mha(q, k, v, causal=True, q_block=128, k_block=256) ** 2)
-        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-
-    g_fused = grads()
-    two_pass()
-    g_two = grads()
-    for a, b in zip(g_two, g_fused):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-4, atol=2e-5)
-
-
 def _dense_attention(q, k, v, window=0, seg=None):
     """Causal dense reference that also returns logsumexp [b, h, s]."""
     b, s, hq, d = q.shape
@@ -350,35 +305,35 @@ def _dense_attention(q, k, v, window=0, seg=None):
     return out, lse
 
 
-# s, block, q heads, kv heads, window, segment lengths, lse cotangent.
-# 512-blocks are worked in four 128-row strips; s = 1024 adds the tiles
-# below the diagonal, which a window of 600 crosses one block row down.
+# s, block, kv heads, window, segment lengths, lse cotangent; each case
+# runs at 1 and at 16 q heads a kv head. 512-blocks are worked in four
+# 128-row strips; s = 1024 adds the tiles below the diagonal, which a
+# window of 600 crosses one block row down.
 SUBTILED = {
-    "mha": (512, 512, 2, 2, 0, None, False),
-    "gqa_rep4": (512, 512, 4, 1, 0, None, False),
-    "window": (512, 512, 2, 2, 200, None, False),
-    "segments": (512, 512, 2, 2, 0, (200, 200, 112), False),
-    "dlse": (512, 512, 2, 2, 0, None, True),
-    "two_blocks_window": (1024, 512, 1, 1, 600, None, True),
+    "mha": (512, 512, 2, 0, None, False),
+    "two_blocks": (1024, 512, 1, 0, None, False),
+    "window": (512, 512, 2, 200, None, False),
+    "segments": (512, 512, 2, 0, (200, 200, 112), False),
+    "dlse": (512, 512, 2, 0, None, True),
+    "two_blocks_window": (1024, 512, 1, 600, None, True),
 }
 
 
-@pytest.mark.parametrize("backward", ["fused", "two_pass"])
+@pytest.mark.parametrize("rep", [1, 16])
 @pytest.mark.parametrize("case", sorted(SUBTILED))
-def test_subtiled_causal_matches_reference(case, backward, two_pass):
+def test_subtiled_causal_matches_reference(case, rep):
     """Forward and gradients where the diagonal tile is worked in strips
-    that skip what lies above the diagonal, in the fused and the
-    two-pass backward."""
+    that skip what lies above the diagonal, alone and in a GQA group of
+    16, whose dk/dv the backward sums over the group."""
     from paddle_tpu.kernels import pallas_attention as pa
 
-    s, blk, hq, hk, window, seg_lens, dlse = SUBTILED[case]
+    s, blk, hk, window, seg_lens, dlse = SUBTILED[case]
     assert pa._strip_rows(blk, blk) == 128
     assert pa.causal_live_share(s, s, blk, blk, 128, True, window) < \
         pa.causal_live_share(s, s, blk, blk, 0, True, window)
-    if backward == "two_pass":
-        two_pass()
     rng = np.random.default_rng(21)
-    q = jnp.asarray(rng.standard_normal((1, s, hq, 128)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((1, s, hk * rep, 128)),
+                    jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, s, hk, 128)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, s, hk, 128)), jnp.float32)
     seg = None
@@ -407,6 +362,134 @@ def test_subtiled_causal_matches_reference(case, backward, two_pass):
                                rtol=2e-3, atol=2e-3)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
                                rtol=2e-3, atol=2e-3)
+    gp = jax.grad(loss(run_pallas), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(run_ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-3, atol=5e-3)
+
+
+def _bwd_pallas_calls(rep, s, d=128):
+    """The pallas_call equations of the flash backward's jaxpr at the
+    cells' blocks, traced on abstract bf16 inputs (g 1, causal)."""
+    from paddle_tpu.kernels import pallas_attention as pa
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    q, kv = spec(1, rep, s, d), spec(1, s, d)
+    jaxpr = jax.make_jaxpr(lambda *a: pa._mha_bwd_impl(
+        *a, None, None, d ** -0.5, True, 1024, 1024))(
+            q, kv, kv, q, q, spec(1, rep, s, dtype=jnp.float32))
+    return [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+
+
+def _vmem_limit(call):
+    return call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+
+
+def test_backward_is_one_call_at_rep16_s8192():
+    """The Nemotron cell's attention (rep 16, s 8192, d 128): dq, dk and
+    dv come out of ONE kernel, whose grid streams k blocks innermost and
+    keeps 16 MiB of whole-length dk/dv beside the tiles."""
+    (call,) = _bwd_pallas_calls(16, 8192)
+    assert call.params["grid_mapping"].grid == (1, 16, 8, 8)
+    assert [a.shape for a in call.params["out_avals"]] == [
+        (1, 16 * 8, 1024, 128), (1, 8, 1024, 128), (1, 8, 1024, 128)]
+    assert _vmem_limit(call) == (16 << 20) + 2 * 8192 * 128 * (4 + 2 * 2)
+
+
+def test_backward_vmem_stops_growing_with_the_group():
+    """While the group's whole dq fits the budget the grid streams q
+    blocks innermost and asks for that dq (rep 1 and 2 at s 8192); past
+    it, what the kernel asks of VMEM follows the kv length alone: the
+    same at 4, 16 and 64 q heads a kv head, the whole-length part twice
+    as large at twice the length."""
+    def grid_and_limit(rep, s):
+        (call,) = _bwd_pallas_calls(rep, s)
+        return call.params["grid_mapping"].grid, _vmem_limit(call) - (16 << 20)
+
+    whole_dkv = 2 * 8192 * 128 * 8
+    assert grid_and_limit(1, 8192) == ((1, 8, 1, 8), 8192 * 128 * 8)
+    assert grid_and_limit(2, 8192) == ((1, 8, 2, 8), 2 * 8192 * 128 * 8)
+    for rep in (4, 16, 64):
+        assert grid_and_limit(rep, 8192) == ((1, rep, 8, 8), whole_dkv)
+    assert grid_and_limit(16, 16384)[1] == 2 * whole_dkv
+
+
+@pytest.fixture
+def whole_dq_budget(monkeypatch):
+    """set(n) sets the budget under which the backward keeps the group's
+    whole dq. The kernels' calls are jitted and the budget is baked into
+    a cached trace, so JAX's caches are cleared with every change."""
+    from paddle_tpu.kernels import pallas_attention as pa
+
+    def set_(n):
+        monkeypatch.setattr(pa, "_WHOLE_DQ_VMEM_BUDGET", n)
+        jax.clear_caches()
+
+    yield set_
+    monkeypatch.undo()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("window", [0, 600])
+def test_both_grid_orders_give_bitwise_equal_gradients(window,
+                                                       whole_dq_budget):
+    """Streaming q blocks innermost (the whole dq kept) and k blocks
+    innermost (the whole dk/dv kept) sum every gradient in the same
+    order: the same bits, in strips and with an lse cotangent."""
+    from paddle_tpu.kernels import pallas_attention as pa
+
+    rng = np.random.default_rng(43)
+    q = jnp.asarray(rng.standard_normal((1, 1024, 8, 128)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, 1024, 2, 128)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, 1024, 2, 128)), jnp.float32)
+
+    def grads():
+        def f(q, k, v):
+            o, lse = pa.mha_with_lse(q, k, v, causal=True, q_block=512,
+                                     k_block=512, window=window)
+            return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    whole_dq_budget(1 << 40)
+    q_inner = grads()
+    whole_dq_budget(0)
+    k_inner = grads()
+    for a, b in zip(q_inner, k_inner):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_mha_grad_window_and_lse_cotangent_at_rep16():
+    """A group of 16 q heads over 2 kv heads with a window, whole tiles
+    (blocks of 256 are not cut in strips) three k blocks deep and a
+    non-zero lse cotangent: forward, lse and the three gradients match
+    the dense reference."""
+    from paddle_tpu.kernels import pallas_attention as pa
+
+    rng = np.random.default_rng(41)
+    s, hk, window = 768, 2, 300
+    q = jnp.asarray(rng.standard_normal((1, s, 16 * hk, 128)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, s, hk, 128)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((1, s, hk, 128)), jnp.float32)
+
+    def loss(run):
+        def f(q, k, v):
+            o, lse = run(q, k, v)
+            return jnp.sum(o ** 2) + jnp.sum(jnp.cos(lse))
+        return f
+
+    def run_pallas(q, k, v):
+        return pa.mha_with_lse(q, k, v, causal=True, q_block=256,
+                               k_block=256, window=window)
+
+    def run_ref(q, k, v):
+        return _dense_attention(q, k, v, window)
+
+    for a, b in zip(run_pallas(q, k, v), run_ref(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-3)
     gp = jax.grad(loss(run_pallas), argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(loss(run_ref), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gp, gr):
