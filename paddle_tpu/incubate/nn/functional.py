@@ -51,7 +51,8 @@ def fused_linear_cross_entropy(x, weight, labels, bias=None,
     Math-equivalent to ``F.cross_entropy(F.linear(x, w), labels)`` with
     mean reduction over non-ignored tokens — softmax is row-wise, so
     chunking the sequence axis is exact. Logits exist one seq-chunk at a
-    time (f32 ``[..., seq_chunk, vocab]``); the chunk body is
+    time (``[..., seq_chunk, vocab]`` in the product's dtype, reduced by
+    ``F.softmax_cross_entropy_rows``); the chunk body is
     ``jax.checkpoint``'ed so backward recomputes each chunk's logits and
     accumulates the weight cotangent inside the scan. For a causal-LM
     train step the full-logits pair (f32 log-softmax + bf16 matmul
@@ -95,13 +96,11 @@ def fused_linear_cross_entropy(x, weight, labels, bias=None,
         logits = h @ w
         if bias is not None:
             logits = logits + bias
-        logits = logits.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
         valid = t != ignore_index
-        tsafe = jnp.where(valid, t, 0)
-        nll = -jnp.take_along_axis(logp, tsafe[..., None], axis=-1)[..., 0]
+        nll = F.softmax_cross_entropy_rows(
+            logits, jnp.where(valid, t, 0), valid)
         s, n = carry
-        return (s + jnp.sum(jnp.where(valid, nll, 0.0)),
+        return (s + jnp.sum(nll).astype(jnp.float32),
                 n + jnp.sum(valid.astype(jnp.int32))), None
 
     (loss_sum, count), _ = jax.lax.scan(

@@ -135,6 +135,6 @@ class ErnieMoEForCausalLM(Layer):
         if labels is None:
             return logits
         lm_loss = F.cross_entropy(
-            logits[:, :-1, :], labels[:, 1:], ignore_index=-100
+            logits, F.shift_labels(labels), ignore_index=-100
         )
         return lm_loss + total_aux

@@ -214,14 +214,14 @@ class GPTForCausalLM(Layer):
             from ..incubate.nn.functional import fused_linear_cross_entropy
 
             return fused_linear_cross_entropy(
-                hidden[:, :-1, :], self.lm_head.weight.value,
-                labels[:, 1:], ignore_index=-100,
+                hidden, self.lm_head.weight.value,
+                F.shift_labels(labels), ignore_index=-100,
                 seq_chunk=self.config.fused_head_loss_chunk)
         logits = self.lm_head(hidden)
         if labels is None:
             return logits
         return F.cross_entropy(
-            logits[:, :-1, :], labels[:, 1:], ignore_index=-100
+            logits, F.shift_labels(labels), ignore_index=-100
         )
 
     def init_kv_caches(self, batch_size: int, max_len: int, dtype=None):

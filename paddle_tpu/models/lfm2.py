@@ -295,7 +295,7 @@ class Lfm2MoeForCausalLM(Layer):
             logits = hidden @ self.model.embed_tokens.weight.value.T
             if labels is None:
                 return logits
-            return F.cross_entropy(logits[:, :-1, :], labels[:, 1:],
+            return F.cross_entropy(logits, F.shift_labels(labels),
                                    ignore_index=-100)
 
     def step_counters(self):

@@ -501,25 +501,26 @@ class LlamaForCausalLM(Layer):
             return self._lm_loss(hidden, labels)
 
     def _lm_loss(self, hidden, labels):
-        shift_labels = labels[:, 1:]
+        # next-token LM loss over every position: the last one's target
+        # is ignored, so neither the logits nor the hidden states are
+        # sliced
+        targets = F.shift_labels(labels, ignore_index=-100)
         if self.config.fused_head_loss_chunk:
             # chunked head+CE: math-identical to the full-logits path
             # (softmax is row-wise) but peak memory is one seq chunk
             from ..incubate.nn.functional import fused_linear_cross_entropy
 
-            shift_hidden = hidden[:, :-1, :]
             if self.lm_head is not None:
                 return fused_linear_cross_entropy(
-                    shift_hidden, self.lm_head.weight.value, shift_labels,
+                    hidden, self.lm_head.weight.value, targets,
                     ignore_index=-100,
                     seq_chunk=self.config.fused_head_loss_chunk)
             return fused_linear_cross_entropy(
-                shift_hidden, self.model.embed_tokens.weight.value,
-                shift_labels, transpose_weight=True, ignore_index=-100,
+                hidden, self.model.embed_tokens.weight.value, targets,
+                transpose_weight=True, ignore_index=-100,
                 seq_chunk=self.config.fused_head_loss_chunk)
-        # next-token LM loss, fp32 softmax over the (tp-sharded) vocab
-        shift_logits = self.logits(hidden)[:, :-1, :]
-        return F.cross_entropy(shift_logits, shift_labels, ignore_index=-100)
+        # fp32 softmax over the (tp-sharded) vocab
+        return F.cross_entropy(self.logits(hidden), targets, ignore_index=-100)
 
     def init_kv_caches(self, batch_size: int, max_len: int, dtype=None):
         cfg = self.config

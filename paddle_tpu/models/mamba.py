@@ -249,7 +249,7 @@ class MambaForCausalLM(Layer):
         logits = x @ self.embeddings.weight.value.T  # tied
         if labels is None:
             return logits
-        return F.cross_entropy(logits[:, :-1], labels[:, 1:])
+        return F.cross_entropy(logits, F.shift_labels(labels))
 
 
 class Mamba2Mixer(Layer):

@@ -214,7 +214,7 @@ class NemotronHForCausalLM(Layer):
             logits = self.lm_head(hidden)
             if labels is None:
                 return logits
-            return F.cross_entropy(logits[:, :-1, :], labels[:, 1:],
+            return F.cross_entropy(logits, F.shift_labels(labels),
                                    ignore_index=-100)
 
     def step_counters(self):

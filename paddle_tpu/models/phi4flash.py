@@ -352,6 +352,6 @@ class Phi4FlashForCausalLM(Layer):
             # in the backward pass: whole, the logits and their float32
             # log-softmax are 1.6 GB at 8192 x 25,008
             return fused_linear_cross_entropy(
-                hidden[:, :-1, :], embedding, labels[:, 1:],
+                hidden, embedding, F.shift_labels(labels),
                 transpose_weight=True, ignore_index=-100,
                 seq_chunk=HEAD_LOSS_ROWS)

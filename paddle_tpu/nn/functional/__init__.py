@@ -96,9 +96,11 @@ from .loss import (  # noqa: F401
     multi_label_soft_margin_loss,
     nll_loss,
     poisson_nll_loss,
+    shift_labels,
     sigmoid_focal_loss,
     smooth_l1_loss,
     soft_margin_loss,
+    softmax_cross_entropy_rows,
     square_error_cost,
     triplet_margin_loss,
 )

@@ -10,6 +10,69 @@ from jax import lax
 from .common import _f32up, _v, cosine_similarity
 
 
+def shift_labels(labels, ignore_index: int = -100):
+    """Next-token targets for a causal LM: ``labels[:, 1:]`` followed by
+    a column of ``ignore_index``, so the loss runs on the whole ``[b, s,
+    vocab]`` logits (the last position predicts nothing) instead of a
+    ``[:, :-1]`` slice of them, which copies the logits once and pads
+    their cotangent back. The count of valid targets is the slice's."""
+    labels = _v(labels)
+    return jnp.concatenate(
+        [labels[:, 1:],
+         jnp.full((labels.shape[0], 1), ignore_index, labels.dtype)],
+        axis=1)
+
+
+@jax.custom_vjp
+def softmax_cross_entropy_rows(logits, label, valid):
+    """Per-row ``(logsumexp(logits) - logits[label]) * valid``, in
+    float32 (or wider), for hard labels on the last axis; ``label`` is
+    in range wherever ``valid`` is 0 too.
+
+    The forward keeps only each row's log-sum-exp, and picks the label's
+    logit as a masked sum inside the same row reduction (no gather); the
+    backward makes the logits' cotangent ``(softmax - onehot) * g`` in
+    one pass and writes it once, rounded to the logits' dtype. No
+    float32 array of the logits' size is written and nothing is
+    scattered."""
+    return _softmax_ce_fwd(logits, label, valid)[0]
+
+
+def _widen(logits):
+    return logits.astype(jnp.promote_types(logits.dtype, jnp.float32))
+
+
+def _is_label(x, label):
+    # a 1-D iota: inside a loop (the chunked loss) a loop-invariant
+    # array of the logits' shape would be hoisted out and kept whole
+    return lax.iota(jnp.int32, x.shape[-1]) == label[..., None]
+
+
+def _softmax_ce_fwd(logits, label, valid):
+    x = _widen(logits)
+    m = jnp.max(x, axis=-1)
+    lse = m + jnp.log(jnp.sum(jnp.exp(x - m[..., None]), axis=-1))
+    picked = jnp.sum(jnp.where(_is_label(x, label), x, 0.0), axis=-1)
+    loss = (lse - picked) * valid.astype(x.dtype)
+    return loss, (logits, lse, label, valid)
+
+
+def _softmax_ce_bwd(res, g):
+    logits, lse, label, valid = res
+    x = _widen(logits)
+    p = jnp.exp(x - lse[..., None])
+    scale = (g * valid.astype(g.dtype))[..., None]
+    dx = (p - _is_label(x, label).astype(x.dtype)) * scale
+    # written once, in the logits' dtype, for the head's two backward
+    # products to read: without the barrier XLA folds this pass into
+    # both products' operands and re-tiles the step's other products
+    # around them, a net loss on a v5e in the dense 7B step
+    return lax.optimization_barrier(dx.astype(logits.dtype)), None, None
+
+
+softmax_cross_entropy_rows.defvjp(_softmax_ce_fwd, _softmax_ce_bwd)
+
+
 def cross_entropy(
     logits,
     label,
@@ -22,36 +85,36 @@ def cross_entropy(
     """Parity: F.cross_entropy (softmax_with_cross_entropy phi kernel).
 
     Computes in fp32 regardless of input dtype (matching the fused kernel's
-    accumulation behavior).
+    accumulation behavior). Hard labels without smoothing go through
+    ``softmax_cross_entropy_rows``: the logits stay in their own dtype and
+    are widened inside its row reductions.
     """
-    logits = _f32up(_v(logits))
+    logits = _v(logits)
+    label = _v(label)
     if axis not in (-1, logits.ndim - 1):
         # normalize to class-dim-last so gathers/one-hots line up
         logits = jnp.moveaxis(logits, axis, -1)
         if soft_label:
-            label = jnp.moveaxis(_v(label), axis, -1)
-        axis = -1
-    logp = jax.nn.log_softmax(logits, axis=axis)
+            label = jnp.moveaxis(label, axis, -1)
+    if not soft_label and label_smoothing == 0.0:
+        valid = label != ignore_index
+        loss = softmax_cross_entropy_rows(
+            logits, jnp.where(valid, label, 0), valid)
+        return _reduce(loss, valid.astype(loss.dtype), reduction)
+    logp = jax.nn.log_softmax(_f32up(logits), axis=-1)
     if soft_label:
-        target = _v(label).astype(logits.dtype)
-        loss = -jnp.sum(target * logp, axis=axis)
+        loss = -jnp.sum(label.astype(logp.dtype) * logp, axis=-1)
         valid = jnp.ones(loss.shape, jnp.float32)
     else:
-        label = _v(label)
-        num_classes = logits.shape[axis]
-        if label_smoothing > 0.0:
-            onehot = jax.nn.one_hot(label, num_classes, dtype=jnp.float32)
-            smooth = (
-                onehot * (1.0 - label_smoothing) + label_smoothing / num_classes
-            )
-            loss = -jnp.sum(smooth * logp, axis=axis)
-        else:
-            safe_label = jnp.where(label == ignore_index, 0, label)
-            loss = -jnp.take_along_axis(
-                logp, safe_label[..., None], axis=axis
-            ).squeeze(axis)
+        num_classes = logits.shape[-1]
+        onehot = jax.nn.one_hot(label, num_classes, dtype=jnp.float32)
+        smooth = onehot * (1.0 - label_smoothing) + label_smoothing / num_classes
         valid = (label != ignore_index).astype(jnp.float32)
-        loss = loss * valid
+        loss = -jnp.sum(smooth * logp, axis=-1) * valid
+    return _reduce(loss, valid, reduction)
+
+
+def _reduce(loss, valid, reduction):
     if reduction == "none":
         return loss
     if reduction == "sum":
